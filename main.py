@@ -15,6 +15,7 @@ Examples:
 from __future__ import annotations
 
 import argparse
+import sys
 
 from pytorch_distributed_tpu.config import CONFIGS, build_options
 
@@ -112,7 +113,11 @@ def main(argv=None):
         print(f"[main] training config {args.config} "
               f"({opt.agent_type}/{opt.env_type}/{opt.game}/"
               f"{opt.memory_type}/{opt.model_type}) -> {opt.refs}")
-        runtime.train(opt, backend=args.backend)
+        topo = runtime.train(opt, backend=args.backend)
+        if topo.stop_reason in runtime.FATAL_STOP_REASONS:
+            # Topology.run returns normally once the monitor has stopped
+            # the run; a run that lost a worker did not succeed
+            sys.exit(f"[main] run FAILED: stopped by {topo.stop_reason}")
     else:
         runtime.test(opt)
 

@@ -110,7 +110,7 @@ class AnakinDriver:
         from pytorch_distributed_tpu.factory import (
             anakin_eligible, build_device_env, build_megabatch_train_step,
             build_model, build_train_state_and_step, init_params,
-            resolve_megabatch,
+            resolve_megabatch, resolve_steps_per_dispatch,
         )
         from pytorch_distributed_tpu.memory.device_per import (
             per_write_masked,
@@ -249,9 +249,7 @@ class AnakinDriver:
         # EXACT constructions, so a co-located step is the same XLA
         # program a split-process learner dispatches — the parity
         # oracle's ground) ----
-        K = ap.steps_per_dispatch
-        if K <= 0:
-            K = 32 if jax.devices()[0].platform == "tpu" else 1
+        K = resolve_steps_per_dispatch(opt)
         # ISSUE-13 megabatching: the SAME factory resolution the
         # split-process learner uses, so the co-located twin's learner
         # dispatch is the same XLA program (the parity oracle's ground)
@@ -288,6 +286,11 @@ class AnakinDriver:
                     lambda ts, rs, key: step_fn(
                         ts, sample_rows(rs, key, ap.batch_size)),
                     donate_argnums=(0,) if pp.donate else ())
+
+        from pytorch_distributed_tpu.agents.learner import announce_startup
+
+        announce_startup(opt, mesh=mesh, steps_per_dispatch=K,
+                         replay=self.rings[0])
 
         # learner-side sampling key stream (run_learner's scheme: one
         # split amortised over 64 dispatches, beta refreshed with it)

@@ -14,7 +14,7 @@ snapshots (weights, learner_step, wall) on the ``evaluator_freq`` cadence
 — a cheap shared-memory copy that holds its schedule even when this
 process is starved of CPU (``evaluator_nice`` on a 1-core host stretched
 the old eval-inline cadence from ~60 s to ~10 min and made a north-star
-run's +18 crossing timestamp a sampling artifact, RESULTS.md round 3) —
+run's +18 crossing timestamp a sampling artifact) —
 while the expensive greedy episodes drain the snapshot backlog in order
 and publish each result against its CAPTURE step and wall time.  Under
 sustained starvation the backlog drops its oldest pending snapshots

@@ -400,8 +400,8 @@ class InferenceServer:
     def _begin_packed(self, req: Tuple):
         """Dispatch one frame-packed request WITHOUT syncing: roll the
         client's device-resident stack by its new frames and act, fused
-        in one program — only the newest frame crossed the (possibly
-        tunnelled) link.  Returns ``((cid, nonce, tick), out_handle)``
+        in one program — only the newest frame crossed the host-to-device
+        link.  Returns ``((cid, nonce, tick), out_handle)``
         for the caller to sync after every pending dispatch is issued.
         The stack seed always exists: a client's first
         post-``begin_session`` submit is a full upload by

@@ -358,11 +358,12 @@ class AgentParams:
     max_replay_ratio: float = 0.0
     # Device-replay learners fuse this many update steps into ONE
     # dispatched XLA program (lax.scan over sample+train): program-launch
-    # latency, not chip compute, bounds the hot loop when dispatch is
-    # high-latency (tunnelled dev chips; congested hosts).  0 = auto
-    # (32 on TPU, 1 elsewhere).  Cadences (publish/checkpoint/stats) are
-    # quantized to the dispatch size, and the ``steps`` budget itself may
-    # overshoot by up to K-1 updates (the final dispatch is whole).
+    # latency is paid once per K updates.  0 = auto (32 on TPU, 1
+    # elsewhere — factory.resolve_steps_per_dispatch; the 32 has not
+    # been measured on a directly attached chip).  Cadences
+    # (publish/checkpoint/stats) are quantized to the dispatch size, and
+    # the ``steps`` budget itself may overshoot by up to K-1 updates
+    # (the final dispatch is whole).
     steps_per_dispatch: int = 0
     target_model_update: float = 250   # >=1: hard every N steps; <1: soft tau
     nstep: int = 5
@@ -491,9 +492,10 @@ class PerfParams:
     # cost_analysis FLOPs) at learner startup.
     enabled: bool = False
     # Peak dense FLOP/s per chip for the MFU ratio.  0 = auto from the
-    # device kind (utils/perf.PEAK_FLOPS); unknown kinds (CPU, new TPU
-    # generations) export achieved FLOP/s but no MFU row unless this is
-    # set explicitly (``TPU_APEX_PERF_PEAK_FLOPS=...``).
+    # device kind (utils/perf.PEAK_FLOPS): the CPU exports achieved
+    # FLOP/s but no MFU row, a TPU kind missing from the table is an
+    # error — unless this is set explicitly
+    # (``TPU_APEX_PERF_PEAK_FLOPS=...``).
     peak_flops: float = 0.0
     # Per-role memory watermarks on the drain cadence: device
     # live/peak bytes from ``device.memory_stats()`` where the backend

@@ -671,27 +671,18 @@ def build_train_state_and_step(opt: Options, spec: EnvSpec, model, params,
     raise ValueError(f"unknown agent_type: {opt.agent_type}")
 
 
-def _dqn_train_apply(opt: Options, model):
-    """The learner-side apply for the dqn family: the model's own apply,
-    re-based for NHWC ring storage when that knob is live, and swapped
-    for the Pallas fused torso (ops/pallas_torso.py) when the ISSUE-13
-    ``pallas_torso`` knob is on and runnable.  Decided HERE — one gate
-    shared by the sequential step and the megabatch step — so the two
-    programs can never train through different torsos.  Actors and
-    evaluators never route through this: the param tree is identical,
-    so they keep the standard apply."""
-    train_apply = model.apply
-    nhwc = device_ring_channels_last(opt)
-    if nhwc:
-        # the HBM ring stores rows NHWC (same param tree, transpose
-        # moved from 3x per update to once per ingest — see
-        # memory/device_replay.py chunk_to_nhwc)
-        train_apply = model.clone(nhwc_input=True).apply
+def select_torso(opt: Options) -> str:
+    """Which torso the dqn learner's train program runs — ``"xla"``,
+    ``"pallas"`` or ``"pallas-interpret"`` — decided from the
+    ``pallas_torso`` knob, the model family and the backend.  The ONE
+    gate: ``_dqn_train_apply`` builds what this names and the learner's
+    start-up line prints it, so a request the host cannot honour is
+    never a silent downgrade."""
     from pytorch_distributed_tpu.utils.perf import resolve_mxu
 
     lp = resolve_mxu(opt.learner_perf_params)
     if not lp.pallas_torso:
-        return train_apply
+        return "xla"
     import warnings
 
     if opt.model_type != "dqn-cnn":
@@ -699,18 +690,37 @@ def _dqn_train_apply(opt: Options, model):
             f"pallas_torso=true serves the dqn-cnn torso only (got "
             f"model_type={opt.model_type}); keeping the XLA apply",
             stacklevel=3)
-        return train_apply
+        return "xla"
+    if lp.pallas_interpret:
+        return "pallas-interpret"
     import jax
 
-    if jax.devices()[0].platform != "tpu" and not lp.pallas_interpret:
-        # LOUD downgrade, never a silent one: a config that asked for
-        # the MXU kernel but runs on a host without one must say so
+    if jax.devices()[0].platform != "tpu":
         warnings.warn(
             "pallas_torso=true but no TPU backend is present "
             "(set pallas_interpret=true for the interpreter-mode CPU "
             "fallback — tier-1 parity tests only; it is slower than "
             "XLA's native conv); keeping the XLA apply", stacklevel=3)
-        return train_apply
+        return "xla"
+    return "pallas"
+
+
+def _dqn_train_apply(opt: Options, model):
+    """The learner-side apply for the dqn family: the model's own apply,
+    re-based for NHWC ring storage when that knob is live, and swapped
+    for the Pallas fused torso (ops/pallas_torso.py) when
+    ``select_torso`` says so.  Decided HERE — one gate shared by the
+    sequential step and the megabatch step — so the two programs can
+    never train through different torsos.  Actors and evaluators never
+    route through this: the param tree is identical, so they keep the
+    standard apply."""
+    nhwc = device_ring_channels_last(opt)
+    torso = select_torso(opt)
+    if torso == "xla":
+        # the HBM ring may store rows NHWC (same param tree, transpose
+        # moved from 3x per update to once per ingest — see
+        # memory/device_replay.py chunk_to_nhwc)
+        return model.clone(nhwc_input=True).apply if nhwc else model.apply
     from pytorch_distributed_tpu.ops.pallas_torso import (
         build_pallas_torso_apply,
     )
@@ -720,7 +730,7 @@ def _dqn_train_apply(opt: Options, model):
         norm_val=model.norm_val,
         compute_dtype=jnp.dtype(opt.model_params.compute_dtype),
         nhwc_input=nhwc,
-        interpret=lp.pallas_interpret)
+        interpret=torso == "pallas-interpret")
 
 
 def build_megabatch_train_step(opt: Options, model):
@@ -769,6 +779,22 @@ def build_megabatch_train_step(opt: Options, model):
             guard=guard,
         )
     return None
+
+
+def resolve_steps_per_dispatch(opt: Options) -> int:
+    """Update steps fused into one dispatched program on the device-
+    replay paths: ``agent_params.steps_per_dispatch`` when set, else
+    auto — 32 on a TPU (amortise the launch), 1 elsewhere (on the CPU
+    backend the dispatch IS the compute).  One rule for the split
+    learner and the Anakin loop; the learner's start-up line prints
+    the result.  The 32 was chosen over a link that no longer exists
+    and has not been measured on a directly attached chip."""
+    K = opt.agent_params.steps_per_dispatch
+    if K > 0:
+        return K
+    import jax
+
+    return 32 if jax.devices()[0].platform == "tpu" else 1
 
 
 def resolve_megabatch(opt: Options, steps_per_call: int

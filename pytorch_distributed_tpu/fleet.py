@@ -69,7 +69,9 @@ import time
 from typing import List, Optional
 
 from pytorch_distributed_tpu.config import Options, build_options
-from pytorch_distributed_tpu.runtime import Topology
+from pytorch_distributed_tpu.runtime import (
+    FATAL_STOP_REASONS, Topology, cpu_child_env,
+)
 
 _CTX = mp.get_context("spawn")
 
@@ -649,11 +651,6 @@ def _remote_actor_main(opt: Options, coordinator: str, process_ind: int,
     the stop/disconnected split, a gateway blip read as "run complete"
     and silently drained the whole remote fleet with zero restarts
     consumed."""
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
-
     from pytorch_distributed_tpu.factory import get_worker, probe_env
     from pytorch_distributed_tpu.parallel.dcn import (
         DcnClient, DcnRefused, RemoteClock, RemoteMemory, RemoteParamStore,
@@ -806,7 +803,13 @@ def run_fleet_actors(opt: Options, coordinator: str, actor_base: int,
 
             w = threading.Thread(target=_thread_main,
                                  name=f"fleet-actor-{ind}", daemon=True)
-        w.start()
+        if backend == "process":
+            # rollout workers are CPU processes wherever they run (an
+            # actor host may sit beside a chip another process owns)
+            with cpu_child_env():
+                w.start()
+        else:
+            w.start()
         return w
 
     workers = {actor_base + i: spawn(actor_base + i)
@@ -1066,9 +1069,18 @@ def main(argv: Optional[List[str]] = None) -> None:
         overrides["resume"] = "must"
     opt = build_options(args.config, **overrides)
 
+    if args.role in ("learner", "learner-replica"):
+        # the roles that own an accelerator share main.py's cache rule
+        from pytorch_distributed_tpu.utils.helpers import (
+            enable_compile_cache,
+        )
+
+        enable_compile_cache()
     if args.role == "learner":
-        run_fleet_learner(opt, local_actors=args.local_actors,
-                          port=args.port)
+        topo = run_fleet_learner(opt, local_actors=args.local_actors,
+                                 port=args.port)
+        if topo.stop_reason in FATAL_STOP_REASONS:
+            sys.exit(f"[fleet] run FAILED: stopped by {topo.stop_reason}")
     elif args.role == "learner-replica":
         assert args.coordinator, "--coordinator host:port required"
         run_replica_host(opt, args.coordinator, args.replica_id)

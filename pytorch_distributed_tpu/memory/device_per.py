@@ -219,8 +219,12 @@ class DevicePerReplay(DeviceReplay):
         # Pallas hierarchical sampler on unsharded TPU rings; the flat XLA
         # scheme everywhere else (dp-sharded rings address rows through
         # collectives the kernel can't, and CPU interpret mode is slower
-        # than XLA's cumsum).
+        # than XLA's cumsum).  ``sampler`` names the choice for the
+        # learner's start-up line.  NOTE the gate is "a mesh exists", not
+        # "dp > 1": the learner builds a mesh whenever more than one chip
+        # is visible, so on a multi-chip host this is always "xla".
         self._draw_fn = None
+        self.sampler = "xla"
         if (self._row_sharding is None
                 and jax.devices()[0].platform == "tpu"):
             from pytorch_distributed_tpu.ops.pallas_sampling import (
@@ -228,6 +232,7 @@ class DevicePerReplay(DeviceReplay):
             )
 
             self._draw_fn = hierarchical_sample
+            self.sampler = "pallas"
 
         feed = functools.partial(per_feed, capacity=self.capacity)
         if self.channels_last:

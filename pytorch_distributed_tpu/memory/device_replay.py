@@ -206,10 +206,10 @@ def build_uniform_fused_step(step_fn, batch_size: int,
     the HBM ring: ``(train_state, ring_state, keys (K, 2)) ->
     (train_state', metrics_of_last_substep)``.
 
-    Multi-step fusion exists because program-launch latency, not chip
-    compute, bounds the learner when the device sits behind a network
-    tunnel (or any high-latency dispatch path): K updates per dispatch
-    amortise the launch to 1/K per update.  The ring is read-only inside —
+    Multi-step fusion amortises program-launch latency: K updates per
+    dispatch pay the launch once, 1/K per update (how much that buys on
+    a directly attached chip is not measured).  The ring is read-only
+    inside —
     ingest stays on the host drain cadence between dispatches.
 
     ``megabatch`` M > 1 (ISSUE 13, with ``megabatch_step`` from

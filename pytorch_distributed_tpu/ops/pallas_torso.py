@@ -25,8 +25,9 @@ bf16 conv behaviour; parity vs the XLA reference is tolerance-based
 fp summation order inside a hand-tiled GEMM differs from XLA's.
 
 CPU story: ``interpret=True`` runs the same kernels under the Pallas
-interpreter so the tier-1 parity tests execute on this image; the
-production gate (factory._dqn_train_apply) only engages the kernel on a
+interpreter so the tier-1 parity tests execute on a CPU; the compiled
+kernels are checked on the chip by tools/kernel_check.py.  The
+production gate (factory.select_torso) only engages the kernel on a
 TPU backend (or under the explicit ``pallas_interpret`` knob) and
 downgrades LOUDLY otherwise.  Knobs: config.LearnerPerfParams
 (``TPU_APEX_MXU_PALLAS_TORSO`` / ``TPU_APEX_MXU_PALLAS_INTERPRET``).
@@ -78,8 +79,13 @@ def _mm_kernel(x_ref, w_ref, o_ref):
 # backward dw = x^T @ g GEMM contracts over B*OH*OW rows (51k at the
 # production batch 128 on Conv_0), so an untiled contraction dim would
 # stage ~26 MB x-tiles and blow the ~16 MB VMEM budget on exactly the
-# TPU the kernel targets.  Worst resident set per step is now
-# (TM, TK) + (TK, Np) + (TM, Np) — ~1.5 MB at the FC-512's Np=512.
+# TPU the kernel targets.  N is NOT tiled: the resident set per step is
+# (TM, TK) + (TK, Np) + (TM, Np) — ~1.5 MB forward at the FC-512's
+# Np=512, but the custom-VJP backward rotates the dims, and dx = g @ w^T
+# for the FC-512 has Np = 3,200: a 6.5 MB f32 weight tile plus a 1.6 MB
+# output tile.  That compiles and runs on a v5e under jax 0.9.0
+# (tools/kernel_check.py, chip run of ISSUE 21) with little room to
+# spare; a wider layer would need an N grid axis.
 _TILE_M = 128
 _TILE_K = 512
 _LANES = 128

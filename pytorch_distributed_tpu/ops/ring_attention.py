@@ -30,8 +30,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from pytorch_distributed_tpu.utils.helpers import shard_map
-
 NEG_INF = -1e30
 
 
@@ -124,8 +122,8 @@ def sharded_attention_call(body, q, k, v, mesh: Mesh, axis: str,
     bspec = batch_axis if (batch_axis and mesh.shape[batch_axis] > 1) \
         else None
     spec = P(bspec, None, axis, None)
-    fn = shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
-                   out_specs=spec, check_vma=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
+                       out_specs=spec, check_vma=False)
     return fn(q, k, v)
 
 

@@ -40,7 +40,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from pytorch_distributed_tpu.models.dtqn_pipeline import block_forward
-from pytorch_distributed_tpu.utils.helpers import shard_map
 
 
 def pipeline_blocks(stacked: Any, x: jnp.ndarray, *, mesh: Mesh,
@@ -51,7 +50,7 @@ def pipeline_blocks(stacked: Any, x: jnp.ndarray, *, mesh: Mesh,
     M = num_microbatches
     perm = [(i, (i + 1) % S) for i in range(S)]
 
-    @partial(shard_map, mesh=mesh,
+    @partial(jax.shard_map, mesh=mesh,
              in_specs=(jax.tree_util.tree_map(lambda _: P("pp"), stacked),
                        P("dp")),
              out_specs=P("dp"), check_vma=False)

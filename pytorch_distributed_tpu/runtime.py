@@ -5,9 +5,10 @@ the shared objects (replay plane, param store, clocks — the explicit
 equivalents of the reference's shared memory at main.py:42, shared CUDA
 model at :44-47, and mp.Value logs at :51-54), then run one logger,
 ``num_actors`` actors and one evaluator as workers, with **the learner in
-the parent process** — the parent owns the TPU mesh; every child pins JAX to
-CPU through the spawn trampoline, so exactly one process initialises the
-accelerator (the reference instead gives every process a CUDA context).
+the parent process** — the parent owns the TPU mesh; every child is exec'd
+with ``JAX_PLATFORMS=cpu`` (``cpu_child_env``), so exactly one process
+initialises the accelerator (the reference instead gives every process a
+CUDA context).
 Scaling learners means widening the mesh's dp axis, not adding racing
 processes (agents/learner.py docstring).
 
@@ -22,6 +23,7 @@ deterministic test harness SURVEY.md §4 calls for).
 
 from __future__ import annotations
 
+import contextlib
 import multiprocessing as mp
 import os
 import signal
@@ -41,6 +43,41 @@ from pytorch_distributed_tpu.agents.param_store import ParamStore
 
 _CTX = mp.get_context("spawn")
 
+# why a run ended early, when it was not the learner reaching its own end
+# (Topology.stop_reason); a run stopped for one of these FAILED — main.py
+# and the fleet CLI exit non-zero on them
+FATAL_STOP_REASONS = ("worker-fatal", "inference-server-dead")
+
+_SPAWN_ENV_LOCK = threading.Lock()
+
+
+@contextlib.contextmanager
+def cpu_child_env():
+    """The environment a CPU worker is exec'd with: ``JAX_PLATFORMS=cpu``
+    and no ``JAX_COMPILATION_CACHE_DIR``.  A spawn child unpickles its
+    arguments — importing this package and jax — BEFORE its target
+    runs, so a flip inside the child comes too late to be a guarantee:
+    with libtpu on the host, a child that so much as brushes the TPU
+    backend while the parent holds the chip fails or hangs.  Wrap
+    ``Process.start()`` in this; the parent's own environment is
+    restored on exit (jax read it at import, so the learner never
+    notices the swap)."""
+    with _SPAWN_ENV_LOCK:
+        saved = {k: os.environ.get(k)
+                 for k in ("JAX_PLATFORMS", "JAX_COMPILATION_CACHE_DIR")}
+        os.environ["JAX_PLATFORMS"] = "cpu"
+        # CPU-backend processes never use the persistent compile cache
+        # (utils/helpers.enable_compile_cache says why)
+        os.environ.pop("JAX_COMPILATION_CACHE_DIR", None)
+        try:
+            yield
+        finally:
+            for k, v in saved.items():
+                if v is None:
+                    os.environ.pop(k, None)
+                else:
+                    os.environ[k] = v
+
 
 def _count_params(opt: Options, spec: EnvSpec) -> int:
     from pytorch_distributed_tpu.factory import build_model, init_params
@@ -51,31 +88,26 @@ def _count_params(opt: Options, spec: EnvSpec) -> int:
 
 
 def _child_main(role: str, agent_type: str, args: tuple) -> None:
-    """Spawn trampoline: pin this child to the CPU backend *before* any JAX
-    computation, then dispatch to the worker function.  Backends initialise
-    lazily, so flipping the config here is safe even though modules were
-    imported during unpickling.
+    """Spawn trampoline: a CPU process (``cpu_child_env`` set that up
+    before exec), which says so in the run's ``startup.jsonl`` and then
+    dispatches to the worker function.
 
     Also the crash boundary for the flight recorder: an exception escaping
     the worker dumps this process's event rings to ``blackbox/`` BEFORE
     re-raising — the supervisor's restart must not erase the evidence."""
-    os.environ["JAX_PLATFORMS"] = "cpu"
-    # CPU-backend processes never use the persistent compile cache: the
-    # CPU AOT loader can nondeterministically SIGABRT re-loaded
-    # multi-device programs (utils/helpers.enable_compile_cache), and a
-    # TPU parent's cache env var would otherwise leak in here
-    os.environ.pop("JAX_COMPILATION_CACHE_DIR", None)
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
     from pytorch_distributed_tpu.utils import flight_recorder
+    from pytorch_distributed_tpu.utils.helpers import record_startup
 
     opt = args[0]
     flight_recorder.configure(opt.log_dir, run_id=opt.refs)
     label = role
     if role in ("actor", "evaluator") and len(args) > 2:
         label = f"{role}-{args[2]}"
-    jax.config.update("jax_compilation_cache_dir", None)
+    rec = record_startup(opt.log_dir, label)
+    if rec["platform"] != "cpu":
+        raise RuntimeError(
+            f"{label} initialised the {rec['platform']} backend; workers "
+            f"are CPU processes (the learner owns the accelerator)")
     if role == "evaluator":
         # The evaluator's batch-1 greedy episodes are bursty CPU work
         # that matters only for reporting cadence; on an oversubscribed
@@ -131,6 +163,9 @@ class Topology:
         # set when a SIGTERM (preemption notice) ended the run rather
         # than the step budget — observable by callers/tests
         self.preempted = threading.Event()
+        # why the monitor stopped the run (one of FATAL_STOP_REASONS),
+        # None while/when the learner ran to its own end
+        self.stop_reason: Optional[str] = None
         # ---- hang watchdog (health sentinel): every supervised role
         # publishes liveness-progress marks on a shared board riding the
         # clock's spawn pickle; the monitor SIGKILLs workers whose marks
@@ -371,11 +406,19 @@ class Topology:
             ls._q = tq
             as_._q = tq
 
+    def _stop_run(self, reason: str) -> None:
+        """The monitor's fail-fast exit: record WHY before tripping the
+        stop event, so the caller can tell a stopped run from a finished
+        one (``Topology.run`` itself returns normally either way)."""
+        self.stop_reason = reason
+        self.clock.stop.set()
+
     def _spawn(self, role: str, ind: int, args: tuple) -> None:
         p = _CTX.Process(
             target=_child_main, args=(role, self.opt.agent_type, args),
             name=f"{role}-{ind}", daemon=True)
-        p.start()
+        with cpu_child_env():
+            p.start()
         # restart the slot's watchdog grace window with the incarnation
         self.progress_board.note_start(f"{role}-{ind}")
         self._workers.append(p)
@@ -419,7 +462,7 @@ class Topology:
                 recorder.record("inference-server-dead")
                 flight_recorder.dump_all(
                     "inference server died; run stopped")
-                self.clock.stop.set()
+                self._stop_run("inference-server-dead")
                 return
             for i, (p, role, ind, args) in enumerate(list(self._proc_meta)):
                 if p.exitcode in (None, 0):
@@ -444,7 +487,7 @@ class Topology:
                     flight_recorder.dump_all(
                         f"{role}-{ind} died "
                         f"({describe_exit(p.exitcode)}); run stopped")
-                    self.clock.stop.set()
+                    self._stop_run("worker-fatal")
                     return
             # ---- hang watchdog: an alive-but-stuck worker never
             # produces an exit code, so liveness is read off the
@@ -488,7 +531,7 @@ class Topology:
                               f"stopping run")
                         recorder.record("worker-fatal", role=role,
                                         slot=ind, exit=EXIT_HUNG)
-                        self.clock.stop.set()
+                        self._stop_run("worker-fatal")
                         return
                 if "learner" in hung:
                     # the learner runs on THIS process's main thread: a

@@ -66,7 +66,10 @@ from typing import Any, Callable, Dict, List, Optional
 # ---------------------------------------------------------------------------
 
 # Peak dense bf16 FLOP/s per chip by device_kind, for the MFU estimate.
-# Public figures; unknown kinds report achieved FLOP/s with mfu omitted.
+# Public figures (Google Cloud TPU documentation).  The directly attached
+# v5e reports device_kind "TPU v5 lite" (chip run, ISSUE 21).  A TPU kind
+# missing from this table is an error where a peak is used, never a
+# default; non-TPU devices have no peak (None).
 PEAK_FLOPS = {
     "TPU v4": 275e12,
     "TPU v5e": 197e12,
@@ -90,16 +93,23 @@ DTYPE_PEAK_SCALE = {
 
 def peak_flops_of(device, compute_dtype: Optional[str] = None
                   ) -> Optional[float]:
-    """Peak dense FLOP/s for a jax device, None when the kind is not in
-    the table (CPU, future generations).  ``compute_dtype`` scales the
-    bf16 table entry to the dtype's MXU peak (fp32 = half); unknown
-    dtypes keep the bf16 figure."""
+    """Peak dense FLOP/s for a jax device.  None for a non-TPU device
+    (the CPU has no table entry and no MFU); a TPU whose ``device_kind``
+    is not in ``PEAK_FLOPS`` raises — the chip path must know its
+    device, and an MFU against a guessed peak is worse than none.
+    ``compute_dtype`` scales the bf16 table entry to the dtype's MXU
+    peak (fp32 = half); unknown dtypes keep the bf16 figure."""
     kind = getattr(device, "device_kind", "") or ""
     for name, peak in PEAK_FLOPS.items():
         if kind.lower().startswith(name.lower()):
             if compute_dtype is not None:
                 peak *= DTYPE_PEAK_SCALE.get(str(compute_dtype), 1.0)
             return peak
+    if getattr(device, "platform", "") == "tpu" \
+            or kind.lower().startswith("tpu"):
+        raise ValueError(
+            f"no peak FLOP/s known for TPU device_kind {kind!r}; add it "
+            f"to utils/perf.PEAK_FLOPS with its source")
     return None
 
 
@@ -514,13 +524,10 @@ class PerfMonitor:
             if self.params.peak_flops > 0:
                 self._peak = float(self.params.peak_flops)
             else:
-                try:
-                    import jax
+                import jax
 
-                    self._peak = peak_flops_of(jax.devices()[0],
-                                               self.compute_dtype)
-                except Exception:  # noqa: BLE001
-                    self._peak = None
+                self._peak = peak_flops_of(jax.devices()[0],
+                                           self.compute_dtype)
         return self._peak
 
     def drain(self, step: int = 0, now: Optional[float] = None

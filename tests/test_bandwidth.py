@@ -594,8 +594,12 @@ class TestBandwidthAcceptance:
                     status = fetch_status(addr, timeout=5.0)
                 except (ConnectionError, OSError):
                     status = None
-                if status and (status.get("wire") or {}).get(
-                        "bytes_per_transition", 0) > 0:
+                wire = (status or {}).get("wire") or {}
+                # the byte-ledger verdict only exists once a client has
+                # reported its counters (tick cadence) — bytes can flow
+                # a moment before that, so wait for both
+                if wire.get("bytes_per_transition", 0) > 0 \
+                        and "bytes_balanced" in wire.get("ledger", {}):
                     break
                 time.sleep(0.25)
             assert status is not None and "wire" in status, \

@@ -1,7 +1,7 @@
 """Pallas hierarchical PER sampler: interpret-mode equivalence against the
 flat XLA scheme, distribution correctness, and the device_per plug-in hook.
 On CPU the kernel runs in interpret mode; the real-TPU path compiles the
-same kernel (validated on hardware; see ops/pallas_sampling.py)."""
+same kernel (checked on the chip by tools/kernel_check.py)."""
 
 import numpy as np
 import pytest

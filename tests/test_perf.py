@@ -115,6 +115,14 @@ class TestFlopsCapture:
 
         assert perf.peak_flops_of(_Cpu()) is None
 
+        class _NewTpu:
+            platform = "tpu"
+            device_kind = "TPU v9 mega"
+
+        # the chip path must know its device: no default, no None
+        with pytest.raises(ValueError, match="TPU v9 mega"):
+            perf.peak_flops_of(_NewTpu())
+
     def test_peak_flops_scales_by_compute_dtype(self):
         """ISSUE-13 satellite: the MFU denominator is dtype-aware — an
         fp32 run scores against the fp32 MXU peak (half the bf16

@@ -6,8 +6,8 @@ Nature-DQN update at the chip-bound asymptote — this probe explains WHY,
 with a real XLA profile rather than an assertion:
 
 1. captures a ``jax.profiler`` trace of the production fused K=32
-   program on the chip and converts it op-by-op with xprof
-   (tensorboard_plugin_profile) to a self-time ranking;
+   program on the chip and converts it op-by-op with xprof to a
+   self-time ranking;
 2. sweeps the levers that would move the number if the bound were
    elsewhere: batch scaling (128 -> 512 at constant FLOP intensity per
    row) and compute dtype (bf16 vs f32);
@@ -121,15 +121,9 @@ def op_breakdown(trace_dir: str, top: int = 12) -> list:
     if not paths:
         return [{"error": "no xplane.pb captured"}]
     path = max(paths, key=os.path.getmtime)
-    # xprof is the maintained layout; the legacy tensorboard_plugin_profile
-    # ships stale protobuf gencode that explodes on protobuf>=4 unless the
-    # pure-python parser is forced
     os.environ.setdefault("PROTOCOL_BUFFERS_PYTHON_IMPLEMENTATION",
                           "python")
-    try:
-        from xprof.convert import raw_to_tool_data
-    except ImportError:
-        from tensorboard_plugin_profile.convert import raw_to_tool_data
+    from xprof.convert import raw_to_tool_data
     data, _ = raw_to_tool_data.xspace_to_tool_data([path], "hlo_stats", {})
     if isinstance(data, bytes):
         data = data.decode()
@@ -217,7 +211,10 @@ def main() -> None:
     dev = jax.devices()[0]
     from pytorch_distributed_tpu.utils.perf import peak_flops_of
 
-    peak = peak_flops_of(dev) or float("nan")
+    peak = peak_flops_of(dev)
+    if peak is None:
+        sys.exit(f"[mfu_probe] needs a TPU; default backend is "
+                 f"{dev.platform!r} ({dev.device_kind!r})")
     out = {"device_kind": getattr(dev, "device_kind", "?")}
 
     # production point: B=128, K=32, bf16
