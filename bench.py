@@ -295,46 +295,21 @@ def bench_micro() -> dict:
         peak = _peak_flops(jax.devices()[0])
         out["mfu"] = round(achieved / peak, 4) if peak else None
         out["mfu_peak"] = round(achieved_pk / peak, 4) if peak else None
-        # What binds the MFU: preferably the MACHINE-READABLE attribution
-        # from the latest ``tools/mfu_probe.py --json --out
-        # MFU_PROBE.json`` run on this class of hardware (re-tiling
-        # share + per-category self-time bins off a real XLA trace);
-        # falls back to the checked-in r03 finding when no probe
-        # artifact exists (CPU CI hosts can't trace a TPU).
+        # what bound the MFU in r03 (a note, not a measurement of this run)
         out["mfu_bound"] = _mfu_bound_note()
     return out
 
 
 def _mfu_bound_note() -> str:
-    """Compose the micro section's ``mfu_bound`` string from the
-    ``attribution`` block of an ``MFU_PROBE.json`` artifact at the repo
-    root (written by ``tools/mfu_probe.py --json --out MFU_PROBE.json``)
-    when one exists — the bench quotes the probe's measured numbers
-    instead of a hand-copied string that can drift."""
-    path = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                        "MFU_PROBE.json")
-    try:
-        with open(path) as f:
-            probe = json.load(f)
-        att = probe["attribution"]
-        bins = att.get("bins", {})
-        top = sorted(bins.items(), key=lambda kv: -kv[1])[:3]
-        bins_s = ", ".join(f"{k} {v:.0%}" for k, v in top)
-        # measured attribution ONLY — no qualitative diagnosis spliced
-        # in (a probe taken after the Pallas torso / wide family lands
-        # may show no lane underfill at all; the conclusion belongs to
-        # whoever reads the bins, not to a string frozen at r03)
-        return (f"re-tiling share {att['retiling_share']:.0%} of device "
-                f"self time; top self-time bins: {bins_s} "
-                f"(mfu_probe.py on {probe.get('device_kind', '?')})")
-    except (OSError, KeyError, ValueError, TypeError):
-        # the r03 trace finding (2026-07-31, v5 lite): batch- and
-        # dtype-invariant, channels-last A/B'd slower — the structural
-        # lane underfill plus XLA's own re-tiling
-        return ("narrow conv channels (4/32/64) underfill the 128-lane "
-                "MXU; batch- and dtype-invariant, channels-last A/B'd "
-                "slower; ~25% of device time is XLA's own re-tiling "
-                "(mfu_probe.py)")
+    """The micro section's ``mfu_bound`` string: the r03 trace finding
+    (2026-07-31, v5 lite; batch- and dtype-invariant, channels-last A/B'd
+    slower), taken over a link that no longer exists.  What bounds the
+    learner on the directly attached chip is in PERF.md, per program
+    phase, from a traced run of a benchmark cell."""
+    return ("narrow conv channels (4/32/64) underfill the 128-lane "
+            "MXU; batch- and dtype-invariant, channels-last A/B'd "
+            "slower; ~25% of device time is XLA's own re-tiling "
+            "(r03; see PERF.md for the directly attached chip)")
 
 
 FAMILY_DISPATCH = 8  # steps per dispatched program in the family rows

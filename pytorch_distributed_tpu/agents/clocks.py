@@ -24,6 +24,14 @@ class GlobalClock:
     def __init__(self):
         self.actor_step = _CTX.Value("l", 0, lock=True)
         self.learner_step = _CTX.Value("l", 0, lock=True)
+        # updates whose dispatch has COMPLETED on the device, written by
+        # ``run_learner``.  ``learner_step`` counts at enqueue and leads
+        # the device by the dispatches in flight (seconds, for R2D2's
+        # half-second dispatches); this one never leads ``learner_step``
+        # and reaches it once the last dispatch is done.  Read this for a
+        # rate over a window.  (The Anakin and replica drivers do not keep
+        # it: they count at enqueue only.)
+        self.learner_done = _CTX.Value("l", 0, lock=True)
         # Best evaluator reward so far — shared so (a) the learner can bind
         # it into every checkpoint epoch (utils/checkpoint.py save_epoch
         # extras) and (b) a resumed run's evaluator can't clobber
@@ -76,6 +84,10 @@ class GlobalClock:
     def set_learner_step(self, value: int) -> None:
         with self.learner_step.get_lock():
             self.learner_step.value = value
+
+    def set_learner_done(self, value: int) -> None:
+        with self.learner_done.get_lock():
+            self.learner_done.value = value
 
     def done(self, steps: int) -> bool:
         """Termination predicate shared by every worker loop

@@ -24,6 +24,7 @@ update.
 
 from __future__ import annotations
 
+import collections
 import time
 from typing import Any
 
@@ -545,6 +546,12 @@ def run_learner(opt: Options, spec: EnvSpec, process_ind: int, memory: Any,
     last_metrics = None
     t_cadence = time.monotonic()
     last_stats_lstep = lstep
+    # host phases of the loop (README "Observability"): pace, drain, keys,
+    # dispatch, sample, priorities, publish, checkpoint, stats; every one
+    # is also a ``learner/<phase>`` span in a profiler trace.  ``step`` is
+    # not a stretch of the host's time and has no span: it runs from a
+    # dispatch's enqueue to its COMPLETION (``_reap`` below), the same
+    # reading the ``learn`` trace span records.
     timer = StepTimer("learner")
     # per-phase timings go straight to the run's JSONL stream (appends are
     # atomic line writes; the logger process keeps the aggregated scalars)
@@ -561,6 +568,32 @@ def run_learner(opt: Options, spec: EnvSpec, process_ind: int, memory: Any,
         for t in (tracer, tracing.get_tracer("feeder"),
                   tracing.get_tracer("gateway")):
             t.flush_to(timing_writer, step=step)
+
+    # dispatches enqueued and not yet seen complete, oldest first: (one
+    # of the dispatch's metric refs, perf_counter at enqueue, updates it
+    # holds, trace id).  ``clock.learner_step`` counts at enqueue;
+    # ``clock.learner_done`` counts here, so it never leads and reaches
+    # ``learner_step`` once the last dispatch is done.
+    in_flight: collections.deque = collections.deque()
+    ldone = lstep
+    clock.set_learner_done(ldone)
+
+    def _reap() -> None:
+        """Book every dispatch that has completed since the last loop turn:
+        one non-blocking ``is_ready()`` per turn and per completion, no
+        thread and no wait, so the runtime alone bounds what is in flight.
+        The interval is read when the loop comes by, one turn late at
+        most."""
+        nonlocal ldone
+        seen = ldone
+        while in_flight and in_flight[0][0].is_ready():
+            _ref, t_enqueue, updates, trace_id = in_flight.popleft()
+            seconds = time.perf_counter() - t_enqueue
+            timer.add("step", seconds)
+            tracer.record("learn", seconds * 1e3, trace_id=trace_id)
+            ldone += updates
+        if ldone != seen:
+            clock.set_learner_done(ldone)
 
     def _save_epoch() -> None:
         """One coordinated checkpoint epoch: train state + replay +
@@ -622,7 +655,7 @@ def run_learner(opt: Options, spec: EnvSpec, process_ind: int, memory: Any,
         already-diverged params), and every committed epoch newer than
         the target is fenced with a ROLLED_BACK marker so neither this
         run nor a later --resume can step back onto it."""
-        nonlocal state, lstep, lstep0, device_key, key_buf
+        nonlocal state, lstep, lstep0, device_key, key_buf, ldone
         if _rb["used"] >= hp.max_rollbacks:
             _fatal_divergence(
                 f"divergence persists after {_rb['used']} rollback(s) "
@@ -653,6 +686,9 @@ def run_learner(opt: Options, spec: EnvSpec, process_ind: int, memory: Any,
                 device_key = ckpt.deserialize_prng_key(saved, device_key)
             key_buf.clear()  # pre-split keys belong to the abandoned tail
         clock.set_learner_step(lstep)
+        in_flight.clear()  # the abandoned tail completes unobserved
+        ldone = lstep
+        clock.set_learner_done(ldone)
         with clock.rollbacks.get_lock():
             clock.rollbacks.value += 1
         _rb["used"] += 1
@@ -669,6 +705,16 @@ def run_learner(opt: Options, spec: EnvSpec, process_ind: int, memory: Any,
               f"{hp.max_rollbacks - _rb['used']} rollback(s) left",
               flush=True)
 
+    def _throttled() -> bool:
+        """Is the learner ahead of ``max_replay_ratio`` samples per
+        collected transition?  Baselined on THIS run's steps (lstep -
+        lstep0): a resumed checkpoint's cumulative count against a fresh
+        actor clock would stall the learner for hours."""
+        return (not clock.stop.is_set()
+                and time.monotonic() < deadline
+                and (lstep - lstep0 + 1) * ap.batch_size
+                > ap.max_replay_ratio * max(clock.actor_step.value, 1))
+
     # anchor the first rate window at loop entry (not process start:
     # warmup compiles must not dilute it); the anchor drain carries the
     # one-time flops_per_update row + startup watermarks, so write it
@@ -679,23 +725,19 @@ def run_learner(opt: Options, spec: EnvSpec, process_ind: int, memory: Any,
         clock.bump_progress("learner")
         for _action, _arg in _linj.data_frame(("poison_grad",)):
             _poison[0] = True
-        if ap.max_replay_ratio > 0:
+        if ap.max_replay_ratio > 0 and _throttled():
             # pacing gate: don't draw more than max_replay_ratio samples
             # per collected transition (config.py AgentParams docstring).
-            # Baselined on THIS run's steps (lstep - lstep0): a resumed
-            # checkpoint's cumulative count against a fresh actor clock
-            # would stall the learner for hours.  Queue-backed memories
-            # keep draining while throttled — a full ingest queue blocks
-            # actors before they can advance the clock (deadlock).
-            while (not clock.stop.is_set()
-                   and time.monotonic() < deadline
-                   and (lstep - lstep0 + 1) * ap.batch_size
-                   > ap.max_replay_ratio * max(clock.actor_step.value, 1)):
-                if hasattr(memory, "drain"):
-                    memory.drain()
-                # pacing throttle = flow control, not a hang
-                clock.bump_progress("learner")
-                time.sleep(0.002)
+            # Queue-backed memories keep draining while throttled — a
+            # full ingest queue blocks actors before they can advance the
+            # clock (deadlock).
+            with timer.phase("pace"):
+                while _throttled():
+                    if hasattr(memory, "drain"):
+                        memory.drain()
+                    # pacing throttle = flow control, not a hang
+                    clock.bump_progress("learner")
+                    time.sleep(0.002)
             if clock.stop.is_set():
                 break
         if on_device:
@@ -711,18 +753,19 @@ def run_learner(opt: Options, spec: EnvSpec, process_ind: int, memory: Any,
                 # one split dispatch amortised over 64 dispatches
                 # instead of one tiny program per step; beta (PER)
                 # anneals slowly and refreshes on the same cadence
-                keys = jax.random.split(device_key, 64 * K + 1)
-                device_key = keys[0]
-                rest = keys[1:]
-                # typed PRNG keys are (n,)-shaped, raw keys (n, 2) —
-                # group into 64 dispatches of K either way
-                key_buf = (list(rest.reshape(64, K, *rest.shape[1:]))
-                           if K > 1 else list(rest))
-                if is_device_per:
-                    beta_dev = jax.device_put(
-                        np.float32(replay.beta(lstep)))
-            with timer.phase("step"), \
-                    tracer.span("learn", trace_id=tracing.current_trace()):
+                with timer.phase("keys"):
+                    keys = jax.random.split(device_key, 64 * K + 1)
+                    device_key = keys[0]
+                    rest = keys[1:]
+                    # typed PRNG keys are (n,)-shaped, raw keys (n, 2) —
+                    # group into 64 dispatches of K either way
+                    key_buf = (list(rest.reshape(64, K, *rest.shape[1:]))
+                               if K > 1 else list(rest))
+                    if is_device_per:
+                        beta_dev = jax.device_put(
+                            np.float32(replay.beta(lstep)))
+            t_enqueue = time.perf_counter()
+            with timer.phase("dispatch"):
                 metrics = device_step(key_buf.pop())
                 if block_each_step:
                     jax.block_until_ready(state.params)
@@ -730,10 +773,10 @@ def run_learner(opt: Options, spec: EnvSpec, process_ind: int, memory: Any,
             if is_per:
                 with timer.phase("drain"):
                     memory.drain()
-            with timer.phase("sample"), \
-                    tracer.span("sample",
-                                trace_id=tracing.current_trace()):
+            with timer.phase("sample"):
                 batch = memory.sample(ap.batch_size, rng)
+            tracer.record("sample", timer.last_s * 1e3,
+                          trace_id=tracing.current_trace())
             _last_idx[0] = np.asarray(batch.index)
             if _poison[0]:
                 # poison_grad drill: a non-finite loss injected into
@@ -744,8 +787,8 @@ def run_learner(opt: Options, spec: EnvSpec, process_ind: int, memory: Any,
                     np.asarray(batch.reward), np.nan))
                 print("[faults:learner] poison_grad: NaN rewards "
                       "injected into this update's batch", flush=True)
-            with timer.phase("step"), \
-                    tracer.span("learn", trace_id=tracing.current_trace()):
+            t_enqueue = time.perf_counter()
+            with timer.phase("dispatch"):
                 state, metrics, td_abs = learner.step(state, batch)
             skipped_now = 0.0
             if is_per and isinstance(metrics, dict) \
@@ -776,6 +819,9 @@ def run_learner(opt: Options, spec: EnvSpec, process_ind: int, memory: Any,
         clock.set_learner_step(lstep)  # reference dqn_learner.py:94-95
         perf_mon.note_updates(stride)  # one int add; no-op when disabled
         last_metrics = metrics
+        in_flight.append((jax.tree_util.tree_leaves(metrics)[0], t_enqueue,
+                          stride, tracing.current_trace()))
+        _reap()
 
         # cadences fire on boundary crossings so a multi-step dispatch
         # (stride > 1) never skips them
@@ -784,164 +830,173 @@ def run_learner(opt: Options, spec: EnvSpec, process_ind: int, memory: Any,
             with timer.phase("publish"):
                 _publish_async(state)
         if crossed(ap.checkpoint_freq):
-            _save_epoch()
+            with timer.phase("checkpoint"):
+                _save_epoch()
 
         if crossed(ap.learner_freq):  # reference dqn_learner.py:99-101
-            now = time.monotonic()
-            # sampled (not averaged) losses: the window's last step stands
-            # in for the window, one host fetch total
-            vals = {k: float(v)
-                    for k, v in jax.device_get(last_metrics).items()}
-            stats.add(
-                counter=1,
-                critic_loss=vals.get("learner/critic_loss", 0.0),
-                actor_loss=vals.get("learner/actor_loss", 0.0),
-                q_mean=vals.get("learner/q_mean", 0.0),
-                grad_norm=vals.get("learner/grad_norm", 0.0),
-                moe_aux=vals.get("learner/moe_aux", 0.0),
-                steps_per_sec=(lstep - last_stats_lstep)
-                / max(now - t_cadence, 1e-9),
-            )
-            # ---- sentinel window: guard skips + rolling anomalies ----
-            # host PER counted every step (_win_skips); other paths read
-            # the sampled flag of the window's last step/dispatch (the
-            # fused path's flag already sums over its K substeps,
-            # utils/health.reduce_scan_metrics)
-            skipped_w = float(_win_skips[0]) or vals.get(
-                health.SKIPPED_KEY, 0.0)
-            _win_skips[0] = 0
-            if skipped_w:
-                clock.add_skipped_steps(int(round(skipped_w)))
-            # ---- data-plane X-ray (ISSUE 8): provenance of what the
-            # learner is actually consuming + the PER priority
-            # distribution, exported on this cadence and fed to the
-            # detector.  Host paths read their sidecars directly; the
-            # device paths pay ONE bounded D2H each (a 256-row
-            # provenance gather / the in-jit bucket histogram).
-            prov = None
-            prov_fn = getattr(memory, "provenance_of", None)
-            if prov_fn is not None and _last_idx[0] is not None:
-                prov = prov_fn(_last_idx[0])
-                prov = None if prov is None else np.asarray(prov)
-            elif on_device and _prov_sample is not None:
-                pr_dev, _ = _prov_sample(
-                    replay.state, jax.random.fold_in(_tel_key, lstep),
-                    n=256)
-                prov = np.asarray(pr_dev)
-            cur_version = int(getattr(param_store, "version", 0) or 0)
-            ds = (health.provenance_stats(prov, cur_version, lstep)
-                  if prov is not None else None)
-            if ds is not None:
-                timing_writer.histogram("learner/staleness",
-                                        ds["staleness"].tolist(),
-                                        step=lstep)
-                timing_writer.histogram("learner/sample_age",
-                                        ds["age"].tolist(), step=lstep)
-                timing_writer.histogram("replay/actor_share",
-                                        ds["shares"].tolist(),
-                                        step=lstep)
-                perf_mon.set_gauge("data/staleness_p50",
-                                   float(np.median(ds["staleness"])))
-                perf_mon.set_gauge("data/sample_age_p95",
-                                   float(np.percentile(ds["age"], 95)))
-                perf_mon.set_gauge("data/top_actor_share",
-                                   float(ds["shares"].max()))
-            xray = None
-            # mass/rows kept SEPARATE from the X-ray: an all-zero leaf
-            # set yields xray=None, and the detector must still see
-            # (mass ~0, rows > 0) — the degenerate collapse the signal
-            # was originally built for
-            p_mass, p_rows = None, 0
-            leaves_fn = getattr(memory, "priority_leaves", None)
-            leaves = leaves_fn() if leaves_fn is not None else None
-            if leaves is not None and len(leaves):
-                p_mass = float(np.sum(leaves))
-                p_rows = int(len(leaves))
-                xray = health.priority_xray(leaves)
-            elif on_device and _xray_dev is not None:
-                counts, ess, rows_d, mass = jax.device_get(
-                    _xray_dev(replay.state))
-                rows_d = int(rows_d)
-                p_mass, p_rows = float(mass), rows_d
-                if rows_d:
-                    xray = {"rows": rows_d, "mass": float(mass),
-                            "ess": float(ess),
-                            "ess_frac": float(ess) / rows_d,
-                            "counts": np.asarray(counts),
-                            "log10_lo": health.PRIORITY_XRAY_LOG10_LO,
-                            "log10_hi": health.PRIORITY_XRAY_LOG10_HI}
-            if xray is not None:
-                timing_writer.bucket_histogram(
-                    "replay/priority", xray["counts"],
-                    log10_lo=xray["log10_lo"], log10_hi=xray["log10_hi"],
-                    step=lstep,
-                    extra={"ess": xray["ess"],
-                           "ess_frac": xray["ess_frac"],
-                           "mass": xray["mass"], "rows": xray["rows"]})
+            # the window's bookkeeping, one host fetch included: the
+            # phase's own row lands in the NEXT window's drain
+            with timer.phase("stats"):
+                now = time.monotonic()
+                # sampled (not averaged) losses: the window's last step stands
+                # in for the window, one host fetch total
+                vals = {k: float(v)
+                        for k, v in jax.device_get(last_metrics).items()}
+                stats.add(
+                    counter=1,
+                    critic_loss=vals.get("learner/critic_loss", 0.0),
+                    actor_loss=vals.get("learner/actor_loss", 0.0),
+                    q_mean=vals.get("learner/q_mean", 0.0),
+                    grad_norm=vals.get("learner/grad_norm", 0.0),
+                    moe_aux=vals.get("learner/moe_aux", 0.0),
+                    steps_per_sec=(lstep - last_stats_lstep)
+                    / max(now - t_cadence, 1e-9),
+                )
+                # ---- sentinel window: guard skips + rolling anomalies ----
+                # host PER counted every step (_win_skips); other paths read
+                # the sampled flag of the window's last step/dispatch (the
+                # fused path's flag already sums over its K substeps,
+                # utils/health.reduce_scan_metrics)
+                skipped_w = float(_win_skips[0]) or vals.get(
+                    health.SKIPPED_KEY, 0.0)
+                _win_skips[0] = 0
+                if skipped_w:
+                    clock.add_skipped_steps(int(round(skipped_w)))
+                # ---- data-plane X-ray (ISSUE 8): provenance of what the
+                # learner is actually consuming + the PER priority
+                # distribution, exported on this cadence and fed to the
+                # detector.  Host paths read their sidecars directly; the
+                # device paths pay ONE bounded D2H each (a 256-row
+                # provenance gather / the in-jit bucket histogram).
+                prov = None
+                prov_fn = getattr(memory, "provenance_of", None)
+                if prov_fn is not None and _last_idx[0] is not None:
+                    prov = prov_fn(_last_idx[0])
+                    prov = None if prov is None else np.asarray(prov)
+                elif on_device and _prov_sample is not None:
+                    pr_dev, _ = _prov_sample(
+                        replay.state, jax.random.fold_in(_tel_key, lstep),
+                        n=256)
+                    prov = np.asarray(pr_dev)
+                cur_version = int(getattr(param_store, "version", 0) or 0)
+                ds = (health.provenance_stats(prov, cur_version, lstep)
+                      if prov is not None else None)
+                if ds is not None:
+                    timing_writer.histogram("learner/staleness",
+                                            ds["staleness"].tolist(),
+                                            step=lstep)
+                    timing_writer.histogram("learner/sample_age",
+                                            ds["age"].tolist(), step=lstep)
+                    timing_writer.histogram("replay/actor_share",
+                                            ds["shares"].tolist(),
+                                            step=lstep)
+                    perf_mon.set_gauge("data/staleness_p50",
+                                       float(np.median(ds["staleness"])))
+                    perf_mon.set_gauge("data/sample_age_p95",
+                                       float(np.percentile(ds["age"], 95)))
+                    perf_mon.set_gauge("data/top_actor_share",
+                                       float(ds["shares"].max()))
+                xray = None
+                # mass/rows kept SEPARATE from the X-ray: an all-zero leaf
+                # set yields xray=None, and the detector must still see
+                # (mass ~0, rows > 0) — the degenerate collapse the signal
+                # was originally built for
+                p_mass, p_rows = None, 0
+                leaves_fn = getattr(memory, "priority_leaves", None)
+                leaves = leaves_fn() if leaves_fn is not None else None
+                if leaves is not None and len(leaves):
+                    p_mass = float(np.sum(leaves))
+                    p_rows = int(len(leaves))
+                    xray = health.priority_xray(leaves)
+                elif on_device and _xray_dev is not None:
+                    counts, ess, rows_d, mass = jax.device_get(
+                        _xray_dev(replay.state))
+                    rows_d = int(rows_d)
+                    p_mass, p_rows = float(mass), rows_d
+                    if rows_d:
+                        xray = {"rows": rows_d, "mass": float(mass),
+                                "ess": float(ess),
+                                "ess_frac": float(ess) / rows_d,
+                                "counts": np.asarray(counts),
+                                "log10_lo": health.PRIORITY_XRAY_LOG10_LO,
+                                "log10_hi": health.PRIORITY_XRAY_LOG10_HI}
+                if xray is not None:
+                    timing_writer.bucket_histogram(
+                        "replay/priority", xray["counts"],
+                        log10_lo=xray["log10_lo"], log10_hi=xray["log10_hi"],
+                        step=lstep,
+                        extra={"ess": xray["ess"],
+                               "ess_frac": xray["ess_frac"],
+                               "mass": xray["mass"], "rows": xray["rows"]})
+                    timing_writer.scalars({
+                        "replay/priority_ess": xray["ess"],
+                        "replay/priority_ess_frac": xray["ess_frac"],
+                    }, step=lstep)
+                    perf_mon.set_gauge("data/priority_ess",
+                                       xray["ess_frac"])
+                anomalies = detector.observe(
+                    loss=vals.get("learner/critic_loss"),
+                    grad_norm=vals.get("learner/grad_norm"),
+                    td_mean=_last_td[0],
+                    priority_mass=p_mass,
+                    replay_rows=p_rows,
+                    skipped=skipped_w,
+                    priority_ess=xray["ess_frac"] if xray else None)
+                if anomalies:
+                    recorder.record("anomaly", step=lstep, kinds=anomalies,
+                                    streak=detector.streak)
+                    print(f"[health] anomaly at step {lstep}: "
+                          f"{'+'.join(anomalies)} (streak {detector.streak}"
+                          f"/{hp.anomaly_threshold})", flush=True)
                 timing_writer.scalars({
-                    "replay/priority_ess": xray["ess"],
-                    "replay/priority_ess_frac": xray["ess_frac"],
+                    "health/skipped_steps": float(clock.skipped_steps.value),
+                    "health/rollbacks": float(clock.rollbacks.value),
+                    "health/anomaly_streak": float(detector.streak),
                 }, step=lstep)
-                perf_mon.set_gauge("data/priority_ess",
-                                   xray["ess_frac"])
-            anomalies = detector.observe(
-                loss=vals.get("learner/critic_loss"),
-                grad_norm=vals.get("learner/grad_norm"),
-                td_mean=_last_td[0],
-                priority_mass=p_mass,
-                replay_rows=p_rows,
-                skipped=skipped_w,
-                priority_ess=xray["ess_frac"] if xray else None)
-            if anomalies:
-                recorder.record("anomaly", step=lstep, kinds=anomalies,
-                                streak=detector.streak)
-                print(f"[health] anomaly at step {lstep}: "
-                      f"{'+'.join(anomalies)} (streak {detector.streak}"
-                      f"/{hp.anomaly_threshold})", flush=True)
-            timing_writer.scalars({
-                "health/skipped_steps": float(clock.skipped_steps.value),
-                "health/rollbacks": float(clock.rollbacks.value),
-                "health/anomaly_streak": float(detector.streak),
-            }, step=lstep)
-            if hp.rollback and detector.should_rollback():
-                _rollback("+".join(anomalies) if anomalies
-                          else "anomaly streak")
-            if perf_mon.enabled:
-                # throughput-attribution gauges the monitor can't see
-                # from inside: replay ratio on THIS run's steps (the
-                # pacing gate's own accounting) and how full the ingest
-                # queue is (1.0 = actors blocked on backpressure)
-                perf_mon.set_gauge(
-                    "learner/replay_ratio",
-                    (lstep - lstep0) * ap.batch_size
-                    / max(int(clock.actor_step.value), 1))
-                _q = getattr(memory, "_q", None)
-                if _q is not None and hasattr(_q, "qsize"):
-                    try:
-                        depth = int(_q.qsize())
-                        bound = int(getattr(memory, "max_queue_chunks",
-                                            0))
-                        perf_mon.set_gauge("learner/ingest_queue_depth",
-                                           depth)
-                        if bound:
-                            perf_mon.set_gauge(
-                                "learner/ingest_queue_util",
-                                depth / bound)
-                    except (NotImplementedError, OSError):
-                        pass  # macOS mp queues have no qsize
-                timing_writer.scalars(perf_mon.drain(step=lstep),
-                                      step=lstep)
-            # bandwidth X-ray (ISSUE 18): the headline wire/replay/ckpt
-            # series on the same stats cadence — wire/<link>/bytes_per_s
-            # rates come from deltas against the previous emit
-            wire_series = bandwidth.emit_scalars()
-            if wire_series:
-                timing_writer.scalars(wire_series, step=lstep)
-            timing_writer.scalars(timer.drain(), step=lstep)
-            _flush_traces(lstep)
-            t_cadence = now
-            last_stats_lstep = lstep
+                if hp.rollback and detector.should_rollback():
+                    _rollback("+".join(anomalies) if anomalies
+                              else "anomaly streak")
+                if perf_mon.enabled:
+                    # throughput-attribution gauges the monitor can't see
+                    # from inside: replay ratio on THIS run's steps (the
+                    # pacing gate's own accounting) and how full the ingest
+                    # queue is (1.0 = actors blocked on backpressure)
+                    perf_mon.set_gauge(
+                        "learner/replay_ratio",
+                        (lstep - lstep0) * ap.batch_size
+                        / max(int(clock.actor_step.value), 1))
+                    _q = getattr(memory, "_q", None)
+                    if _q is not None and hasattr(_q, "qsize"):
+                        try:
+                            depth = int(_q.qsize())
+                            bound = int(getattr(memory, "max_queue_chunks",
+                                                0))
+                            perf_mon.set_gauge("learner/ingest_queue_depth",
+                                               depth)
+                            if bound:
+                                perf_mon.set_gauge(
+                                    "learner/ingest_queue_util",
+                                    depth / bound)
+                        except (NotImplementedError, OSError):
+                            pass  # macOS mp queues have no qsize
+                    timing_writer.scalars(perf_mon.drain(step=lstep),
+                                          step=lstep)
+                # bandwidth X-ray (ISSUE 18): the headline wire/replay/ckpt
+                # series on the same stats cadence — wire/<link>/bytes_per_s
+                # rates come from deltas against the previous emit
+                wire_series = bandwidth.emit_scalars()
+                if wire_series:
+                    timing_writer.scalars(wire_series, step=lstep)
+                timing_writer.scalars(timer.drain(), step=lstep)
+                _flush_traces(lstep)
+                t_cadence = now
+                last_stats_lstep = lstep
 
+    # nothing is enqueued any more: wait for the tail, so that
+    # ``learner_done`` reaches ``learner_step`` (the final publication
+    # below would wait for it anyway)
+    jax.block_until_ready([entry[0] for entry in in_flight])
+    _reap()
     # final publication + final checkpoint epoch so a next run can resume
     # — this is also the preemption path: a SIGTERM (runtime.py) trips
     # clock.stop, the loop above drains out, and the run's last complete
@@ -959,7 +1014,9 @@ def run_learner(opt: Options, spec: EnvSpec, process_ind: int, memory: Any,
     if perf_mon.enabled:
         # final partial window: short runs must still export their rates
         timing_writer.scalars(perf_mon.drain(step=lstep), step=lstep)
-    _flush_traces(lstep)  # tail spans of the final partial window
+    # tail phases and spans of the final partial window
+    timing_writer.scalars(timer.drain(), step=lstep)
+    _flush_traces(lstep)
     timing_writer.close()
 
 

@@ -27,10 +27,13 @@ import jax.numpy as jnp
 import numpy as np
 
 from pytorch_distributed_tpu.memory.device_replay import (
-    DeviceReplay, ring_write, ring_write_masked, round_capacity,
+    DeviceReplay, jit_feed, ring_write, ring_write_masked, round_capacity,
 )
 from pytorch_distributed_tpu.utils.experience import (
     REPLAY_FIELDS, Batch, Transition,
+)
+from pytorch_distributed_tpu.utils.profiling import (
+    PHASE_DRAW, PHASE_FEED, PHASE_GATHER, PHASE_WRITEBACK,
 )
 
 # single-owner declaration (apexlint): the masked PER scatter may only
@@ -61,7 +64,9 @@ def per_feed(state: PerReplayState, chunk: Transition,
     """Ingest a chunk at the cursor (shared ring write, device_replay.py
     ring_write); new rows take the running max priority."""
     new, idx = ring_write(state, chunk, capacity)
-    return new._replace(priority=new.priority.at[idx].set(new.max_priority))
+    with jax.named_scope(PHASE_FEED):
+        return new._replace(
+            priority=new.priority.at[idx].set(new.max_priority))
 
 
 def per_write_masked(state: PerReplayState, chunk: Transition, valid,
@@ -74,13 +79,14 @@ def per_write_masked(state: PerReplayState, chunk: Transition, valid,
     the split-process drain produce bit-identical PER rings.  Returns
     ``(state', n_written)``."""
     new, total = ring_write_masked(state, chunk, valid, capacity)
-    # same drop-indexing as the field scatter: invalid rows point at
-    # ``capacity`` (out of bounds) and are dropped branch-free
-    offs = jnp.cumsum(valid.astype(jnp.int32)) - 1
-    idx = jnp.where(valid, (state.pos + offs) % capacity, capacity)
-    return new._replace(
-        priority=new.priority.at[idx].set(new.max_priority,
-                                          mode="drop")), total
+    with jax.named_scope(PHASE_FEED):
+        # same drop-indexing as the field scatter: invalid rows point at
+        # ``capacity`` (out of bounds) and are dropped branch-free
+        offs = jnp.cumsum(valid.astype(jnp.int32)) - 1
+        idx = jnp.where(valid, (state.pos + offs) % capacity, capacity)
+        return new._replace(
+            priority=new.priority.at[idx].set(new.max_priority,
+                                              mode="drop")), total
 
 
 def per_sample(state: PerReplayState, key: jax.Array, batch_size: int,
@@ -91,33 +97,37 @@ def per_sample(state: PerReplayState, key: jax.Array, batch_size: int,
     index draw — the hook the Pallas hierarchical sampler
     (ops/pallas_sampling.py) plugs into on unsharded TPU rings; None keeps
     the flat cumsum+searchsorted XLA scheme."""
-    p = state.priority  # empty rows hold 0 and can never be drawn
-    if sample_fn is not None:
-        idx, probs = sample_fn(p, key, batch_size)
-        total = jnp.sum(p)
-    else:
-        cdf = jnp.cumsum(p)
-        total = cdf[-1]  # one O(N) pass serves both u-scaling and probs
-        u = jax.random.uniform(key, (batch_size,)) * total
-        idx = jnp.clip(jnp.searchsorted(cdf, u, side="right"),
-                       0, state.priority.shape[0] - 1).astype(jnp.int32)
-        probs = p[idx] / jnp.maximum(total, 1e-12)
-    fill = jnp.maximum(state.fill.astype(jnp.float32), 1.0)
-    weights = (fill * jnp.maximum(probs, 1e-12)) ** (-beta)
-    # max weight = weight of the min-probability VALID row
-    min_p = jnp.min(jnp.where(p > 0, p, jnp.inf)) / jnp.maximum(total, 1e-12)
-    max_w = (fill * jnp.maximum(min_p, 1e-12)) ** (-beta)
-    weights = weights / jnp.maximum(max_w, 1e-12)
-    return Batch(
-        state0=state.state0[idx],
-        action=state.action[idx],
-        reward=state.reward[idx],
-        gamma_n=state.gamma_n[idx],
-        state1=state.state1[idx],
-        terminal1=state.terminal1[idx],
-        weight=weights.astype(jnp.float32),
-        index=idx,
-    )
+    with jax.named_scope(PHASE_DRAW):
+        p = state.priority  # empty rows hold 0 and can never be drawn
+        if sample_fn is not None:
+            idx, probs = sample_fn(p, key, batch_size)
+            total = jnp.sum(p)
+        else:
+            cdf = jnp.cumsum(p)
+            total = cdf[-1]  # one O(N) pass serves u-scaling and probs
+            u = jax.random.uniform(key, (batch_size,)) * total
+            idx = jnp.clip(jnp.searchsorted(cdf, u, side="right"),
+                           0, state.priority.shape[0] - 1
+                           ).astype(jnp.int32)
+            probs = p[idx] / jnp.maximum(total, 1e-12)
+        fill = jnp.maximum(state.fill.astype(jnp.float32), 1.0)
+        weights = (fill * jnp.maximum(probs, 1e-12)) ** (-beta)
+        # max weight = weight of the min-probability VALID row
+        min_p = (jnp.min(jnp.where(p > 0, p, jnp.inf))
+                 / jnp.maximum(total, 1e-12))
+        max_w = (fill * jnp.maximum(min_p, 1e-12)) ** (-beta)
+        weights = weights / jnp.maximum(max_w, 1e-12)
+    with jax.named_scope(PHASE_GATHER):
+        return Batch(
+            state0=state.state0[idx],
+            action=state.action[idx],
+            reward=state.reward[idx],
+            gamma_n=state.gamma_n[idx],
+            state1=state.state1[idx],
+            terminal1=state.terminal1[idx],
+            weight=weights.astype(jnp.float32),
+            index=idx,
+        )
 
 
 PRIORITY_XRAY_LOG10_LO = -6.0   # log10 bucket floor (p^alpha units)
@@ -234,14 +244,9 @@ class DevicePerReplay(DeviceReplay):
             self._draw_fn = hierarchical_sample
             self.sampler = "pallas"
 
-        feed = functools.partial(per_feed, capacity=self.capacity)
-        if self.channels_last:
-            from pytorch_distributed_tpu.memory.device_replay import (
-                wrap_feed_nhwc,
-            )
-
-            feed = wrap_feed_nhwc(feed)
-        self._feed_fn = jax.jit(feed, donate_argnums=0)
+        self._feed_fn = jit_feed(
+            functools.partial(per_feed, capacity=self.capacity),
+            self.channels_last)
         self._sample_fn = jax.jit(
             functools.partial(per_sample, sample_fn=self._draw_fn),
             static_argnames="batch_size")
@@ -298,9 +303,13 @@ class DevicePerReplay(DeviceReplay):
             groups = steps_per_call // megabatch
 
             def one_group(ts, rs: PerReplayState, kset, beta):
-                batches = jax.vmap(
-                    lambda k: per_sample(rs, k, batch_size, beta,
-                                         sample_fn=draw_fn))(kset)
+                # the batching of the M draws moves gathered rows
+                # (vmap's own transposes): gather's, where no inner
+                # name says draw
+                with jax.named_scope(PHASE_GATHER):
+                    batches = jax.vmap(
+                        lambda k: per_sample(rs, k, batch_size, beta,
+                                             sample_fn=draw_fn))(kset)
                 ts, metrics, td_abs, ok = megabatch_step(ts, batches)
 
                 def writeback(rs_c, x):
@@ -311,12 +320,15 @@ class DevicePerReplay(DeviceReplay):
                     return suppress_writeback(1.0 - ok_i, rs_new,
                                               rs_c), None
 
-                rs, _ = jax.lax.scan(writeback, rs,
-                                     (batches.index, td_abs, ok))
+                with jax.named_scope(PHASE_WRITEBACK):
+                    rs, _ = jax.lax.scan(writeback, rs,
+                                         (batches.index, td_abs, ok))
                 return ts, rs, metrics
 
             def multi_mega(ts, rs, keys, beta):
-                gkeys = keys.reshape(groups, megabatch, *keys.shape[1:])
+                with jax.named_scope(PHASE_DRAW):
+                    gkeys = keys.reshape(groups, megabatch,
+                                         *keys.shape[1:])
 
                 def body(carry, kset):
                     ts, rs = carry
@@ -332,13 +344,15 @@ class DevicePerReplay(DeviceReplay):
         def one(ts, rs: PerReplayState, key, beta):
             batch = per_sample(rs, key, batch_size, beta, sample_fn=draw_fn)
             ts, metrics, td_abs = train_step(ts, batch)
-            rs_new = per_update_priorities(rs, batch.index, td_abs, alpha)
-            skipped = (metrics.get(SKIPPED_KEY)
-                       if isinstance(metrics, dict) else None)
-            if skipped is not None:
-                # guarded step: a skipped (non-finite) substep must not
-                # scatter its zeroed TD over real priorities either
-                rs_new = suppress_writeback(skipped, rs_new, rs)
+            with jax.named_scope(PHASE_WRITEBACK):
+                rs_new = per_update_priorities(rs, batch.index, td_abs,
+                                               alpha)
+                skipped = (metrics.get(SKIPPED_KEY)
+                           if isinstance(metrics, dict) else None)
+                if skipped is not None:
+                    # guarded step: a skipped (non-finite) substep must
+                    # not scatter its zeroed TD over real priorities
+                    rs_new = suppress_writeback(skipped, rs_new, rs)
             return ts, rs_new, metrics
 
         if steps_per_call <= 1:

@@ -29,6 +29,9 @@ from pytorch_distributed_tpu.utils import bandwidth, experience
 from pytorch_distributed_tpu.utils.experience import (
     REPLAY_FIELDS, Batch, Transition,
 )
+from pytorch_distributed_tpu.utils.profiling import (
+    PHASE_DRAW, PHASE_FEED, PHASE_GATHER,
+)
 
 
 class ReplayState(NamedTuple):
@@ -64,24 +67,26 @@ def ring_write(state, chunk: Transition, capacity: int):
     Returns (state', idx) so extended schemas can set their extra
     per-row fields at the same slots."""
     n = chunk.reward.shape[0]
-    idx = (state.pos + jnp.arange(n, dtype=jnp.int32)) % capacity
-    repl = dict(
-        state0=state.state0.at[idx].set(chunk.state0),
-        action=state.action.at[idx].set(chunk.action),
-        reward=state.reward.at[idx].set(chunk.reward),
-        gamma_n=state.gamma_n.at[idx].set(chunk.gamma_n),
-        state1=state.state1.at[idx].set(chunk.state1),
-        terminal1=state.terminal1.at[idx].set(chunk.terminal1),
-        pos=(state.pos + n) % capacity,
-        fill=jnp.minimum(state.fill + n, capacity),
-    )
-    prov_col = getattr(state, "prov", None)
-    if prov_col is not None:
-        # rows without provenance overwrite with the -1 sentinel (a
-        # recycled slot must never keep its previous row's provenance)
-        repl["prov"] = prov_col.at[idx].set(
-            jnp.full((n, prov_col.shape[1]), -1, prov_col.dtype)
-            if chunk.prov is None else chunk.prov.astype(prov_col.dtype))
+    with jax.named_scope(PHASE_FEED):
+        idx = (state.pos + jnp.arange(n, dtype=jnp.int32)) % capacity
+        repl = dict(
+            state0=state.state0.at[idx].set(chunk.state0),
+            action=state.action.at[idx].set(chunk.action),
+            reward=state.reward.at[idx].set(chunk.reward),
+            gamma_n=state.gamma_n.at[idx].set(chunk.gamma_n),
+            state1=state.state1.at[idx].set(chunk.state1),
+            terminal1=state.terminal1.at[idx].set(chunk.terminal1),
+            pos=(state.pos + n) % capacity,
+            fill=jnp.minimum(state.fill + n, capacity),
+        )
+        prov_col = getattr(state, "prov", None)
+        if prov_col is not None:
+            # rows without provenance overwrite with the -1 sentinel (a
+            # recycled slot must never keep its previous row's provenance)
+            repl["prov"] = prov_col.at[idx].set(
+                jnp.full((n, prov_col.shape[1]), -1, prov_col.dtype)
+                if chunk.prov is None
+                else chunk.prov.astype(prov_col.dtype))
     return state._replace(**repl), idx
 
 
@@ -102,26 +107,28 @@ def ring_write_masked(state, chunk: Transition, valid,
     valid rows); invalid rows are pointed at index ``capacity`` —
     out of bounds — and dropped by the scatter (``mode="drop"``), which
     XLA resolves with no branch.  Returns ``(state', n_written)``."""
-    offs = jnp.cumsum(valid.astype(jnp.int32)) - 1
-    idx = jnp.where(valid, (state.pos + offs) % capacity, capacity)
-    total = jnp.sum(valid.astype(jnp.int32))
-    wr = lambda buf, x: buf.at[idx].set(x, mode="drop")
-    repl = dict(
-        state0=wr(state.state0, chunk.state0),
-        action=wr(state.action, chunk.action),
-        reward=wr(state.reward, chunk.reward),
-        gamma_n=wr(state.gamma_n, chunk.gamma_n),
-        state1=wr(state.state1, chunk.state1),
-        terminal1=wr(state.terminal1, chunk.terminal1),
-        pos=(state.pos + total) % capacity,
-        fill=jnp.minimum(state.fill + total, capacity),
-    )
-    prov_col = getattr(state, "prov", None)
-    if prov_col is not None:
-        n = chunk.reward.shape[0]
-        repl["prov"] = wr(prov_col, (
-            jnp.full((n, prov_col.shape[1]), -1, prov_col.dtype)
-            if chunk.prov is None else chunk.prov.astype(prov_col.dtype)))
+    with jax.named_scope(PHASE_FEED):
+        offs = jnp.cumsum(valid.astype(jnp.int32)) - 1
+        idx = jnp.where(valid, (state.pos + offs) % capacity, capacity)
+        total = jnp.sum(valid.astype(jnp.int32))
+        wr = lambda buf, x: buf.at[idx].set(x, mode="drop")
+        repl = dict(
+            state0=wr(state.state0, chunk.state0),
+            action=wr(state.action, chunk.action),
+            reward=wr(state.reward, chunk.reward),
+            gamma_n=wr(state.gamma_n, chunk.gamma_n),
+            state1=wr(state.state1, chunk.state1),
+            terminal1=wr(state.terminal1, chunk.terminal1),
+            pos=(state.pos + total) % capacity,
+            fill=jnp.minimum(state.fill + total, capacity),
+        )
+        prov_col = getattr(state, "prov", None)
+        if prov_col is not None:
+            n = chunk.reward.shape[0]
+            repl["prov"] = wr(prov_col, (
+                jnp.full((n, prov_col.shape[1]), -1, prov_col.dtype)
+                if chunk.prov is None
+                else chunk.prov.astype(prov_col.dtype)))
     return state._replace(**repl), total
 
 
@@ -133,14 +140,22 @@ def chunk_to_nhwc(chunk: Transition) -> Transition:
     forwards that each needed the copy: ~25% of device time in the XLA
     profile, tools/mfu_probe.py)."""
     t = lambda x: jnp.transpose(x, (0, 2, 3, 1))
-    return chunk._replace(state0=t(chunk.state0), state1=t(chunk.state1))
+    with jax.named_scope(PHASE_FEED):
+        return chunk._replace(state0=t(chunk.state0),
+                              state1=t(chunk.state1))
 
 
-def wrap_feed_nhwc(feed_fn):
-    """Single point wrapping a ring's feed with the ingest transpose —
-    DeviceReplay and DevicePerReplay share it so the layout contract
-    lives in one place."""
-    return lambda st, ch: feed_fn(st, chunk_to_nhwc(ch))
+def jit_feed(feed_fn, channels_last: bool = False):
+    """The jitted, donating feed program of a ring, under a name a trace
+    can show (``jit_feed_chunk``; a bare ``functools.partial`` compiles to
+    ``jit__unknown``).  Single point wrapping a channels-last ring's feed
+    with the ingest transpose — DeviceReplay and DevicePerReplay share it
+    so the layout contract lives in one place."""
+    def feed_chunk(state, chunk):
+        return feed_fn(state, chunk_to_nhwc(chunk) if channels_last
+                       else chunk)
+
+    return jax.jit(feed_chunk, donate_argnums=0)
 
 
 def snapshot_states_to_nchw(out: dict) -> dict:
@@ -175,17 +190,20 @@ def sample_rows(state: ReplayState, key: jax.Array,
                 batch_size: int) -> Batch:
     """Uniform on-device sampling from the ring — public so the learner and
     the driver dryrun can fuse it into their train-step programs."""
-    idx = jax.random.randint(key, (batch_size,), 0, jnp.maximum(state.fill, 1))
-    return Batch(
-        state0=state.state0[idx],
-        action=state.action[idx],
-        reward=state.reward[idx],
-        gamma_n=state.gamma_n[idx],
-        state1=state.state1[idx],
-        terminal1=state.terminal1[idx],
-        weight=jnp.ones((batch_size,), dtype=jnp.float32),
-        index=idx.astype(jnp.int32),
-    )
+    with jax.named_scope(PHASE_DRAW):
+        idx = jax.random.randint(key, (batch_size,), 0,
+                                 jnp.maximum(state.fill, 1))
+    with jax.named_scope(PHASE_GATHER):
+        return Batch(
+            state0=state.state0[idx],
+            action=state.action[idx],
+            reward=state.reward[idx],
+            gamma_n=state.gamma_n[idx],
+            state1=state.state1[idx],
+            terminal1=state.terminal1[idx],
+            weight=jnp.ones((batch_size,), dtype=jnp.float32),
+            index=idx.astype(jnp.int32),
+        )
 
 
 def provenance_sample(state: ReplayState, key: jax.Array,
@@ -234,11 +252,14 @@ def build_uniform_fused_step(step_fn, batch_size: int,
         groups = steps_per_call // megabatch
 
         def multi_mega(ts, ring_state, keys):
-            gkeys = keys.reshape(groups, megabatch, *keys.shape[1:])
+            with jax.named_scope(PHASE_DRAW):
+                gkeys = keys.reshape(groups, megabatch, *keys.shape[1:])
 
             def one_group(ts, kset):
-                batches = jax.vmap(
-                    lambda k: sample_rows(ring_state, k, batch_size))(kset)
+                with jax.named_scope(PHASE_GATHER):  # vmap's transposes
+                    batches = jax.vmap(
+                        lambda k: sample_rows(ring_state, k,
+                                              batch_size))(kset)
                 ts, metrics, _td, _ok = megabatch_step(ts, batches)
                 return ts, metrics
 
@@ -302,10 +323,8 @@ class DeviceReplay:
             self._scalar_sharding = None
 
         self.state = self._init_state()
-        feed = functools.partial(_feed, capacity=capacity)
-        if self.channels_last:
-            feed = wrap_feed_nhwc(feed)
-        self._feed_fn = jax.jit(feed, donate_argnums=0)
+        self._feed_fn = jit_feed(
+            functools.partial(_feed, capacity=capacity), self.channels_last)
         self._sample_fn = jax.jit(
             sample_rows, static_argnames="batch_size", donate_argnums=())
 

@@ -38,8 +38,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from pytorch_distributed_tpu.memory.device_replay import round_capacity
+from pytorch_distributed_tpu.memory.device_replay import (
+    jit_feed, round_capacity,
+)
 from pytorch_distributed_tpu.memory.sequence_replay import SegmentBatch
+from pytorch_distributed_tpu.utils.profiling import (
+    PHASE_DRAW, PHASE_FEED, PHASE_GATHER, PHASE_WRITEBACK,
+)
 
 
 class SegmentChunk(NamedTuple):
@@ -74,19 +79,20 @@ def seq_feed(state: SeqReplayState, chunk: SegmentChunk,
     """Ring-write a chunk of segments at the cursor; new rows enter at the
     running max priority (Ape-X/R2D2 standard — replayed at least once)."""
     n = chunk.reward.shape[0]
-    idx = (state.pos + jnp.arange(n, dtype=jnp.int32)) % capacity
-    return state._replace(
-        obs=state.obs.at[idx].set(chunk.obs),
-        action=state.action.at[idx].set(chunk.action),
-        reward=state.reward.at[idx].set(chunk.reward),
-        terminal=state.terminal.at[idx].set(chunk.terminal),
-        mask=state.mask.at[idx].set(chunk.mask),
-        c0=state.c0.at[idx].set(chunk.c0),
-        h0=state.h0.at[idx].set(chunk.h0),
-        priority=state.priority.at[idx].set(state.max_priority),
-        pos=(state.pos + n) % capacity,
-        fill=jnp.minimum(state.fill + n, capacity),
-    )
+    with jax.named_scope(PHASE_FEED):
+        idx = (state.pos + jnp.arange(n, dtype=jnp.int32)) % capacity
+        return state._replace(
+            obs=state.obs.at[idx].set(chunk.obs),
+            action=state.action.at[idx].set(chunk.action),
+            reward=state.reward.at[idx].set(chunk.reward),
+            terminal=state.terminal.at[idx].set(chunk.terminal),
+            mask=state.mask.at[idx].set(chunk.mask),
+            c0=state.c0.at[idx].set(chunk.c0),
+            h0=state.h0.at[idx].set(chunk.h0),
+            priority=state.priority.at[idx].set(state.max_priority),
+            pos=(state.pos + n) % capacity,
+            fill=jnp.minimum(state.fill + n, capacity),
+        )
 
 
 def seq_sample(state: SeqReplayState, key: jax.Array, batch_size: int,
@@ -94,29 +100,32 @@ def seq_sample(state: SeqReplayState, key: jax.Array, batch_size: int,
     """Proportional segment sample + IS weights, all on device — the
     sequence twin of device_per.per_sample (same inverse-CDF scheme, same
     max-weight normalisation over valid rows)."""
-    p = state.priority  # empty rows hold 0 and can never be drawn
-    cdf = jnp.cumsum(p)
-    total = cdf[-1]
-    u = jax.random.uniform(key, (batch_size,)) * total
-    idx = jnp.clip(jnp.searchsorted(cdf, u, side="right"),
-                   0, p.shape[0] - 1).astype(jnp.int32)
-    probs = p[idx] / jnp.maximum(total, 1e-12)
-    fill = jnp.maximum(state.fill.astype(jnp.float32), 1.0)
-    weights = (fill * jnp.maximum(probs, 1e-12)) ** (-beta)
-    min_p = jnp.min(jnp.where(p > 0, p, jnp.inf)) / jnp.maximum(total, 1e-12)
-    max_w = (fill * jnp.maximum(min_p, 1e-12)) ** (-beta)
-    weights = weights / jnp.maximum(max_w, 1e-12)
-    return SegmentBatch(
-        obs=state.obs[idx],
-        action=state.action[idx],
-        reward=state.reward[idx],
-        terminal=state.terminal[idx],
-        mask=state.mask[idx],
-        c0=state.c0[idx],
-        h0=state.h0[idx],
-        weight=weights.astype(jnp.float32),
-        index=idx,
-    )
+    with jax.named_scope(PHASE_DRAW):
+        p = state.priority  # empty rows hold 0 and can never be drawn
+        cdf = jnp.cumsum(p)
+        total = cdf[-1]
+        u = jax.random.uniform(key, (batch_size,)) * total
+        idx = jnp.clip(jnp.searchsorted(cdf, u, side="right"),
+                       0, p.shape[0] - 1).astype(jnp.int32)
+        probs = p[idx] / jnp.maximum(total, 1e-12)
+        fill = jnp.maximum(state.fill.astype(jnp.float32), 1.0)
+        weights = (fill * jnp.maximum(probs, 1e-12)) ** (-beta)
+        min_p = (jnp.min(jnp.where(p > 0, p, jnp.inf))
+                 / jnp.maximum(total, 1e-12))
+        max_w = (fill * jnp.maximum(min_p, 1e-12)) ** (-beta)
+        weights = weights / jnp.maximum(max_w, 1e-12)
+    with jax.named_scope(PHASE_GATHER):
+        return SegmentBatch(
+            obs=state.obs[idx],
+            action=state.action[idx],
+            reward=state.reward[idx],
+            terminal=state.terminal[idx],
+            mask=state.mask[idx],
+            c0=state.c0[idx],
+            h0=state.h0[idx],
+            weight=weights.astype(jnp.float32),
+            index=idx,
+        )
 
 
 def seq_update_priorities(state: SeqReplayState, idx: jax.Array,
@@ -183,9 +192,8 @@ class DeviceSequenceReplay:
             self._scalar_sharding = None
 
         self.state = self._init_state()
-        self._feed_fn = jax.jit(
-            functools.partial(seq_feed, capacity=self.capacity),
-            donate_argnums=0)
+        self._feed_fn = jit_feed(
+            functools.partial(seq_feed, capacity=self.capacity))
         self._sample_fn = jax.jit(seq_sample, static_argnames="batch_size")
 
     def _alloc(self, shape, dtype, sharded: bool = True):
@@ -251,13 +259,15 @@ class DeviceSequenceReplay:
         def one(ts, rs: SeqReplayState, key, beta):
             batch = seq_sample(rs, key, batch_size, beta)
             ts, metrics, seq_pr = train_step(ts, batch)
-            rs_new = seq_update_priorities(rs, batch.index, seq_pr, alpha)
-            skipped = (metrics.get(SKIPPED_KEY)
-                       if isinstance(metrics, dict) else None)
-            if skipped is not None:
-                # a guard-skipped substep's zeroed priorities must not
-                # overwrite the ring's real ones (utils/health.py)
-                rs_new = suppress_writeback(skipped, rs_new, rs)
+            with jax.named_scope(PHASE_WRITEBACK):
+                rs_new = seq_update_priorities(rs, batch.index, seq_pr,
+                                               alpha)
+                skipped = (metrics.get(SKIPPED_KEY)
+                           if isinstance(metrics, dict) else None)
+                if skipped is not None:
+                    # a guard-skipped substep's zeroed priorities must not
+                    # overwrite the ring's real ones (utils/health.py)
+                    rs_new = suppress_writeback(skipped, rs_new, rs)
             return ts, rs_new, metrics
 
         if steps_per_call <= 1:

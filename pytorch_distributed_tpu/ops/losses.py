@@ -42,6 +42,9 @@ import optax
 from pytorch_distributed_tpu.utils.experience import Batch
 from pytorch_distributed_tpu.utils.health import finite_guard
 from pytorch_distributed_tpu.utils.helpers import global_norm, update_target
+from pytorch_distributed_tpu.utils.profiling import (
+    PHASE_ONLINE, PHASE_OPTIMIZER, PHASE_TARGET,
+)
 
 PyTree = Any
 
@@ -95,6 +98,45 @@ def _value_loss(pred: jnp.ndarray, target: jnp.ndarray, weight: jnp.ndarray,
     return jnp.mean(weight * per), jnp.abs(td)
 
 
+def online_grad(loss_fn: Callable, has_aux: bool = False) -> Callable:
+    """``jax.value_and_grad(loss_fn)``, evaluated inside the device phase
+    ``train.online``: entered around the whole differentiation, so the
+    loss needs to name only what is NOT the online pass (the target
+    pass; the innermost name on an op's path is its phase)."""
+    grad_fn = jax.value_and_grad(loss_fn, has_aux=has_aux)
+
+    def scoped(*args):
+        with jax.named_scope(PHASE_ONLINE):
+            return grad_fn(*args)
+
+    return scoped
+
+
+def _dqn_loss(apply_fn: Callable, params: PyTree, target_params: PyTree,
+              batch: Batch, enable_double: bool, huber: bool):
+    """The TD loss of one minibatch, ``(loss, (td_abs, q_mean))``: the one
+    statement of the DQN objective (reference dqn_learner.py:55-76) that
+    the fused step, the replica split and the megabatch group step all
+    differentiate.  Device phases (utils/profiling.py): the callers
+    differentiate it inside ``train.online`` (``online_grad``); the pass
+    through ``target_params`` is ``train.target``, the innermost name."""
+    q = apply_fn(params, batch.state0)                           # (B, A)
+    a = batch.action.astype(jnp.int32).reshape(-1, 1)
+    q_sel = jnp.take_along_axis(q, a, axis=1)[:, 0]
+    with jax.named_scope(PHASE_TARGET):
+        q_next = apply_fn(target_params, batch.state1)           # (B, A)
+    if enable_double:
+        a_next = jnp.argmax(apply_fn(params, batch.state1), axis=-1)
+        bootstrap = jnp.take_along_axis(
+            q_next, a_next[:, None], axis=1)[:, 0]
+    else:
+        bootstrap = jnp.max(q_next, axis=-1)
+    target = (batch.reward
+              + batch.gamma_n * bootstrap * (1.0 - batch.terminal1))
+    loss, td_abs = _value_loss(q_sel, target, batch.weight, huber)
+    return loss, (td_abs, jnp.mean(jnp.max(q, axis=-1)))
+
+
 def build_dqn_train_step(
     apply_fn: Callable,
     tx: optax.GradientTransformation,
@@ -115,35 +157,25 @@ def build_dqn_train_step(
 
     def step(state: TrainState, batch: Batch):
         def loss_fn(params):
-            q = apply_fn(params, batch.state0)                       # (B, A)
-            a = batch.action.astype(jnp.int32).reshape(-1, 1)
-            q_sel = jnp.take_along_axis(q, a, axis=1)[:, 0]
-            q_next = apply_fn(state.target_params, batch.state1)     # (B, A)
-            if enable_double:
-                a_next = jnp.argmax(apply_fn(params, batch.state1), axis=-1)
-                bootstrap = jnp.take_along_axis(
-                    q_next, a_next[:, None], axis=1)[:, 0]
-            else:
-                bootstrap = jnp.max(q_next, axis=-1)
-            target = (batch.reward
-                      + batch.gamma_n * bootstrap * (1.0 - batch.terminal1))
-            loss, td_abs = _value_loss(q_sel, target, batch.weight, huber)
-            return loss, (td_abs, jnp.mean(jnp.max(q, axis=-1)))
+            return _dqn_loss(apply_fn, params, state.target_params, batch,
+                             enable_double, huber)
 
-        (loss, (td_abs, q_mean)), grads = jax.value_and_grad(
+        (loss, (td_abs, q_mean)), grads = online_grad(
             loss_fn, has_aux=True)(state.params)
-        # data-parallel: mean grads across the mesh's dp axis if present
-        grads = _pmean(grads, axis_name)
-        updates, opt_state = tx.update(grads, state.opt_state, state.params)
-        params = optax.apply_updates(state.params, updates)
-        new_step = state.step + 1
-        target_params = update_target(state.target_params, params, new_step,
-                                      target_model_update)
-        metrics = {
-            "learner/critic_loss": loss,
-            "learner/q_mean": q_mean,
-            "learner/grad_norm": global_norm(grads),
-        }
+        with jax.named_scope(PHASE_OPTIMIZER):
+            # data-parallel: mean grads across the mesh's dp axis if present
+            grads = _pmean(grads, axis_name)
+            updates, opt_state = tx.update(grads, state.opt_state,
+                                           state.params)
+            params = optax.apply_updates(state.params, updates)
+            new_step = state.step + 1
+            target_params = update_target(state.target_params, params,
+                                          new_step, target_model_update)
+            metrics = {
+                "learner/critic_loss": loss,
+                "learner/q_mean": q_mean,
+                "learner/grad_norm": global_norm(grads),
+            }
         return (TrainState(params, target_params, opt_state, new_step),
                 metrics, td_abs)
 
@@ -182,48 +214,35 @@ def build_dqn_grad_and_apply(
 
     def grad_fn(state: TrainState, batch: Batch):
         def loss_fn(params):
-            q = apply_fn(params, batch.state0)
-            a = batch.action.astype(jnp.int32).reshape(-1, 1)
-            q_sel = jnp.take_along_axis(q, a, axis=1)[:, 0]
-            q_next = apply_fn(state.target_params, batch.state1)
-            if enable_double:
-                a_next = jnp.argmax(apply_fn(params, batch.state1),
-                                    axis=-1)
-                bootstrap = jnp.take_along_axis(
-                    q_next, a_next[:, None], axis=1)[:, 0]
-            else:
-                bootstrap = jnp.max(q_next, axis=-1)
-            target = (batch.reward
-                      + batch.gamma_n * bootstrap
-                      * (1.0 - batch.terminal1))
-            loss, td_abs = _value_loss(q_sel, target, batch.weight,
-                                       huber)
-            return loss, (td_abs, jnp.mean(jnp.max(q, axis=-1)))
+            return _dqn_loss(apply_fn, params, state.target_params, batch,
+                             enable_double, huber)
 
-        (loss, (td_abs, q_mean)), grads = jax.value_and_grad(
+        (loss, (td_abs, q_mean)), grads = online_grad(
             loss_fn, has_aux=True)(state.params)
-        ok = jnp.isfinite(loss) & jnp.all(jnp.isfinite(td_abs))
-        for leaf in jax.tree_util.tree_leaves(grads):
-            ok = ok & jnp.all(jnp.isfinite(leaf))
-        metrics = {
-            "learner/critic_loss": loss,
-            "learner/q_mean": q_mean,
-            "learner/grad_norm": global_norm(grads),
-        }
+        with jax.named_scope(PHASE_OPTIMIZER):
+            ok = jnp.isfinite(loss) & jnp.all(jnp.isfinite(td_abs))
+            for leaf in jax.tree_util.tree_leaves(grads):
+                ok = ok & jnp.all(jnp.isfinite(leaf))
+            metrics = {
+                "learner/critic_loss": loss,
+                "learner/q_mean": q_mean,
+                "learner/grad_norm": global_norm(grads),
+            }
         return grads, ok.astype(jnp.float32), metrics, td_abs
 
     def apply_grads(state: TrainState, grads, ok):
-        updates, opt_state = tx.update(grads, state.opt_state,
-                                       state.params)
-        params = optax.apply_updates(state.params, updates)
-        new_step = state.step + 1
-        target_params = update_target(state.target_params, params,
-                                      new_step, target_model_update)
-        new = TrainState(params, target_params, opt_state, new_step)
-        # ok <= 0: the whole round was invalid — pass the input state
-        # through per-leaf, exactly finite_guard's skip semantics
-        return jax.tree_util.tree_map(
-            lambda a, b: jnp.where(ok > 0, a, b), new, state)
+        with jax.named_scope(PHASE_OPTIMIZER):
+            updates, opt_state = tx.update(grads, state.opt_state,
+                                           state.params)
+            params = optax.apply_updates(state.params, updates)
+            new_step = state.step + 1
+            target_params = update_target(state.target_params, params,
+                                          new_step, target_model_update)
+            new = TrainState(params, target_params, opt_state, new_step)
+            # ok <= 0: the whole round was invalid — pass the input state
+            # through per-leaf, exactly finite_guard's skip semantics
+            return jax.tree_util.tree_map(
+                lambda a, b: jnp.where(ok > 0, a, b), new, state)
 
     return grad_fn, apply_grads
 
@@ -282,26 +301,21 @@ def build_dqn_megabatch_step(
     from pytorch_distributed_tpu.utils.health import SKIPPED_KEY
 
     def minibatch_loss(params, target_params, batch: Batch):
-        q = apply_fn(params, batch.state0)                       # (B, A)
-        a = batch.action.astype(jnp.int32).reshape(-1, 1)
-        q_sel = jnp.take_along_axis(q, a, axis=1)[:, 0]
-        q_next = apply_fn(target_params, batch.state1)           # (B, A)
-        if enable_double:
-            a_next = jnp.argmax(apply_fn(params, batch.state1), axis=-1)
-            bootstrap = jnp.take_along_axis(
-                q_next, a_next[:, None], axis=1)[:, 0]
-        else:
-            bootstrap = jnp.max(q_next, axis=-1)
-        target = (batch.reward
-                  + batch.gamma_n * bootstrap * (1.0 - batch.terminal1))
-        loss, td_abs = _value_loss(q_sel, target, batch.weight, huber)
-        return loss, (td_abs, jnp.mean(jnp.max(q, axis=-1)))
+        return _dqn_loss(apply_fn, params, target_params, batch,
+                         enable_double, huber)
 
     def step(state: TrainState, batches: Batch):
         grad_fn = jax.value_and_grad(minibatch_loss, has_aux=True)
-        (losses, (td_abs, q_means)), grads = jax.vmap(
-            grad_fn, in_axes=(None, None, 0))(
-                state.params, state.target_params, batches)
+        with jax.named_scope(PHASE_ONLINE):  # around vmap: its own
+            # batching transposes carry no inner name
+            (losses, (td_abs, q_means)), grads = jax.vmap(
+                grad_fn, in_axes=(None, None, 0))(
+                    state.params, state.target_params, batches)
+        with jax.named_scope(PHASE_OPTIMIZER):
+            return _apply_group(state, grads, losses, td_abs, q_means)
+
+    def _apply_group(state, grads, losses, td_abs, q_means):
+        """The M sequential optimizer applies (``train.optimizer``)."""
         grads = _pmean(grads, axis_name)
         M = losses.shape[0]
         ok = (_per_minibatch_ok(losses, td_abs, q_means, grads=grads)
@@ -343,6 +357,32 @@ def build_dqn_megabatch_step(
     return step
 
 
+def _ddpg_critic_loss(actor_apply_fn: Callable, critic_apply_fn: Callable,
+                      actor_params: PyTree, critic_params: PyTree,
+                      target_full: PyTree, batch: Batch, huber: bool):
+    """Critic TD loss of the decoupled DDPG update, ``(loss, td_abs)``
+    (reference ddpg_learner.py:76-86); the target actor and critic passes
+    are ``train.target``; differentiated inside ``train.online``."""
+    full = merge_ddpg_params(actor_params, critic_params)
+    q = critic_apply_fn(full, batch.state0, batch.action)
+    with jax.named_scope(PHASE_TARGET):
+        a_next = actor_apply_fn(target_full, batch.state1)
+        q_next = critic_apply_fn(target_full, batch.state1, a_next)
+    tgt = (batch.reward
+           + batch.gamma_n * q_next * (1.0 - batch.terminal1))
+    return _value_loss(q, tgt, batch.weight, huber)
+
+
+def _ddpg_actor_loss(actor_apply_fn: Callable, critic_apply_fn: Callable,
+                     actor_params: PyTree, critic_params: PyTree,
+                     batch: Batch):
+    """Policy loss ``-Q(s, pi(s)).mean()`` (reference
+    ddpg_learner.py:66-74)."""
+    full = merge_ddpg_params(actor_params, critic_params)
+    a = actor_apply_fn(full, batch.state0)
+    return -jnp.mean(critic_apply_fn(full, batch.state0, a))
+
+
 def build_ddpg_megabatch_step(
     actor_apply_fn: Callable,
     critic_apply_fn: Callable,
@@ -378,18 +418,13 @@ def build_ddpg_megabatch_step(
 
     def critic_loss_fn(critic_params, actor_params, target_full,
                        batch: Batch):
-        full = merge_ddpg_params(actor_params, critic_params)
-        q = critic_apply_fn(full, batch.state0, batch.action)
-        a_next = actor_apply_fn(target_full, batch.state1)
-        q_next = critic_apply_fn(target_full, batch.state1, a_next)
-        tgt = (batch.reward
-               + batch.gamma_n * q_next * (1.0 - batch.terminal1))
-        return _value_loss(q, tgt, batch.weight, huber)
+        return _ddpg_critic_loss(actor_apply_fn, critic_apply_fn,
+                                 actor_params, critic_params, target_full,
+                                 batch, huber)
 
     def actor_loss_fn(actor_params, critic_params, batch: Batch):
-        full = merge_ddpg_params(actor_params, critic_params)
-        a = actor_apply_fn(full, batch.state0)
-        return -jnp.mean(critic_apply_fn(full, batch.state0, a))
+        return _ddpg_actor_loss(actor_apply_fn, critic_apply_fn,
+                                actor_params, critic_params, batch)
 
     def step(state: TrainState, batches: Batch):
         params, target = state.params, state.target_params
@@ -397,15 +432,11 @@ def build_ddpg_megabatch_step(
 
         # ---- stage 1: M critic grads at group entry, one batched bwd ----
         cgrad_fn = jax.value_and_grad(critic_loss_fn, has_aux=True)
-        (closs, td_abs), cgrads = jax.vmap(
-            cgrad_fn, in_axes=(None, None, None, 0))(
-                params["critic"], params["actor"], target_full, batches)
-        cgrads = _pmean(cgrads, axis_name)
-        M = closs.shape[0]
-        ones = jnp.ones((M,), jnp.float32)
-        ok_c = (_per_minibatch_ok(closs, td_abs, grads=cgrads)
-                if guard else ones)
-
+        with jax.named_scope(PHASE_ONLINE):
+            (closs, td_abs), cgrads = jax.vmap(
+                cgrad_fn, in_axes=(None, None, None, 0))(
+                    params["critic"], params["actor"], target_full,
+                    batches)
         def capply(carry, x):
             cp, copt = carry
             g, ok_i = x
@@ -417,18 +448,21 @@ def build_ddpg_megabatch_step(
             new_cp = sel(new_cp, cp)
             return (new_cp, sel(new_opt, copt)), new_cp
 
-        (final_critic, critic_opt), critics = jax.lax.scan(
-            capply, (params["critic"], state.opt_state["critic"]),
-            (cgrads, ok_c))
+        with jax.named_scope(PHASE_OPTIMIZER):
+            cgrads = _pmean(cgrads, axis_name)
+            M = closs.shape[0]
+            ones = jnp.ones((M,), jnp.float32)
+            ok_c = (_per_minibatch_ok(closs, td_abs, grads=cgrads)
+                    if guard else ones)
+            (final_critic, critic_opt), critics = jax.lax.scan(
+                capply, (params["critic"], state.opt_state["critic"]),
+                (cgrads, ok_c))
 
         # ---- stage 2: M actor grads at (entry actor, final critic) ----
         agrad_fn = jax.value_and_grad(actor_loss_fn)
-        aloss, agrads = jax.vmap(agrad_fn, in_axes=(None, None, 0))(
-            params["actor"], final_critic, batches)
-        agrads = _pmean(agrads, axis_name)
-        ok = ok_c * (_per_minibatch_ok(aloss, grads=agrads)
-                     if guard else ones)
-
+        with jax.named_scope(PHASE_ONLINE):
+            aloss, agrads = jax.vmap(agrad_fn, in_axes=(None, None, 0))(
+                params["actor"], final_critic, batches)
         def aapply(carry, x):
             ap_, aopt, tgt, step_c = carry
             g, ok_i, critic_i = x
@@ -445,23 +479,28 @@ def build_ddpg_megabatch_step(
                     sel(new_tgt, tgt),
                     jnp.where(keep, new_step, step_c)), None
 
-        (final_actor, actor_opt, new_target, new_step), _ = jax.lax.scan(
-            aapply,
-            (params["actor"], state.opt_state["actor"], target,
-             state.step),
-            (agrads, ok, critics))
+        with jax.named_scope(PHASE_OPTIMIZER):
+            agrads = _pmean(agrads, axis_name)
+            ok = ok_c * (_per_minibatch_ok(aloss, grads=agrads)
+                         if guard else ones)
+            (final_actor, actor_opt, new_target, new_step), _ = \
+                jax.lax.scan(
+                    aapply,
+                    (params["actor"], state.opt_state["actor"], target,
+                     state.step),
+                    (agrads, ok, critics))
 
-        last_g = jax.tree_util.tree_map(
-            lambda l: l[-1], {"actor": agrads, "critic": cgrads})
-        metrics = {
-            "learner/critic_loss": closs[-1],
-            "learner/actor_loss": aloss[-1],
-            "learner/grad_norm": global_norm(last_g),
-        }
-        if guard:
-            metrics[SKIPPED_KEY] = jnp.sum(1.0 - ok)
-        td_abs = jnp.where(ok[:, None] > 0.5, td_abs,
-                           jnp.zeros_like(td_abs))
+            last_g = jax.tree_util.tree_map(
+                lambda l: l[-1], {"actor": agrads, "critic": cgrads})
+            metrics = {
+                "learner/critic_loss": closs[-1],
+                "learner/actor_loss": aloss[-1],
+                "learner/grad_norm": global_norm(last_g),
+            }
+            if guard:
+                metrics[SKIPPED_KEY] = jnp.sum(1.0 - ok)
+            td_abs = jnp.where(ok[:, None] > 0.5, td_abs,
+                               jnp.zeros_like(td_abs))
         new_state = TrainState(
             {"actor": final_actor, "critic": final_critic}, new_target,
             {"actor": actor_opt, "critic": critic_opt}, new_step)
@@ -516,47 +555,46 @@ def build_ddpg_train_step(
         target_full = merge_ddpg_params(target["actor"], target["critic"])
 
         def critic_loss_fn(critic_params):
-            full = merge_ddpg_params(params["actor"], critic_params)
-            q = critic_apply_fn(full, batch.state0, batch.action)
-            a_next = actor_apply_fn(target_full, batch.state1)
-            q_next = critic_apply_fn(target_full, batch.state1, a_next)
-            tgt = (batch.reward
-                   + batch.gamma_n * q_next * (1.0 - batch.terminal1))
-            return _value_loss(q, tgt, batch.weight, huber)
+            return _ddpg_critic_loss(actor_apply_fn, critic_apply_fn,
+                                     params["actor"], critic_params,
+                                     target_full, batch, huber)
 
-        (critic_loss, td_abs), critic_grads = jax.value_and_grad(
+        (critic_loss, td_abs), critic_grads = online_grad(
             critic_loss_fn, has_aux=True)(params["critic"])
-        critic_grads = _pmean(critic_grads, axis_name)
-        critic_updates, critic_opt = critic_tx.update(
-            critic_grads, state.opt_state["critic"], params["critic"])
-        new_critic = optax.apply_updates(params["critic"], critic_updates)
+        with jax.named_scope(PHASE_OPTIMIZER):
+            critic_grads = _pmean(critic_grads, axis_name)
+            critic_updates, critic_opt = critic_tx.update(
+                critic_grads, state.opt_state["critic"], params["critic"])
+            new_critic = optax.apply_updates(params["critic"],
+                                             critic_updates)
 
         # ---- actor update (reference ddpg_learner.py:66-74) ----
         def actor_loss_fn(actor_params):
-            full = merge_ddpg_params(actor_params, new_critic)
-            a = actor_apply_fn(full, batch.state0)
-            q = critic_apply_fn(full, batch.state0, a)
-            return -jnp.mean(q)
+            return _ddpg_actor_loss(actor_apply_fn, critic_apply_fn,
+                                    actor_params, new_critic, batch)
 
-        actor_loss, actor_grads = jax.value_and_grad(actor_loss_fn)(
+        actor_loss, actor_grads = online_grad(actor_loss_fn)(
             params["actor"])
-        actor_grads = _pmean(actor_grads, axis_name)
-        actor_updates, actor_opt = actor_tx.update(
-            actor_grads, state.opt_state["actor"], params["actor"])
-        new_actor = optax.apply_updates(params["actor"], actor_updates)
+        with jax.named_scope(PHASE_OPTIMIZER):
+            actor_grads = _pmean(actor_grads, axis_name)
+            actor_updates, actor_opt = actor_tx.update(
+                actor_grads, state.opt_state["actor"], params["actor"])
+            new_actor = optax.apply_updates(params["actor"], actor_updates)
 
-        new_params = {"actor": new_actor, "critic": new_critic}
-        new_step = state.step + 1
-        # soft target every step (reference ddpg_learner.py:95, tau=1e-3)
-        new_target = update_target(target, new_params, new_step,
-                                   target_model_update)
-        metrics = {
-            "learner/critic_loss": critic_loss,
-            "learner/actor_loss": actor_loss,
-            # norm over BOTH nets' grads so a diverging policy is visible
-            "learner/grad_norm": global_norm(
-                {"actor": actor_grads, "critic": critic_grads}),
-        }
+            new_params = {"actor": new_actor, "critic": new_critic}
+            new_step = state.step + 1
+            # soft target every step (reference ddpg_learner.py:95,
+            # tau=1e-3)
+            new_target = update_target(target, new_params, new_step,
+                                       target_model_update)
+            metrics = {
+                "learner/critic_loss": critic_loss,
+                "learner/actor_loss": actor_loss,
+                # norm over BOTH nets' grads so a diverging policy is
+                # visible
+                "learner/grad_norm": global_norm(
+                    {"actor": actor_grads, "critic": critic_grads}),
+            }
         return (TrainState(new_params, new_target,
                            {"actor": actor_opt, "critic": critic_opt},
                            new_step),
@@ -586,8 +624,10 @@ def build_ddpg_train_step_coupled(
         def loss_fn(full):
             # critic TD loss (reference ddpg_learner.py:76-86)
             q = critic_apply_fn(full, batch.state0, batch.action)
-            a_next = actor_apply_fn(state.target_params, batch.state1)
-            q_next = critic_apply_fn(state.target_params, batch.state1, a_next)
+            with jax.named_scope(PHASE_TARGET):
+                a_next = actor_apply_fn(state.target_params, batch.state1)
+                q_next = critic_apply_fn(state.target_params, batch.state1,
+                                         a_next)
             tgt = (batch.reward
                    + batch.gamma_n * q_next * (1.0 - batch.terminal1))
             critic_loss, td_abs = _value_loss(q, tgt, batch.weight, huber)
@@ -596,19 +636,21 @@ def build_ddpg_train_step_coupled(
             actor_loss = -jnp.mean(critic_apply_fn(full, batch.state0, a))
             return critic_loss + actor_loss, (critic_loss, actor_loss, td_abs)
 
-        (_, (critic_loss, actor_loss, td_abs)), grads = jax.value_and_grad(
+        (_, (critic_loss, actor_loss, td_abs)), grads = online_grad(
             loss_fn, has_aux=True)(state.params)
-        grads = _pmean(grads, axis_name)
-        updates, opt_state = tx.update(grads, state.opt_state, state.params)
-        params = optax.apply_updates(state.params, updates)
-        new_step = state.step + 1
-        new_target = update_target(state.target_params, params, new_step,
-                                   target_model_update)
-        metrics = {
-            "learner/critic_loss": critic_loss,
-            "learner/actor_loss": actor_loss,
-            "learner/grad_norm": global_norm(grads),
-        }
+        with jax.named_scope(PHASE_OPTIMIZER):
+            grads = _pmean(grads, axis_name)
+            updates, opt_state = tx.update(grads, state.opt_state,
+                                           state.params)
+            params = optax.apply_updates(state.params, updates)
+            new_step = state.step + 1
+            new_target = update_target(state.target_params, params,
+                                       new_step, target_model_update)
+            metrics = {
+                "learner/critic_loss": critic_loss,
+                "learner/actor_loss": actor_loss,
+                "learner/grad_norm": global_norm(grads),
+            }
         return (TrainState(params, new_target, opt_state, new_step),
                 metrics, td_abs)
 

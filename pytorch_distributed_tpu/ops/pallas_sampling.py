@@ -145,6 +145,9 @@ def hierarchical_sample(priority: jax.Array, key: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((batch_size,), jnp.int32),
         interpret=interpret,
+        # the kernel's name in a device trace, pinned against a rename of
+        # the Python function (today's default, so the kernel is unchanged)
+        name="_draw_kernel",
     )(bid, targets, p3)
 
     idx = jnp.minimum(bid * block + local, n - 1)

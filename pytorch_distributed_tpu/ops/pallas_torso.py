@@ -117,6 +117,7 @@ def _mm(x: jax.Array, w: jax.Array, interpret: bool) -> jax.Array:
         out_specs=pl.BlockSpec((_TILE_M, np_), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.float32),
         interpret=interpret,
+        name="_mm_kernel",   # pinned: see ops/pallas_sampling.py
     )(x, w)
     return out[:m, :n]
 

@@ -29,6 +29,7 @@ no Python loop over T); the n-step lookahead is a static unroll over
 
 from __future__ import annotations
 
+import contextlib
 from typing import Any, Callable, Dict, Tuple
 
 import jax
@@ -36,9 +37,13 @@ import jax.numpy as jnp
 import optax
 
 from pytorch_distributed_tpu.memory.sequence_replay import SegmentBatch
-from pytorch_distributed_tpu.ops.losses import TrainState
+from pytorch_distributed_tpu.ops.losses import TrainState, online_grad
 from pytorch_distributed_tpu.utils.health import finite_guard
 from pytorch_distributed_tpu.utils.helpers import global_norm, update_target
+from pytorch_distributed_tpu.utils.profiling import (
+    PHASE_GATHER, PHASE_ONLINE, PHASE_OPTIMIZER, PHASE_TARGET,
+    SCOPE_BURN_IN, SCOPE_UNROLL,
+)
 
 PyTree = Any
 
@@ -69,13 +74,20 @@ def unpack_frame_stacks(frames: jnp.ndarray, C: int,
                      axis=2)
 
 
-def unroll(apply_fn: Callable, params: PyTree, carry,
-           obs_tm: jnp.ndarray) -> Tuple[Any, jnp.ndarray]:
+def unroll(apply_fn: Callable, params: PyTree, carry, obs_tm: jnp.ndarray,
+           phase: str | None = None) -> Tuple[Any, jnp.ndarray]:
     """Scan the single-step recurrent apply over a time-major observation
-    sequence (T, B, *S) -> (carry_out, q_seq (T, B, A))."""
+    sequence (T, B, *S) -> (carry_out, q_seq (T, B, A)).
+
+    ``phase`` names the device phase (utils/profiling.py) INSIDE the scan
+    body as well: what JAX hoists out of a differentiated scan
+    (loop-invariant casts of the weights) keeps the names entered in the
+    body and loses every name entered around the scan."""
 
     def step(c, o):
-        q, c2 = apply_fn(params, o, c)
+        with (jax.named_scope(phase) if phase
+              else contextlib.nullcontext()):
+            q, c2 = apply_fn(params, o, c)
         return c2, q
 
     return jax.lax.scan(step, carry, obs_tm)
@@ -139,19 +151,24 @@ def _bootstrap_values(q_tm, q_target_tm, enable_double, h_inv):
 
 
 def _apply_update(state, grads, loss, seq_pr, q_mean, tx,
-                  target_model_update, extra_metrics=None):
-    updates, opt_state = tx.update(grads, state.opt_state, state.params)
-    params = optax.apply_updates(state.params, updates)
-    new_step = state.step + 1
-    target_params = update_target(state.target_params, params, new_step,
-                                  target_model_update)
-    metrics = {
-        "learner/critic_loss": loss,
-        "learner/q_mean": q_mean,
-        "learner/grad_norm": global_norm(grads),
-    }
-    if extra_metrics:
-        metrics.update(extra_metrics)
+                  target_model_update, axis_name=None, extra_metrics=None):
+    """Gradient mean over ``axis_name`` (if any), Adam, target sync and
+    metrics: the ``train.optimizer`` phase of both sequence steps."""
+    with jax.named_scope(PHASE_OPTIMIZER):
+        if axis_name is not None:
+            grads = jax.lax.pmean(grads, axis_name)
+        updates, opt_state = tx.update(grads, state.opt_state, state.params)
+        params = optax.apply_updates(state.params, updates)
+        new_step = state.step + 1
+        target_params = update_target(state.target_params, params, new_step,
+                                      target_model_update)
+        metrics = {
+            "learner/critic_loss": loss,
+            "learner/q_mean": q_mean,
+            "learner/grad_norm": global_norm(grads),
+        }
+        if extra_metrics:
+            metrics.update(extra_metrics)
     return (TrainState(params, target_params, opt_state, new_step),
             metrics, seq_pr)
 
@@ -183,31 +200,40 @@ def build_drqn_train_step(
 
     def step(state: TrainState, batch: SegmentBatch):
         T = batch.action.shape[1]
-        obs = batch.obs
-        if packed_frames:
-            obs = unpack_frame_stacks(obs, packed_frames, T)
-        obs_tm = jnp.moveaxis(obs, 0, 1)            # (T+1, B, *S)
+        with jax.named_scope(PHASE_GATHER):
+            obs = batch.obs
+            if packed_frames:
+                obs = unpack_frame_stacks(obs, packed_frames, T)
+            obs_tm = jnp.moveaxis(obs, 0, 1)        # (T+1, B, *S)
         train_len = T - burn_in
         carry0 = (batch.c0, batch.h0)
 
+        def burn_and_unroll(params, phase, refresh=lambda carry: carry):
+            """Refresh the stored state over the burn-in prefix, then
+            unroll the train window from it: (train_len+1, B, A)."""
+            with jax.named_scope(SCOPE_BURN_IN):
+                carry, _ = (unroll(apply_fn, params, carry0,
+                                   obs_tm[:burn_in], phase)
+                            if burn_in else (carry0, None))
+                carry = refresh(carry)
+            with jax.named_scope(SCOPE_UNROLL):
+                return unroll(apply_fn, params, carry, obs_tm[burn_in:],
+                              phase)[1]
+
         # target-side state refresh + full unroll (no gradients flow here)
-        tcarry, _ = (unroll(apply_fn, state.target_params, carry0,
-                            obs_tm[:burn_in])
-                     if burn_in else (carry0, None))
-        _, q_target_tm = unroll(apply_fn, state.target_params, tcarry,
-                                obs_tm[burn_in:])   # (train_len+1, B, A)
+        with jax.named_scope(PHASE_TARGET):
+            q_target_tm = burn_and_unroll(state.target_params, PHASE_TARGET)
 
         # time-major views of the train window
-        a_tm = jnp.moveaxis(batch.action, 0, 1)[burn_in:]        # (L, B)
-        r_tm = jnp.moveaxis(batch.reward, 0, 1)[burn_in:]
-        d_tm = jnp.moveaxis(batch.terminal, 0, 1)[burn_in:]
-        m_tm = jnp.moveaxis(batch.mask, 0, 1)[burn_in:]
+        with jax.named_scope(PHASE_ONLINE):
+            a_tm = jnp.moveaxis(batch.action, 0, 1)[burn_in:]    # (L, B)
+            r_tm = jnp.moveaxis(batch.reward, 0, 1)[burn_in:]
+            d_tm = jnp.moveaxis(batch.terminal, 0, 1)[burn_in:]
+            m_tm = jnp.moveaxis(batch.mask, 0, 1)[burn_in:]
 
         def loss_fn(params):
-            bcarry, _ = (unroll(apply_fn, params, carry0, obs_tm[:burn_in])
-                         if burn_in else (carry0, None))
-            bcarry = jax.lax.stop_gradient(bcarry)
-            _, q_tm = unroll(apply_fn, params, bcarry, obs_tm[burn_in:])
+            q_tm = burn_and_unroll(params, PHASE_ONLINE,
+                                   jax.lax.stop_gradient)
             q_sel = jnp.take_along_axis(
                 q_tm[:train_len], a_tm[..., None].astype(jnp.int32),
                 axis=-1)[..., 0]                                  # (L, B)
@@ -219,12 +245,10 @@ def build_drqn_train_step(
                 q_sel, target, m_tm, batch.weight, priority_eta)
             return loss, (seq_pr, jnp.mean(jnp.max(q_tm, axis=-1)))
 
-        (loss, (seq_pr, q_mean)), grads = jax.value_and_grad(
+        (loss, (seq_pr, q_mean)), grads = online_grad(
             loss_fn, has_aux=True)(state.params)
-        if axis_name is not None:
-            grads = jax.lax.pmean(grads, axis_name)
         return _apply_update(state, grads, loss, seq_pr, q_mean, tx,
-                             target_model_update)
+                             target_model_update, axis_name)
 
     return finite_guard(step) if guard else step
 
@@ -279,12 +303,15 @@ def build_dtqn_train_step(
         train_len = T - burn_in
         # (L+1, B, A) over the train window, burn-in kept as context
         to_tm = lambda q: jnp.moveaxis(q, 0, 1)[burn_in:]
-        q_target_tm = to_tm(target_apply(state.target_params, batch.obs))
+        with jax.named_scope(PHASE_TARGET):
+            q_target_tm = to_tm(target_apply(state.target_params,
+                                             batch.obs))
 
-        a_tm = jnp.moveaxis(batch.action, 0, 1)[burn_in:]
-        r_tm = jnp.moveaxis(batch.reward, 0, 1)[burn_in:]
-        d_tm = jnp.moveaxis(batch.terminal, 0, 1)[burn_in:]
-        m_tm = jnp.moveaxis(batch.mask, 0, 1)[burn_in:]
+        with jax.named_scope(PHASE_ONLINE):
+            a_tm = jnp.moveaxis(batch.action, 0, 1)[burn_in:]
+            r_tm = jnp.moveaxis(batch.reward, 0, 1)[burn_in:]
+            d_tm = jnp.moveaxis(batch.terminal, 0, 1)[burn_in:]
+            m_tm = jnp.moveaxis(batch.mask, 0, 1)[burn_in:]
 
         def loss_fn(params):
             q, aux = split_apply(params, batch.obs)
@@ -301,12 +328,10 @@ def build_dtqn_train_step(
             loss = loss + aux_weight * aux
             return loss, (seq_pr, jnp.mean(jnp.max(q_tm, axis=-1)), aux)
 
-        (loss, (seq_pr, q_mean, aux)), grads = jax.value_and_grad(
+        (loss, (seq_pr, q_mean, aux)), grads = online_grad(
             loss_fn, has_aux=True)(state.params)
-        if axis_name is not None:
-            grads = jax.lax.pmean(grads, axis_name)
         extra = {"learner/moe_aux": aux} if aux_weight else None
         return _apply_update(state, grads, loss, seq_pr, q_mean, tx,
-                             target_model_update, extra)
+                             target_model_update, axis_name, extra)
 
     return finite_guard(step) if guard else step

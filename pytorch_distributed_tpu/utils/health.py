@@ -55,6 +55,8 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from pytorch_distributed_tpu.utils.profiling import PHASE_OPTIMIZER
+
 # metrics key every consumer of the guard keys on: 1.0 for a skipped
 # (non-finite) substep, 0.0 otherwise; summed — not last-sampled — over
 # fused multi-step dispatches (reduce_scan_metrics)
@@ -119,15 +121,16 @@ def finite_guard(step_fn):
 
     def guarded(state, batch):
         new_state, metrics, td = step_fn(state, batch)
-        ok = jnp.all(jnp.isfinite(td))
-        for v in metrics.values():
-            ok = ok & jnp.all(jnp.isfinite(v))
-        sel = lambda n, o: jax.tree_util.tree_map(
-            lambda a, b: jnp.where(ok, a, b), n, o)
-        out_state = sel(new_state, state)
-        out_td = jnp.where(ok, td, jnp.zeros_like(td))
-        metrics = dict(metrics)
-        metrics[SKIPPED_KEY] = 1.0 - ok.astype(jnp.float32)
+        with jax.named_scope(PHASE_OPTIMIZER):
+            ok = jnp.all(jnp.isfinite(td))
+            for v in metrics.values():
+                ok = ok & jnp.all(jnp.isfinite(v))
+            sel = lambda n, o: jax.tree_util.tree_map(
+                lambda a, b: jnp.where(ok, a, b), n, o)
+            out_state = sel(new_state, state)
+            out_td = jnp.where(ok, td, jnp.zeros_like(td))
+            metrics = dict(metrics)
+            metrics[SKIPPED_KEY] = 1.0 - ok.astype(jnp.float32)
         return out_state, metrics, out_td
 
     return guarded
@@ -143,10 +146,11 @@ def reduce_scan_metrics(metrics):
     import jax
     import jax.numpy as jnp
 
-    if not isinstance(metrics, dict):
-        return jax.tree_util.tree_map(lambda x: x[-1], metrics)
-    return {k: (jnp.sum(v, axis=0) if k == SKIPPED_KEY else v[-1])
-            for k, v in metrics.items()}
+    with jax.named_scope(PHASE_OPTIMIZER):
+        if not isinstance(metrics, dict):
+            return jax.tree_util.tree_map(lambda x: x[-1], metrics)
+        return {k: (jnp.sum(v, axis=0) if k == SKIPPED_KEY else v[-1])
+                for k, v in metrics.items()}
 
 
 def suppress_writeback(ok_flag, updated_replay, prior_replay):

@@ -90,6 +90,17 @@ def enable_compile_cache() -> Optional[str]:
         return None
     cache_dir = compile_cache_dir()
     jax.config.update("jax_compilation_cache_dir", cache_dir)
+    # The names a profiler trace shows (utils/profiling.DEVICE_PHASES,
+    # flax module paths) are the EXECUTABLE's HLO metadata.  JAX's default
+    # key strips debug info, so a tree with new scopes loads the
+    # executable an older tree cached and its traces keep the old names
+    # (seen on the chip, PR 24: the dp4 step of the scoped tree, run after
+    # its parent against one cache directory, showed no scope at all).
+    # With the metadata in the key a program's entry also depends on the
+    # checkout's path and on line numbers along its call stack, as every
+    # program that holds a Pallas kernel already did (Mosaic serialises
+    # locations into the kernel body): an edit there recompiles once.
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     return cache_dir
 
 

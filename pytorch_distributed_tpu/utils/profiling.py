@@ -1,13 +1,19 @@
 """Tracing / profiling subsystem.
 
 The reference has none — only stdout banners and TensorBoard scalars
-(SURVEY.md §5 "tracing: none").  Two first-class tools here:
+(SURVEY.md §5 "tracing: none").  Three first-class tools here:
 
+- the **device phase vocabulary** (``PHASE_*``): the ``jax.named_scope``
+  names every learner step program and ring feed enters where the work
+  is written, so a device trace reads in the program's words and not in
+  the compiler's (``copy.33``, ``fusion.563``).
 - ``StepTimer``: cheap per-role wall-time accounting.  Workers wrap their
   hot-loop phases (act / env.step / feed / learn / drain / publish) and the
   accumulated per-phase seconds flow into the metrics stream on the normal
   logger cadence, so "where does the step time go" is a dashboard read, not
-  a guess.
+  a guess.  Every ``phase()`` is also a ``jax.profiler.TraceAnnotation``
+  named ``<prefix>/<phase>``: a host span on the clock the device events
+  of a profiler trace are on.
 - ``trace``: a context manager around ``jax.profiler.trace`` that captures
   a real XLA trace (TensorBoard-viewable) for a bounded window, gated so it
   can be left in production code and switched on with an env var
@@ -22,12 +28,50 @@ together with the env knobs.
 from __future__ import annotations
 
 import contextlib
+import functools
 import os
 import re
 import threading
 import time
 import warnings
 from typing import Dict, Iterator, Optional
+
+# ---------------------------------------------------------------------------
+# device phases: the ONE vocabulary of jax.named_scope names (README
+# "Observability", PERF.md section 3).  A scope is metadata only: it lands
+# in each HLO op's ``op_name`` path (the profiler's ``tf_op``, xprof's
+# framework-op view) and changes neither the compiled program nor its
+# compile-cache key.  Dotted, never slashed: inside ``jvp(...)`` /
+# ``transpose(...)`` a name stays one path component only without a "/".
+# benchmark/harness/phases.py reads them back out of a trace.
+# ---------------------------------------------------------------------------
+PHASE_DRAW = "replay.draw"            # index draw + IS weights
+PHASE_GATHER = "replay.gather"        # row/segment gathers, frame unpacking
+PHASE_TARGET = "train.target"         # every pass through target_params
+PHASE_ONLINE = "train.online"         # online forward + loss (and, as
+#                                       transpose(jvp(train.online)), backward)
+PHASE_OPTIMIZER = "train.optimizer"   # pmean, clip, Adam, target sync,
+#                                       finite-guard select, metrics
+PHASE_WRITEBACK = "replay.writeback"  # |TD| priority scatter + its guard
+PHASE_FEED = "replay.feed"            # ring writes (set-up, closed loop)
+DEVICE_PHASES = (PHASE_DRAW, PHASE_GATHER, PHASE_TARGET, PHASE_ONLINE,
+                 PHASE_OPTIMIZER, PHASE_WRITEBACK, PHASE_FEED)
+# the recurrent family nests these inside train.target / train.online
+SCOPE_BURN_IN = "burn_in"
+SCOPE_UNROLL = "unroll"
+
+
+@functools.lru_cache(maxsize=None)
+def _trace_annotation():
+    """``jax.profiler.TraceAnnotation``, imported at the first timed phase
+    of the process and not at module load; None where JAX cannot be
+    imported: such a role keeps its timer and has no profiler to write
+    into."""
+    try:
+        from jax.profiler import TraceAnnotation
+    except ImportError:
+        return None
+    return TraceAnnotation
 
 
 class StepTimer:
@@ -43,12 +87,24 @@ class StepTimer:
         self._max: Dict[str, float] = {}
         self._n: Dict[str, int] = {}
         self._last_wall: Dict[str, float] = {}
+        # seconds of the phase that closed last: lets a caller book ONE
+        # clock reading under a second name (a Tracer span) instead of
+        # timing the same stretch twice
+        self.last_s = 0.0
 
     @contextlib.contextmanager
     def phase(self, name: str) -> Iterator[None]:
+        """Time the enclosed block under ``name`` and, while a profiler
+        trace is being captured, show it there as the host span
+        ``<prefix>/<name>`` (a TraceMe: one atomic load when no trace is
+        active)."""
+        annotate = _trace_annotation()
+        span = (annotate(f"{self.prefix}/{name}") if annotate is not None
+                else contextlib.nullcontext())
         t0 = time.perf_counter()
         try:
-            yield
+            with span:
+                yield
         finally:
             self.add(name, time.perf_counter() - t0)
 
@@ -58,6 +114,7 @@ class StepTimer:
         pipelined actor loop books dispatch+sync both under their own
         phases and under the serial loop's ``act`` so dashboards stay
         comparable across schedules)."""
+        self.last_s = seconds
         self._acc[name] = self._acc.get(name, 0.0) + seconds
         if seconds > self._max.get(name, 0.0):
             self._max[name] = seconds
