@@ -59,13 +59,16 @@ def announce_startup(opt: Options, *, mesh, steps_per_dispatch: int,
     """The learner's ONE start-up line: what the chip path resolved to —
     platform, device kind and count, mesh axes, ``steps_per_dispatch``,
     which PER sampler and which torso were selected, how parameters are
-    published, and the HBM bytes each device holds once the ring is
-    attached.  Printed once and appended to ``<log_dir>/startup.jsonl``
-    (utils/helpers.record_startup), so no branch the learner takes on
-    the backend it found is silent; ``chip_smoke.py`` asserts on it."""
+    published, the format an HBM ring keeps its observation rows in with
+    the bytes it holds (``replay/hbm_bytes``), and the HBM bytes each
+    device holds once the ring is attached.  Printed once and appended
+    to ``<log_dir>/startup.jsonl`` (utils/helpers.record_startup), so no
+    branch the learner takes on the backend it found is silent;
+    ``chip_smoke.py`` asserts on it."""
     import jax
 
     from pytorch_distributed_tpu.factory import select_torso
+    from pytorch_distributed_tpu.utils.bandwidth import replay_nbytes
     from pytorch_distributed_tpu.utils.helpers import record_startup
 
     axes = ({a: int(n) for a, n in mesh.shape.items() if n > 1} or {"dp": 1}
@@ -77,7 +80,9 @@ def announce_startup(opt: Options, *, mesh, steps_per_dispatch: int,
         steps_per_dispatch=int(steps_per_dispatch),
         per_sampler=getattr(replay, "sampler", "n/a"),
         torso=select_torso(opt) if opt.agent_type == "dqn" else "xla",
-        publish=publish, hbm_bytes_in_use=hbm)
+        publish=publish, ring_rows=getattr(replay, "stored_rows", "n/a"),
+        replay_hbm_bytes=replay_nbytes(getattr(replay, "state", None)),
+        hbm_bytes_in_use=hbm)
     mesh_s = ("none" if axes is None
               else "x".join(f"{a}{n}" for a, n in axes.items()))
     print(f"[learner] start-up: platform={rec['platform']} "
@@ -85,7 +90,9 @@ def announce_startup(opt: Options, *, mesh, steps_per_dispatch: int,
           f"devices={rec['device_count']} mesh={mesh_s} "
           f"steps_per_dispatch={rec['steps_per_dispatch']} "
           f"per_sampler={rec['per_sampler']} torso={rec['torso']} "
-          f"publish={publish} hbm_bytes_in_use={hbm}", flush=True)
+          f"publish={publish} ring_rows={rec['ring_rows']} "
+          f"replay/hbm_bytes={rec['replay_hbm_bytes']} "
+          f"hbm_bytes_in_use={hbm}", flush=True)
     return rec
 
 

@@ -27,7 +27,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from pytorch_distributed_tpu.memory.device_replay import (
-    DeviceReplay, jit_feed, ring_write, ring_write_masked, round_capacity,
+    DeviceReplay, RowCodec, gather_rows, jit_feed, ring_write,
+    ring_write_masked, round_capacity,
 )
 from pytorch_distributed_tpu.utils.experience import (
     REPLAY_FIELDS, Batch, Transition,
@@ -46,7 +47,7 @@ __apex_fn_owners__ = {
 
 
 class PerReplayState(NamedTuple):
-    state0: jax.Array
+    state0: jax.Array        # stored rows (device_replay.py RowCodec)
     action: jax.Array
     reward: jax.Array
     gamma_n: jax.Array
@@ -57,6 +58,7 @@ class PerReplayState(NamedTuple):
     max_priority: jax.Array  # () f32, running max of p^alpha
     pos: jax.Array           # int32 write cursor
     fill: jax.Array          # int32 valid rows
+    codec: RowCodec          # static: how state0/state1 are stored
 
 
 def per_feed(state: PerReplayState, chunk: Transition,
@@ -116,18 +118,8 @@ def per_sample(state: PerReplayState, key: jax.Array, batch_size: int,
         min_p = (jnp.min(jnp.where(p > 0, p, jnp.inf))
                  / jnp.maximum(total, 1e-12))
         max_w = (fill * jnp.maximum(min_p, 1e-12)) ** (-beta)
-        weights = weights / jnp.maximum(max_w, 1e-12)
-    with jax.named_scope(PHASE_GATHER):
-        return Batch(
-            state0=state.state0[idx],
-            action=state.action[idx],
-            reward=state.reward[idx],
-            gamma_n=state.gamma_n[idx],
-            state1=state.state1[idx],
-            terminal1=state.terminal1[idx],
-            weight=weights.astype(jnp.float32),
-            index=idx,
-        )
+        weights = (weights / jnp.maximum(max_w, 1e-12)).astype(jnp.float32)
+    return gather_rows(state, idx, weights)
 
 
 PRIORITY_XRAY_LOG10_LO = -6.0   # log10 bucket floor (p^alpha units)
@@ -260,6 +252,7 @@ class DevicePerReplay(DeviceReplay):
             max_priority=self._alloc((), jnp.float32, sharded=False) + 1.0,
             pos=base.pos,
             fill=base.fill,
+            codec=base.codec,
         )
 
     def beta(self, step: int) -> float:
@@ -294,6 +287,25 @@ class DevicePerReplay(DeviceReplay):
             SKIPPED_KEY, reduce_scan_metrics, suppress_writeback,
         )
 
+        # Only the priority leaves change inside a dispatch.  They alone
+        # are carried through the scans; the row columns are closed over
+        # and handed back as they came in, so donation aliases them
+        # straight through and no loop ever holds a second ring.
+        def leaves(rs: PerReplayState):
+            return rs.priority, rs.max_priority
+
+        def with_leaves(rs: PerReplayState, pri) -> PerReplayState:
+            return rs._replace(priority=pri[0], max_priority=pri[1])
+
+        def writeback(rs: PerReplayState, idx, td_abs, skipped):
+            """The leaves after one minibatch's |TD| write-back; a
+            skipped (non-finite, guarded) minibatch must not scatter its
+            zeroed TD over real priorities."""
+            new = leaves(per_update_priorities(rs, idx, td_abs, alpha))
+            if skipped is None:
+                return new
+            return suppress_writeback(skipped, new, leaves(rs))
+
         if megabatch > 1:
             assert megabatch_step is not None, \
                 "megabatch > 1 needs the factory's megabatch step"
@@ -312,18 +324,17 @@ class DevicePerReplay(DeviceReplay):
                                              sample_fn=draw_fn))(kset)
                 ts, metrics, td_abs, ok = megabatch_step(ts, batches)
 
-                def writeback(rs_c, x):
+                def land(pri, x):
                     idx, td, ok_i = x
-                    rs_new = per_update_priorities(rs_c, idx, td, alpha)
                     # suppress_writeback takes the SKIPPED flag (1.0 =
                     # skipped); ok is the validity mask
-                    return suppress_writeback(1.0 - ok_i, rs_new,
-                                              rs_c), None
+                    return writeback(with_leaves(rs, pri), idx, td,
+                                     1.0 - ok_i), None
 
                 with jax.named_scope(PHASE_WRITEBACK):
-                    rs, _ = jax.lax.scan(writeback, rs,
-                                         (batches.index, td_abs, ok))
-                return ts, rs, metrics
+                    pri, _ = jax.lax.scan(land, leaves(rs),
+                                          (batches.index, td_abs, ok))
+                return ts, pri, metrics
 
             def multi_mega(ts, rs, keys, beta):
                 with jax.named_scope(PHASE_DRAW):
@@ -331,41 +342,43 @@ class DevicePerReplay(DeviceReplay):
                                          *keys.shape[1:])
 
                 def body(carry, kset):
-                    ts, rs = carry
-                    ts, rs, metrics = one_group(ts, rs, kset, beta)
-                    return (ts, rs), metrics
+                    ts, pri = carry
+                    ts, pri, metrics = one_group(
+                        ts, with_leaves(rs, pri), kset, beta)
+                    return (ts, pri), metrics
 
-                (ts, rs), metrics = jax.lax.scan(body, (ts, rs), gkeys)
-                return ts, rs, reduce_scan_metrics(metrics)
+                (ts, pri), metrics = jax.lax.scan(
+                    body, (ts, leaves(rs)), gkeys)
+                return ts, with_leaves(rs, pri), reduce_scan_metrics(metrics)
 
             return jax.jit(multi_mega,
                            donate_argnums=(0, 1) if donate else ())
 
-        def one(ts, rs: PerReplayState, key, beta):
+        def substep(ts, rs: PerReplayState, key, beta):
             batch = per_sample(rs, key, batch_size, beta, sample_fn=draw_fn)
             ts, metrics, td_abs = train_step(ts, batch)
             with jax.named_scope(PHASE_WRITEBACK):
-                rs_new = per_update_priorities(rs, batch.index, td_abs,
-                                               alpha)
-                skipped = (metrics.get(SKIPPED_KEY)
-                           if isinstance(metrics, dict) else None)
-                if skipped is not None:
-                    # guarded step: a skipped (non-finite) substep must
-                    # not scatter its zeroed TD over real priorities
-                    rs_new = suppress_writeback(skipped, rs_new, rs)
-            return ts, rs_new, metrics
+                pri = writeback(rs, batch.index, td_abs,
+                                metrics.get(SKIPPED_KEY)
+                                if isinstance(metrics, dict) else None)
+            return ts, pri, metrics
 
         if steps_per_call <= 1:
+            def one(ts, rs, key, beta):
+                ts, pri, metrics = substep(ts, rs, key, beta)
+                return ts, with_leaves(rs, pri), metrics
+
             return jax.jit(one, donate_argnums=(0, 1) if donate else ())
 
         def multi(ts, rs, keys, beta):
             def body(carry, key):
-                ts, rs = carry
-                ts, rs, metrics = one(ts, rs, key, beta)
-                return (ts, rs), metrics
+                ts, pri = carry
+                ts, pri, metrics = substep(ts, with_leaves(rs, pri), key,
+                                           beta)
+                return (ts, pri), metrics
 
-            (ts, rs), metrics = jax.lax.scan(body, (ts, rs), keys)
-            return ts, rs, reduce_scan_metrics(metrics)
+            (ts, pri), metrics = jax.lax.scan(body, (ts, leaves(rs)), keys)
+            return ts, with_leaves(rs, pri), reduce_scan_metrics(metrics)
 
         return jax.jit(multi, donate_argnums=(0, 1) if donate else ())
 
@@ -373,21 +386,8 @@ class DevicePerReplay(DeviceReplay):
 
     def snapshot(self) -> dict:
         st = jax.device_get(self.state)
-        fill, pos = int(st.fill), int(st.pos)
-        shift = -pos if fill == self.capacity else 0
-        out = {k: np.roll(np.asarray(getattr(st, k)), shift,
-                          axis=0)[:fill].copy()
-               for k in REPLAY_FIELDS}
-        if self.channels_last:  # public schema is NCHW (see DeviceReplay)
-            from pytorch_distributed_tpu.memory.device_replay import (
-                snapshot_states_to_nchw,
-            )
-
-            out = snapshot_states_to_nchw(out)
-        out["prov"] = np.roll(np.asarray(st.prov), shift,
-                              axis=0)[:fill].astype(np.int64)
-        out["leaf_priority"] = np.roll(
-            np.asarray(st.priority), shift)[:fill].copy()
+        out = self._aged_columns(st, REPLAY_FIELDS + ("prov", "priority"))
+        out["leaf_priority"] = out.pop("priority")
         # stored p^alpha on device; snapshot in the shared UNexponentiated
         # unit so host<->device PER resumes agree
         mx = float(np.asarray(st.max_priority))

@@ -13,11 +13,24 @@ the rings in place.  Capacity is statically padded; the write cursor wraps
 with modular index arithmetic (the jit-safe equivalent of the reference's
 circular cursor, reference core/memories/shared_memory.py:45-57).
 
+Stored format of the observation columns (``RowCodec``): a row of
+uint8 frames is kept as ONE line of 32-bit words, ``uint32[N, lanes]`` with
+``lanes`` a multiple of 128.  The TPU's default layout of an array is the
+one with least padding, so ``u8[N, 4, 84, 84]`` (and ``u32[N, 7056]``
+alike) lands with the ROW index minor-most, and every program that gathers
+or scatters rows first copies the whole ring into row-major scratch and
+back (nine tenths of the flagship learner's chip and 9 GB of scratch,
+PERF.md PR 22/24).  A minor dimension that fills whole 128-lane tiles
+keeps the array row-major at rest, so a row gather is a gather and a row
+write a scatter, in place; the feed packs its chunk and the sampler unpacks
+its batch (a few MB), and nothing else sees the words.
+
 No reference equivalent — the reference buffer is host memory only.
 """
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import NamedTuple, Optional, Tuple
 
@@ -34,12 +47,91 @@ from pytorch_distributed_tpu.utils.profiling import (
 )
 
 
+LANES = 128  # 32-bit lanes of one TPU tile row
+RESTORE_ROWS = 4096  # rows per feed of a checkpoint restore
+
+
+@jax.tree_util.register_static
+@dataclasses.dataclass(frozen=True)
+class RowCodec:
+    """Storage format of a ring's observation columns (``state0``,
+    ``state1``), decided from what a row is: a row of one-byte integers
+    with more than one dimension whose byte count divides by 4 is stored
+    as ``lanes`` uint32 words (module docstring); already-flat or wider
+    rows (the control tasks' float32 vectors) are stored as they are.
+
+    Word ``j`` holds byte ``j`` of each quarter of the flattened row (for
+    (4, H, W) frames: pixel ``j`` of the four frames), so ``pack`` and
+    ``unpack`` are shifts over whole ``(n, words)`` planes: exact in both
+    directions, independent of byte order, and with no 4-wide minor
+    dimension for the compiler to tile.
+    A static pytree node: it rides in the ring state, costs no leaf, and
+    tells every jitted reader and writer the format it was built with."""
+    row_shape: Tuple[int, ...]   # one row in store order: (C,H,W)/(H,W,C)
+    dtype: np.dtype
+
+    @property
+    def words(self) -> int:
+        """uint32 words a packed row fills; 0 = stored as it is."""
+        nbytes = int(np.prod(self.row_shape))
+        packs = (self.dtype.itemsize == 1 and self.dtype.kind in "iu"
+                 and len(self.row_shape) > 1 and nbytes % 4 == 0)
+        return nbytes // 4 if packs else 0
+
+    @property
+    def stored_row(self) -> Tuple[int, ...]:
+        return ((-(-self.words // LANES) * LANES,) if self.words
+                else tuple(self.row_shape))
+
+    @property
+    def stored_dtype(self) -> np.dtype:
+        return np.dtype(np.uint32) if self.words else self.dtype
+
+    def pack(self, rows):
+        """Public ``(n, *row_shape)`` -> stored ``(n, *stored_row)``, inside
+        the feed program."""
+        if not self.words:
+            return rows
+        q = _as(jnp.reshape(rows, (rows.shape[0], 4, self.words)), np.uint8)
+        # one quarter at a time, widened after it is sliced: the whole
+        # chunk in 32 bits would be four chunks of working set
+        w = q[:, 0].astype(jnp.uint32)
+        for k in (1, 2, 3):
+            w = w | (q[:, k].astype(jnp.uint32) << (8 * k))
+        return jnp.pad(w, ((0, 0), (0, self.stored_row[0] - self.words)))
+
+    def unpack(self, stored):
+        """Stored ``(..., *stored_row)`` -> public ``(..., *row_shape)``,
+        bit for bit what ``pack`` was given; on a fetched (numpy) column
+        it runs on the host (snapshot).  One broadcast shift over the
+        gathered words: the compiler turns it into a single pass that
+        writes the batch in the layout the first convolution reads
+        (PERF.md PR 25: stacking four shifted planes cost 0.09 ms more
+        per update)."""
+        if not self.words:
+            return stored
+        xp = np if isinstance(stored, np.ndarray) else jnp
+        w = stored[..., None, :self.words]
+        shifts = (xp.arange(4, dtype=xp.uint32) * 8)[:, None]
+        q = ((w >> shifts) & 0xFF).astype(xp.uint8)
+        return _as(q, self.dtype).reshape(*stored.shape[:-1],
+                                          *self.row_shape)
+
+
+def _as(x, dtype):
+    """Reinterpret one-byte integers (int8 <-> uint8), bits untouched."""
+    if x.dtype == dtype:
+        return x
+    return (x.view(dtype) if isinstance(x, np.ndarray)
+            else jax.lax.bitcast_convert_type(x, dtype))
+
+
 class ReplayState(NamedTuple):
-    state0: jax.Array
+    state0: jax.Array     # (N, *codec.stored_row): read through the codec
     action: jax.Array
     reward: jax.Array
     gamma_n: jax.Array
-    state1: jax.Array
+    state1: jax.Array     # stored like state0
     terminal1: jax.Array
     # data-plane provenance columns (ISSUE 8): (actor_id, env_slot,
     # param_version, birth_step) per row as int32 (-1 = unknown) — kept
@@ -48,6 +140,7 @@ class ReplayState(NamedTuple):
     prov: jax.Array       # (N, 4) int32
     pos: jax.Array        # int32 write cursor
     fill: jax.Array       # int32 number of valid rows
+    codec: RowCodec       # static: how state0/state1 are stored
 
 
 # single-owner declaration for the module-level ring mutators
@@ -63,18 +156,21 @@ __apex_fn_owners__ = {
 
 def ring_write(state, chunk: Transition, capacity: int):
     """Write a chunk at the cursor of ANY ring state carrying the six-array
-    schema plus pos/fill (ReplayState, and device_per.py's PerReplayState).
-    Returns (state', idx) so extended schemas can set their extra
-    per-row fields at the same slots."""
+    schema plus pos/fill/codec (ReplayState, and device_per.py's
+    PerReplayState); the chunk's states arrive in store order and are
+    packed here, inside the feed program (RowCodec).  Returns
+    (state', idx) so extended schemas can set their extra per-row fields
+    at the same slots."""
     n = chunk.reward.shape[0]
+    pack = state.codec.pack
     with jax.named_scope(PHASE_FEED):
         idx = (state.pos + jnp.arange(n, dtype=jnp.int32)) % capacity
         repl = dict(
-            state0=state.state0.at[idx].set(chunk.state0),
+            state0=state.state0.at[idx].set(pack(chunk.state0)),
             action=state.action.at[idx].set(chunk.action),
             reward=state.reward.at[idx].set(chunk.reward),
             gamma_n=state.gamma_n.at[idx].set(chunk.gamma_n),
-            state1=state.state1.at[idx].set(chunk.state1),
+            state1=state.state1.at[idx].set(pack(chunk.state1)),
             terminal1=state.terminal1.at[idx].set(chunk.terminal1),
             pos=(state.pos + n) % capacity,
             fill=jnp.minimum(state.fill + n, capacity),
@@ -112,12 +208,13 @@ def ring_write_masked(state, chunk: Transition, valid,
         idx = jnp.where(valid, (state.pos + offs) % capacity, capacity)
         total = jnp.sum(valid.astype(jnp.int32))
         wr = lambda buf, x: buf.at[idx].set(x, mode="drop")
+        pack = state.codec.pack
         repl = dict(
-            state0=wr(state.state0, chunk.state0),
+            state0=wr(state.state0, pack(chunk.state0)),
             action=wr(state.action, chunk.action),
             reward=wr(state.reward, chunk.reward),
             gamma_n=wr(state.gamma_n, chunk.gamma_n),
-            state1=wr(state.state1, chunk.state1),
+            state1=wr(state.state1, pack(chunk.state1)),
             terminal1=wr(state.terminal1, chunk.terminal1),
             pos=(state.pos + total) % capacity,
             fill=jnp.minimum(state.fill + total, capacity),
@@ -134,11 +231,10 @@ def ring_write_masked(state, chunk: Transition, valid,
 
 def chunk_to_nhwc(chunk: Transition) -> Transition:
     """Transpose a chunk's (N, C, H, W) states to (N, H, W, C) — runs
-    inside the jitted feed, so a channels-last ring pays the layout copy
+    inside the jitted feed, so a channels-last ring pays the transpose
     ONCE per ingested row instead of every time the row is sampled (each
-    row is trained on ~replay_ratio times, and each update runs 3 CNN
-    forwards that each needed the copy: ~25% of device time in the XLA
-    profile, tools/mfu_probe.py)."""
+    row is trained on ~replay_ratio times).  What that buys on the chip
+    is not measured."""
     t = lambda x: jnp.transpose(x, (0, 2, 3, 1))
     with jax.named_scope(PHASE_FEED):
         return chunk._replace(state0=t(chunk.state0),
@@ -156,15 +252,6 @@ def jit_feed(feed_fn, channels_last: bool = False):
                        else chunk)
 
     return jax.jit(feed_chunk, donate_argnums=0)
-
-
-def snapshot_states_to_nchw(out: dict) -> dict:
-    """Roll a channels-last snapshot's states back to the public NCHW
-    schema (checkpoints are layout-independent); shared by both ring
-    classes."""
-    for k in ("state0", "state1"):
-        out[k] = np.ascontiguousarray(np.transpose(out[k], (0, 3, 1, 2)))
-    return out
 
 
 def round_capacity(capacity: int, mesh: Optional[jax.sharding.Mesh],
@@ -192,17 +279,27 @@ def sample_rows(state: ReplayState, key: jax.Array,
     the driver dryrun can fuse it into their train-step programs."""
     with jax.named_scope(PHASE_DRAW):
         idx = jax.random.randint(key, (batch_size,), 0,
-                                 jnp.maximum(state.fill, 1))
+                                 jnp.maximum(state.fill, 1), jnp.int32)
+        weight = jnp.ones((batch_size,), dtype=jnp.float32)
+    return gather_rows(state, idx, weight)
+
+
+def gather_rows(state, idx: jax.Array, weight: jax.Array) -> Batch:
+    """Rows ``idx`` of any ring state as a ``Batch`` in the public (store
+    order) shapes and dtypes: the one reader of the stored observation
+    columns inside a program — the gathered words are unpacked here, on
+    the batch only (RowCodec)."""
+    unpack = state.codec.unpack
     with jax.named_scope(PHASE_GATHER):
         return Batch(
-            state0=state.state0[idx],
+            state0=unpack(state.state0[idx]),
             action=state.action[idx],
             reward=state.reward[idx],
             gamma_n=state.gamma_n[idx],
-            state1=state.state1[idx],
+            state1=unpack(state.state1[idx]),
             terminal1=state.terminal1[idx],
-            weight=jnp.ones((batch_size,), dtype=jnp.float32),
-            index=idx.astype(jnp.int32),
+            weight=weight,
+            index=idx,
         )
 
 
@@ -307,8 +404,9 @@ class DeviceReplay:
         # feeds transpose on device at ingest (chunk_to_nhwc), snapshots
         # roll back to the public NCHW schema
         self.channels_last = bool(channels_last and len(state_shape) == 3)
-        self._store_shape = (tuple(state_shape[1:]) + (state_shape[0],)
-                             if self.channels_last else tuple(state_shape))
+        self.codec = RowCodec(
+            tuple(state_shape[1:]) + (state_shape[0],)
+            if self.channels_last else tuple(state_shape), self.state_dtype)
 
         if mesh is not None:
             ndev = mesh.shape[axis]
@@ -329,51 +427,66 @@ class DeviceReplay:
             sample_rows, static_argnames="batch_size", donate_argnums=())
 
     def _alloc(self, shape, dtype, sharded: bool = True):
-        arr = jnp.zeros(shape, dtype=dtype)
-        if self._row_sharding is not None:
-            arr = jax.device_put(
-                arr,
-                self._row_sharding if sharded else self._scalar_sharding)
-        return arr
+        """Zeros, made in their sharding: a column built whole on one
+        device and then spread holds that device to two whole columns
+        while the next is built (12.7 GB of dp4's device 0, PERF.md)."""
+        return jnp.zeros(shape, dtype=dtype, device=(
+            self._row_sharding if sharded else self._scalar_sharding))
 
     def _init_state(self) -> ReplayState:
         N = self.capacity
         alloc = self._alloc
+        stored = (N, *self.codec.stored_row), self.codec.stored_dtype
         return ReplayState(
-            state0=alloc((N, *self._store_shape), self.state_dtype),
+            state0=alloc(*stored),
             action=alloc((N, *self.action_shape), self.action_dtype),
             reward=alloc((N,), jnp.float32),
             gamma_n=alloc((N,), jnp.float32),
-            state1=alloc((N, *self._store_shape), self.state_dtype),
+            state1=alloc(*stored),
             terminal1=alloc((N,), jnp.float32),
             # -1 = unknown provenance (the zeros alloc carries the row
             # sharding; the elementwise subtract preserves it)
             prov=alloc((N, 4), jnp.int32) - 1,
             pos=alloc((), jnp.int32, sharded=False),
             fill=alloc((), jnp.int32, sharded=False),
+            codec=self.codec,
         )
 
     @property
     def size(self) -> int:
         return int(self.state.fill)
 
+    @property
+    def stored_rows(self) -> str:
+        """An observation column at rest, for the learner's start-up
+        line: ``uint32[100000,7168]`` is the packed format."""
+        col = self.state.state0
+        return f"{col.dtype}[{','.join(map(str, col.shape))}]"
+
     # -- checkpoint (utils/checkpoint.py save_replay/load_replay) -----------
 
     def snapshot(self) -> dict:
         """Pull the valid HBM rows to host in AGE order (when full, the
         cursor points at the oldest row; before that, [0, fill) is already
-        oldest-first).  Channels-last rings roll back to the public NCHW
-        schema so checkpoints are layout-independent."""
-        st = jax.device_get(self.state)
+        oldest-first).  The stored words are unpacked and channels-last
+        rings rolled back to the public NCHW schema, so checkpoints are
+        independent of the ring's format."""
+        return self._aged_columns(jax.device_get(self.state),
+                                  REPLAY_FIELDS + ("prov",))
+
+    def _aged_columns(self, st, names) -> dict:
+        """Host copies of the per-row columns ``names`` of a fetched ring
+        state, oldest row first, in the public schema."""
         fill, pos = int(st.fill), int(st.pos)
         shift = -pos if fill == self.capacity else 0
         out = {k: np.roll(np.asarray(getattr(st, k)), shift,
-                          axis=0)[:fill].copy()
-               for k in REPLAY_FIELDS}
-        out["prov"] = np.roll(np.asarray(st.prov), shift,
-                              axis=0)[:fill].astype(np.int64)
-        if self.channels_last:
-            out = snapshot_states_to_nchw(out)
+                          axis=0)[:fill].copy() for k in names}
+        for k in ("state0", "state1"):
+            out[k] = self.codec.unpack(out[k])
+            if self.channels_last:  # the public schema is NCHW
+                out[k] = np.ascontiguousarray(
+                    np.transpose(out[k], (0, 3, 1, 2)))
+        out["prov"] = out["prov"].astype(np.int64)
         return out
 
     def restore(self, data: dict) -> int:
@@ -388,11 +501,16 @@ class DeviceReplay:
             self.state = self._init_state()
         rows = np.asarray(data["reward"])
         n = min(len(rows), self.capacity)
-        if n:
+        cols = [np.asarray(data[k])[-n:] for k in REPLAY_FIELDS]
+        prov = (np.asarray(data["prov"], np.int32)[-n:]
+                if "prov" in data else None)
+        # in slices: one chunk of a whole ring would sit on the device
+        # beside the ring, with the feed's working set on top
+        for lo in range(0, n, RESTORE_ROWS):
+            hi = lo + RESTORE_ROWS
             self.feed_chunk(Transition(
-                *(np.asarray(data[k])[-n:] for k in REPLAY_FIELDS),
-                prov=(np.asarray(data["prov"], np.int32)[-n:]
-                      if "prov" in data else None)))
+                *(c[lo:hi] for c in cols),
+                prov=None if prov is None else prov[lo:hi]))
         return n
 
     def feed_chunk(self, chunk: Transition) -> None:

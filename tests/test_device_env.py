@@ -472,6 +472,9 @@ class TestFusedRollout:
         assert fed == len(rows)
         rs_h = jax.device_get(rs)
         assert int(rs_h.fill) == fed
+        # the observation columns are read through the ring's codec
+        rs_h = rs_h._replace(state0=rs_h.codec.unpack(rs_h.state0),
+                             state1=rs_h.codec.unpack(rs_h.state1))
         for i, row in enumerate(rows):
             for f in ("state0", "action", "reward", "gamma_n",
                       "state1", "terminal1"):

@@ -194,4 +194,173 @@ def test_channels_last_snapshot_is_nchw():
                      channels_last=False)
     b.restore(snap)
     np.testing.assert_array_equal(
-        np.asarray(b.state.state0[:n]), chunk.state0)
+        b.codec.unpack(np.asarray(b.state.state0[:n])), chunk.state0)
+
+
+# ---------------------------------------------------------------------------
+# the stored row format (RowCodec): exact, and invisible from outside
+# ---------------------------------------------------------------------------
+
+def _frames(rng, n, shape=(4, 12, 12), dtype=np.uint8):
+    return rng.integers(0, 256, (n, *shape)).astype(dtype)
+
+
+def _frame_chunk(rng, start, n, shape=(4, 12, 12)):
+    return Transition(
+        state0=_frames(rng, n, shape),
+        action=np.zeros(n, np.int32),
+        reward=np.arange(start, start + n, dtype=np.float32),
+        gamma_n=np.full(n, 0.99, np.float32),
+        state1=_frames(rng, n, shape),
+        terminal1=np.zeros(n, np.float32))
+
+
+def _ring(kind, capacity, shape=(4, 12, 12), **kw):
+    if kind == "per":
+        from pytorch_distributed_tpu.memory.device_per import DevicePerReplay
+        return DevicePerReplay(capacity, shape, state_dtype=np.uint8, **kw)
+    return DeviceReplay(capacity, shape, state_dtype=np.uint8, **kw)
+
+
+@pytest.mark.parametrize("shape,dtype,words", [
+    ((4, 84, 84), np.uint8, 7056),
+    ((84, 84, 4), np.uint8, 7056),
+    ((4, 12, 12), np.int8, 144),
+    ((4,), np.float32, 0),          # flat 32-bit rows: stored as they are
+    ((28224,), np.uint8, 0),        # already flat
+    ((3, 5, 5), np.uint8, 0),       # 75 bytes: no whole words
+])
+def test_codec_round_trip_is_exact(shape, dtype, words):
+    from pytorch_distributed_tpu.memory.device_replay import LANES, RowCodec
+
+    codec = RowCodec(shape, np.dtype(dtype))
+    assert codec.words == words
+    rng = np.random.default_rng(0)
+    x = (rng.standard_normal((5, *shape)).astype(dtype) if words == 0
+         and dtype == np.float32 else
+         rng.integers(-128, 256, (5, *shape)).astype(dtype))
+    stored = codec.pack(jnp.asarray(x))
+    if words:
+        assert stored.dtype == np.uint32
+        assert stored.shape == (5, -(-words // LANES) * LANES)
+        assert not np.asarray(stored)[:, words:].any()
+    else:
+        assert stored.dtype == x.dtype and stored.shape == x.shape
+    for s in (stored, np.asarray(stored)):    # device, and host (snapshot)
+        back = codec.unpack(s)
+        assert isinstance(back, type(s))
+        assert back.dtype == x.dtype and back.shape == x.shape
+        np.testing.assert_array_equal(np.asarray(back), x)
+    # the unpack of a gathered megabatch keeps its leading dimensions
+    np.testing.assert_array_equal(
+        np.asarray(codec.unpack(codec.pack(jnp.asarray(x))[None])), x[None])
+
+
+@pytest.mark.parametrize("channels_last", [False, True])
+@pytest.mark.parametrize("kind", ["uniform", "per"])
+def test_fed_rows_come_back_byte_identical_across_a_wrap(kind,
+                                                         channels_last):
+    """12 rows through an 8-row ring in chunks of 4: snapshot and sample
+    return exactly the bytes that were fed, whatever the ring stores."""
+    rng = np.random.default_rng(1)
+    m = _ring(kind, 8, channels_last=channels_last)
+    assert m.state.state0.dtype == np.uint32       # packed at rest
+    assert m.state.state0.shape == (8, 256)        # 144 words -> 2 x 128
+    chunks = [_frame_chunk(rng, s, 4) for s in (0, 4, 8)]
+    for c in chunks:
+        m.feed_chunk(c)
+    kept = [np.concatenate([getattr(c, f) for c in chunks[1:]])
+            for f in ("state0", "state1")]
+    snap = m.snapshot()                            # oldest first, NCHW
+    np.testing.assert_array_equal(snap["reward"], np.arange(4, 12))
+    np.testing.assert_array_equal(snap["state0"], kept[0])
+    np.testing.assert_array_equal(snap["state1"], kept[1])
+    b = jax.tree_util.tree_map(np.asarray,
+                               m.sample(64, jax.random.PRNGKey(0)))
+    assert b.state0.dtype == np.uint8
+    assert b.state0.shape == (64, 12, 12, 4) if channels_last \
+        else (64, 4, 12, 12)
+    age = b.reward.astype(int) - 4                 # reward names the row
+    assert len(set(age.tolist())) > 4              # both kept chunks drawn
+    want = [np.transpose(k, (0, 2, 3, 1)) if channels_last else k
+            for k in kept]
+    np.testing.assert_array_equal(b.state0, want[0][age])
+    np.testing.assert_array_equal(b.state1, want[1][age])
+
+
+@pytest.mark.parametrize("kind", ["uniform", "per"])
+def test_masked_write_drops_rows_and_leaves_neighbours_untouched(kind):
+    from pytorch_distributed_tpu.memory.device_per import per_write_masked
+    from pytorch_distributed_tpu.memory.device_replay import (
+        ring_write_masked,
+    )
+
+    rng = np.random.default_rng(2)
+    m = _ring(kind, 8)
+    first = _frame_chunk(rng, 0, 8)
+    m.feed_chunk(first)                            # full: cursor back at 0
+    before = jax.device_get(m.state)
+    chunk = _frame_chunk(rng, 100, 6)
+    valid = np.array([True, False, True, True, False, False])
+    write = per_write_masked if kind == "per" else ring_write_masked
+    state, n = jax.jit(write, static_argnames="capacity")(
+        m.state, chunk, valid, capacity=8)
+    assert int(n) == 3 and int(state.pos) == 3
+    m.state = state
+    after = jax.device_get(state)
+    # slots 0..2 took the valid rows in chunk order; the rest kept every
+    # stored word, padding lanes included
+    for f in ("state0", "state1"):
+        np.testing.assert_array_equal(getattr(after, f)[3:],
+                                      getattr(before, f)[3:])
+        np.testing.assert_array_equal(
+            m.codec.unpack(getattr(after, f)[:3]),
+            getattr(chunk, f)[valid])
+    snap = m.snapshot()                            # oldest first: slot 3
+    np.testing.assert_array_equal(
+        snap["state0"],
+        np.concatenate([first.state0[3:], chunk.state0[valid]]))
+
+
+@pytest.mark.parametrize("kind", ["uniform", "per"])
+def test_packed_ring_on_the_mesh(kind):
+    mesh = jax.sharding.Mesh(np.array(jax.devices()), ("dp",))
+    rng = np.random.default_rng(3)
+    m = _ring(kind, 32, mesh=mesh)
+    chunks = [_frame_chunk(rng, s, 16) for s in (0, 16, 32)]
+    for c in chunks:
+        m.feed_chunk(c)                            # wraps once
+    assert m.state.state0.shape == (32, 256)
+    assert len({s.device for s in m.state.state0.addressable_shards}) == 8
+    assert m.state.state0.addressable_shards[0].data.shape == (4, 256)
+    snap = m.snapshot()
+    np.testing.assert_array_equal(
+        snap["state0"], np.concatenate([c.state0 for c in chunks[1:]]))
+    b = jax.tree_util.tree_map(np.asarray,
+                               m.sample(32, jax.random.PRNGKey(1)))
+    np.testing.assert_array_equal(b.state1, snap["state1"][
+        b.reward.astype(int) - 16])
+
+
+@pytest.mark.parametrize("channels_last", [False, True])
+@pytest.mark.parametrize("kind", ["uniform", "per"])
+def test_a_public_schema_snapshot_restores_into_the_packed_ring(
+        kind, channels_last):
+    """What a checkpoint written before the ring packed its rows holds:
+    plain NCHW uint8 columns.  It restores, and comes back as it went."""
+    rng = np.random.default_rng(4)
+    c = _frame_chunk(rng, 0, 6)
+    old = {f: np.asarray(getattr(c, f)) for f in c._fields
+           if getattr(c, f) is not None}
+    if kind == "per":
+        old["leaf_priority"] = np.linspace(0.1, 2.0, 6).astype(np.float32)
+        old["max_priority_base"] = np.float64(3.0)
+    m = _ring(kind, 8, channels_last=channels_last)
+    assert m.restore(old) == 6
+    snap = m.snapshot()
+    for k, v in old.items():
+        np.testing.assert_allclose(snap[k], v, rtol=1e-6)
+    assert snap["state0"].dtype == np.uint8
+    again = _ring(kind, 8, channels_last=not channels_last)
+    again.restore(snap)
+    np.testing.assert_array_equal(again.snapshot()["state1"], c.state1)

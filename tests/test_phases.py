@@ -157,6 +157,41 @@ def test_the_ring_feed_is_a_phase(tmp_path, memory_type):
     assert paths and all(PHASE_FEED in path for path, _ in paths)
 
 
+@pytest.mark.parametrize("kind", ["device", "device-per"])
+def test_the_pack_is_feed_and_the_unpack_is_gather(kind):
+    """Pixel rows are stored packed (memory/device_replay.py RowCodec):
+    the shifts that pack a chunk stand under ``replay.feed``, the shifts
+    that unpack a batch under ``replay.gather``, nothing under no phase."""
+    import numpy as np
+
+    from pytorch_distributed_tpu.memory import DeviceReplay
+    from pytorch_distributed_tpu.memory.device_per import DevicePerReplay
+    from pytorch_distributed_tpu.utils.experience import Transition
+
+    cls = DevicePerReplay if kind == "device-per" else DeviceReplay
+    ring = cls(16, (4, 12, 12), state_dtype=np.uint8)
+    assert ring.codec.words
+    n = 4
+    chunk = Transition(
+        state0=np.zeros((n, 4, 12, 12), np.uint8),
+        action=np.zeros(n, np.int32), reward=np.zeros(n, np.float32),
+        gamma_n=np.ones(n, np.float32),
+        state1=np.zeros((n, 4, 12, 12), np.uint8),
+        terminal1=np.zeros(n, np.float32))
+    feed = list(leaf_paths(
+        jax.make_jaxpr(ring._feed_fn)(ring.state, chunk).jaxpr))
+    assert all(PHASE_FEED in path for path, _ in feed)
+    assert any(prim == "shift_left" for _, prim in feed)
+    extra = {"beta": jnp.float32(0.4)} if kind == "device-per" else {}
+    sample = list(leaf_paths(jax.make_jaxpr(
+        lambda st, key: ring._sample_fn(st, key, batch_size=4, **extra))(
+            ring.state, jax.random.PRNGKey(0)).jaxpr))
+    assert all(_A_PHASE.search(path) for path, _ in sample)
+    # one unpack a column (the draw's random bits shift too, under draw)
+    assert sum(prim == "shift_right_logical" and PHASE_GATHER in path
+               for path, prim in sample) == 2
+
+
 # ---------------------------------------------------------------------------
 # host phases
 # ---------------------------------------------------------------------------

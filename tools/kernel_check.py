@@ -1,6 +1,7 @@
 #!/usr/bin/env python
 """Compile both Pallas kernels for the TPU at production geometry and
-check each against its XLA reference.
+check each against its XLA reference; and read the packed replay ring
+back from the chip, byte for byte.
 
 The tier-1 tests run the kernels under the Pallas interpreter
 (tests/test_pallas_sampling.py, tests/test_pallas_torso.py); this is the
@@ -22,6 +23,13 @@ it exists to prove.
    gradient at batch 128 bf16 — against the model's XLA apply, at the
    tolerances of tests/test_pallas_torso.py (forward rtol/atol 0.05,
    whole-tree gradient cosine > 0.999).
+
+3. Ring rows (memory/device_replay.py ``RowCodec``): random uint8
+   (4, 84, 84) frames through ``feed_chunk`` across a cursor wrap, read
+   back through ``snapshot`` (host unpack) and ``sample`` (the compiled
+   unpack): byte-identical, and the stored column row-major on the
+   device.  The tier-1 tests prove the same on the CPU; the TPU lowers
+   one-byte shifts and lays arrays out differently.
 
 Usage: JAX_PLATFORMS=tpu,cpu python tools/kernel_check.py
 Last stdout line: one JSON object with per-kernel status and wall times
@@ -138,6 +146,44 @@ def check_torso() -> dict:
             "grad_cosine": round(cos, 6)}
 
 
+def check_ring_rows() -> dict:
+    import jax
+
+    from pytorch_distributed_tpu.memory.device_per import DevicePerReplay
+    from pytorch_distributed_tpu.utils.experience import Transition
+
+    cap, n, shape, seed = 4096, 1536, (4, 84, 84), 7
+    rng = np.random.default_rng(seed)
+    ring = DevicePerReplay(cap, shape, state_dtype=np.uint8)
+    fed = []
+    for start in range(0, 3 * n, n):          # 4,608 rows: wraps once
+        fed.append(Transition(
+            state0=rng.integers(0, 256, (n, *shape)).astype(np.uint8),
+            action=np.zeros(n, np.int32),
+            reward=np.arange(start, start + n, dtype=np.float32),
+            gamma_n=np.ones(n, np.float32),
+            state1=rng.integers(0, 256, (n, *shape)).astype(np.uint8),
+            terminal1=np.zeros(n, np.float32)))
+        ring.feed_chunk(fed[-1])
+    host = {f: np.concatenate([getattr(c, f) for c in fed])
+            for f in ("state0", "state1")}
+    snap = ring.snapshot()                    # oldest first
+    first = int(snap["reward"][0])
+    assert first == 3 * n - cap, first
+    for f in host:
+        assert np.array_equal(snap[f], host[f][first:]), f"snapshot {f}"
+    b = jax.device_get(ring.sample(512, jax.random.PRNGKey(seed), beta=0.4))
+    rows = b.reward.astype(np.int64)
+    for f in host:
+        assert np.array_equal(getattr(b, f), host[f][rows]), f"sample {f}"
+    col = ring.state.state0
+    layout = getattr(getattr(col, "format", None), "layout", None)
+    order = tuple(getattr(layout, "major_to_minor", ()) or ())
+    assert order in ((), (0, 1)), f"ring column not row-major: {layout}"
+    return {"stored": ring.stored_rows, "layout": str(layout),
+            "rows_checked": int(cap + len(rows))}
+
+
 def main() -> int:
     import jax
 
@@ -153,7 +199,8 @@ def main() -> int:
               "device_count": len(jax.devices())}
     failed = False
     for name, check in (("per_sampler", check_sampler),
-                        ("torso", check_torso)):
+                        ("torso", check_torso),
+                        ("ring_rows", check_ring_rows)):
         t0 = time.monotonic()
         try:
             report[name] = dict(check(), status="ok")
