@@ -856,6 +856,8 @@ def run_learner(opt: Options, spec: EnvSpec, process_ind: int, memory: Any,
                     q_mean=vals.get("learner/q_mean", 0.0),
                     grad_norm=vals.get("learner/grad_norm", 0.0),
                     moe_aux=vals.get("learner/moe_aux", 0.0),
+                    **{k: vals.get(f"learner/{k}", 0.0)
+                       for k in stats.MOE_FIELDS},
                     steps_per_sec=(lstep - last_stats_lstep)
                     / max(now - t_cadence, 1e-9),
                 )

@@ -87,6 +87,8 @@ def run_logger(opt: Options, clock: GlobalClock, actor_stats: ActorStats,
                         # nonzero only for MoE models (models/moe.py);
                         # rides along like actor_loss does for non-DDPG
                         "learner/moe_aux": le["moe_aux"] / le["counter"],
+                        **{f"learner/{k}": le[k] / le["counter"]
+                           for k in learner_stats.MOE_FIELDS},
                     }, step=step)
                 writer.flush()
 

@@ -32,7 +32,9 @@ from typing import Any, Optional, Tuple
 #   [agent_type, env_type, game, memory_type, model_type]
 # Rows 0 is the reference's only row (dqn/atari/pong/shared/dqn-cnn).  The
 # extra rows cover the driver BASELINE.json tracked configs plus self-
-# contained debug/bench envs that need no ALE install.
+# contained debug/bench envs that need no ALE install.  Rows 12, 14 and 20
+# are the benchmark's configurations (benchmark/configs/); rows 15, 17 and
+# 18 are toy-sized families on the host sequence replay.
 # ---------------------------------------------------------------------------
 CONFIGS = [
     # agent_type, env_type,    game,          memory_type, model_type
@@ -56,6 +58,7 @@ CONFIGS = [
     ["r2d2",      "fake",      "chain",       "sequence",    "dtqn-moe"],# 17 MoE transformer Q (expert parallel)
     ["r2d2",      "fake",      "chain",       "sequence",    "dtqn-pipe"],# 18 staged transformer Q (pipeline parallel)
     ["dqn",       "pong-sim",  "pong",        "device-per",  "dqn-cnn-wide"],# 19 MXU-filling wide torso (ISSUE 13)
+    ["r2d2",      "pong-sim",  "pong",        "device-sequence", "dtqn-hybrid"],# 20 state-space / sparse-expert / grouped-query trunk (models/hybrid.py)
 ]
 
 
@@ -289,6 +292,10 @@ class ModelParams:
     orthogonal_init: bool = True
     # Compute dtype for the forward/backward pass on TPU (params stay fp32).
     compute_dtype: str = "bfloat16"
+    # dtqn-hybrid: which frozen preset of models/hybrid.py PRESETS holds the
+    # trunk's widths ("nemotron-h-9": the published ones; "tiny": CPU tests).
+    # Widths live there and nowhere else.
+    hybrid_preset: str = "nemotron-h-9"
 
 
 @dataclass
@@ -1028,7 +1035,7 @@ def build_options(config: int = 1, **overrides: Any) -> Options:
     memory_type = overrides.pop("memory_type", memory_type)
     model_type = overrides.pop("model_type", model_type)
 
-    if "cnn" in model_type:
+    if "cnn" in model_type or model_type == "dtqn-hybrid":
         env_shape = dict(state_cha=4, state_hei=84, state_wid=84)
         state_dtype = "uint8"
     else:

@@ -60,7 +60,7 @@ MemoriesDict: Dict[str, Optional[Callable]] = {
 # model ctors bound in build_model below (they need probed shapes)
 ModelTypes = ("dqn-cnn", "dqn-cnn-wide", "dqn-mlp", "ddpg-mlp",
               "drqn-mlp", "drqn-cnn", "dtqn-mlp", "dtqn-moe",
-              "dtqn-pipe")
+              "dtqn-pipe", "dtqn-hybrid")
 
 
 def _worker_dicts():
@@ -378,10 +378,12 @@ def sequence_pack_frames(opt: Options) -> int:
     stacks on device (memory/sequence_replay.py SegmentBuilder /
     ops/sequence_losses.py unpack_frame_stacks).  Decided HERE so the
     three parties — actor-side builders, the replay allocation, and the
-    learner step — can never disagree on the wire format.  Only the
-    pixel R2D2 family qualifies (the dtqn rows are low-dim)."""
+    learner step — can never disagree on the wire format.  The two pixel
+    sequence models qualify: drqn-cnn rebuilds the stacks, dtqn-hybrid
+    reads the newest frame of each (models/hybrid.py window_applies);
+    the other dtqn rows are low-dim."""
     if (opt.memory_type in ("sequence", "device-sequence")
-            and opt.model_type == "drqn-cnn"
+            and opt.model_type in ("drqn-cnn", "dtqn-hybrid")
             and opt.memory_params.state_dtype == "uint8"):
         return opt.env_params.state_cha
     return 0
@@ -389,8 +391,10 @@ def sequence_pack_frames(opt: Options) -> int:
 
 def lstm_dim_of(opt: Options) -> int:
     """Stored-recurrent-state width for the configured model (the CNN
-    variant floors at 512, matching its torso output; transformers store
-    a 1-dim placeholder — their context is the segment window itself)."""
+    variant floors at 512, matching its torso output; every dtqn* model,
+    the hybrid trunk with its state-space layers included, stores a 1-dim
+    placeholder: a segment starts from zero state and its burn-in prefix
+    is context)."""
     if opt.model_type.startswith("dtqn"):
         return 1
     d = opt.model_params.lstm_dim
@@ -473,6 +477,18 @@ def build_model(opt: Options, spec: EnvSpec):
 
             return DtqnPipelineModel(**kw)
         return DtqnMlpModel(**kw)
+    if opt.model_type == "dtqn-hybrid":
+        from pytorch_distributed_tpu.models.hybrid import (
+            PRESETS, HybridQModel,
+        )
+
+        return HybridQModel(
+            action_space=spec.num_actions,
+            state_shape=spec.state_shape,
+            window=opt.agent_params.seq_len + 1,
+            preset=PRESETS[mp_.hybrid_preset],
+            norm_val=spec.norm_val,
+            compute_dtype=jnp.dtype(mp_.compute_dtype))
     if opt.model_type == "drqn-cnn":
         from pytorch_distributed_tpu.models.drqn import DrqnCnnModel
 
@@ -558,7 +574,22 @@ def build_train_state_and_step(opt: Options, spec: EnvSpec, model, params,
             priority_eta=ap.priority_eta,
             guard=guard,
         )
-        if opt.model_type.startswith("dtqn"):
+        if hasattr(model, "train_parts"):
+            from pytorch_distributed_tpu.ops.sequence_losses import (
+                build_dtqn_train_step,
+            )
+
+            # a model that brings its own window passes (models/hybrid.py):
+            # the burn-in prefix stays, as context for its state-space and
+            # attention layers, excluded from the loss; how the target
+            # network is kept (there: bfloat16 where it is only read
+            # through bfloat16 matmuls, 14 bytes a parameter of train state
+            # and not 16) is the model's to say
+            state = state._replace(target_params=model.target_copy(params))
+            online, kw["target_window_apply"], kw["after_update"] = \
+                model.train_parts(sequence_pack_frames(opt))
+            step = build_dtqn_train_step(online, tx, **kw)
+        elif opt.model_type.startswith("dtqn"):
             from pytorch_distributed_tpu.ops.sequence_losses import (
                 build_dtqn_train_step,
             )
@@ -784,14 +815,19 @@ def build_megabatch_train_step(opt: Options, model):
 def resolve_steps_per_dispatch(opt: Options) -> int:
     """Update steps fused into one dispatched program on the device-
     replay paths: ``agent_params.steps_per_dispatch`` when set, else
-    auto — 32 on a TPU (amortise the launch), 1 elsewhere (on the CPU
-    backend the dispatch IS the compute).  One rule for the split
-    learner and the Anakin loop; the learner's start-up line prints
-    the result.  The 32 was chosen over a link that no longer exists
-    and has not been measured on a directly attached chip."""
+    auto — 32 on a TPU (amortise the launch) for the models whose update
+    takes milliseconds, 1 for dtqn-hybrid (an update is half a second:
+    nothing to amortise, and 32 would be a 17-second dispatch), 1
+    elsewhere (on the CPU backend the dispatch IS
+    the compute).  One rule for the split learner and the Anakin loop;
+    the learner's start-up line prints the result.  The 32 was chosen
+    over a link that no longer exists; on the directly attached chip it
+    is what the benchmark's apex and r2d2 cells run (PERF.md)."""
     K = opt.agent_params.steps_per_dispatch
     if K > 0:
         return K
+    if opt.model_type == "dtqn-hybrid":
+        return 1
     import jax
 
     return 32 if jax.devices()[0].platform == "tpu" else 1

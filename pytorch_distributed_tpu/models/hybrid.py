@@ -1,0 +1,811 @@
+"""Hybrid sequence Q-network: a layer PATTERN of state-space (Mamba-2),
+sparse-expert and grouped-query attention blocks over one frame per
+position (model_type ``dtqn-hybrid``, CONFIGS row 20).
+
+The trunk is layers 0-8 of a published hybrid language model at their
+published widths (``PRESETS["nemotron-h-9"]``; source and every departure:
+benchmark/configs/nemotron_h_pong.json), with the token embedding and LM
+head replaced by the repo's sequence-family contract (models/dtqn.py): one
+84x84 uint8 frame a position -> Dense -> trunk -> final RMSNorm ->
+zero-initialised Q head.  Pre-norm residual blocks ``x <- x +
+mixer(RMSNorm(x))``, no biases but the conv's.  One letter a layer:
+
+- ``M``  Mamba-2: ``[z | xBC | dt] = u W_in``; causal depth-wise conv +
+  silu on xBC; per head ``S_t = exp(dt_t A) S_{t-1} + dt_t x_t (x) B_t``,
+  ``y_t = S_t C_t + D x_t``; gated grouped RMSNorm; ``W_out``.  The
+  learner computes it in chunks (``ssd_chunked``: matmuls inside a chunk,
+  a scan over chunk states in float32), the actor one position at a time.
+- ``*``  causal grouped-query attention WITHOUT rotary or position table
+  (the family's attention is position-free), in query blocks: no
+  ``(B, heads, T, T)`` array exists.
+- ``E``  sigmoid-scored experts: top-k of ``s + b_sel`` over ALL experts,
+  weights ``s`` (without ``b_sel``) normalised, times ``route_scale``;
+  ``relu(u W_up)^2 W_down`` per expert, plus one shared expert.  The layer
+  is TOLD WHICH EXPERTS IT HOLDS (``first_expert``, ``experts_held``): it
+  routes over all of them and computes its own experts' part for the rows
+  routed to them, sorted by expert and multiplied as grouped matmuls (on a
+  TPU the Pallas ``megablox.gmm`` that ships with JAX, elsewhere
+  ``jax.lax.ragged_dot``): no capacity, no dropped token at any skew,
+  device work by the rows routed here, in steps of a run.  What the absent
+  experts would add is left out (one chip of a layer shared by several; the exchange is
+  parallel/expert_parallel.py's to add).  ``b_sel`` has no gradient: after
+  every update it moves by ``bias_rate`` against each expert's load
+  (``balance_selection_bias``), the family's way of spreading the tokens
+  over the experts (at ``bias_rate`` 1e-3 slower than Adam's first updates
+  move the router: PERF.md section 6).
+
+Contracts shared with the other sequence families (recurrent actor,
+evaluator, sequence learner): ``window_q(frames (B, T, H, W))`` is the
+learner's one causal pass, zero state at position 0; ``__call__(obs,
+carry)`` acts one step through a carry of (conv tails, SSM states, key /
+value caches, count) whose leaves all lead with the batch dimension;
+``state_for_segment`` stores the 1-dim placeholder of every ``dtqn*``
+model.  bfloat16 matmuls; float32 parameters, router, softmax, norms,
+``dt``, ``A`` and scan state.  Every mixer runs under ``jax.checkpoint``
+and names its device work ``model.*`` (utils/profiling.py) INSIDE the
+checkpointed function and inside each scan body.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import math
+from typing import Any, Dict, Tuple
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from pytorch_distributed_tpu.utils.profiling import (
+    SCOPE_ATTN, SCOPE_EMBED, SCOPE_HEAD, SCOPE_MOE, SCOPE_MOE_EXPERTS,
+    SCOPE_MOE_ROUTE, SCOPE_MOE_SHARED, SCOPE_SSM,
+)
+
+F32 = jnp.float32
+
+
+@dataclasses.dataclass(frozen=True)
+class HybridPreset:
+    """Every width of the trunk, in ONE place."""
+
+    pattern: str                 # one letter a layer: M, E or *
+    d_model: int
+    # M: Mamba-2
+    ssm_heads: int
+    ssm_head_dim: int
+    ssm_state: int
+    ssm_groups: int
+    conv_kernel: int
+    chunk: int
+    dt_min: float
+    dt_max: float
+    dt_floor: float
+    # *: grouped-query attention
+    attn_heads: int
+    kv_heads: int
+    attn_head_dim: int
+    attn_block: int              # query rows per block
+    # E: experts
+    n_experts: int               # routed over
+    top_k: int
+    expert_width: int
+    shared_width: int
+    route_scale: float
+    experts_held: int            # computed here ...
+    first_expert: int            # ... starting at this one
+    bias_rate: float = 1e-3      # b_sel's step against the load, an update
+    norm_eps: float = 1e-5
+
+    @property
+    def d_inner(self) -> int:
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def conv_dim(self) -> int:
+        return self.d_inner + 2 * self.ssm_groups * self.ssm_state
+
+
+PRESETS: Dict[str, HybridPreset] = {
+    # layers 0-8 of the published 52 (one period: 4 M, 4 E, 1 *), every
+    # width as published; 8 of the 128 experts held: one of the 16 chips
+    # that share each layer
+    "nemotron-h-9": HybridPreset(
+        pattern="MEMEM*EME", d_model=2688,
+        ssm_heads=64, ssm_head_dim=64, ssm_state=128, ssm_groups=8,
+        conv_kernel=4, chunk=128, dt_min=1e-3, dt_max=0.1, dt_floor=1e-4,
+        attn_heads=32, kv_heads=2, attn_head_dim=128, attn_block=256,
+        n_experts=128, top_k=6, expert_width=1856, shared_width=3712,
+        route_scale=2.5, experts_held=8, first_expert=0),
+    # CPU tests: every mechanism, no width
+    "tiny": HybridPreset(
+        pattern="ME*E", d_model=32,
+        ssm_heads=4, ssm_head_dim=8, ssm_state=8, ssm_groups=2,
+        conv_kernel=4, chunk=4, dt_min=1e-3, dt_max=0.1, dt_floor=1e-4,
+        attn_heads=4, kv_heads=2, attn_head_dim=8, attn_block=4,
+        n_experts=16, top_k=3, expert_width=16, shared_width=24,
+        route_scale=2.5, experts_held=4, first_expert=0),
+}
+
+
+def is_matmul_weight(name: str) -> bool:
+    """Leaves the trunk reads only through bfloat16 matmuls (named
+    ``w_*``): the ones a bfloat16 target copy may round."""
+    return name.startswith("w_")
+
+
+def bf16_target(params: Any) -> Any:
+    """The target network's copy for this model type: matmul weights in
+    bfloat16 (they are cast to it at every use anyway), the rest
+    (norms, router, ``dt_bias``, ``A_log``, ``D``, conv, head) float32.
+    14 bytes a parameter of train state (weights, Adam's two moments,
+    this copy) and not 16."""
+    def cast(path, leaf):
+        name = getattr(path[-1], "key", "")
+        return leaf.astype(jnp.bfloat16) if is_matmul_weight(name) \
+            else jnp.array(leaf)
+
+    return jax.tree_util.tree_map_with_path(cast, params)
+
+
+# ---------------------------------------------------------------------------
+# the layer equations, as pure functions of a layer's parameter dict
+# ---------------------------------------------------------------------------
+
+def rms_norm(x, scale, eps: float, groups: int = 1):
+    """RMSNorm in float32 over the last axis (in ``groups`` equal parts),
+    learned scale; returns float32."""
+    x = x.astype(F32)
+    shape = x.shape
+    xg = x.reshape(*shape[:-1], groups, shape[-1] // groups)
+    xg = xg * jax.lax.rsqrt(jnp.mean(jnp.square(xg), -1, keepdims=True) + eps)
+    return xg.reshape(shape) * scale
+
+
+def _mm(x, w, cd):
+    """``x @ w`` with both operands in the compute dtype, float32 out."""
+    return jnp.matmul(x.astype(cd), w.astype(cd), preferred_element_type=F32)
+
+
+def relu2(x):
+    return jnp.square(jax.nn.relu(x))
+
+
+def ssd_chunked(x, dt, A, Bm, Cm, chunk: int, cd=jnp.bfloat16,
+                state_dtype=F32):
+    """The state-space recurrence over a window from a zero state, chunk
+    by chunk: ``S_t = exp(dt_t A) S_{t-1} + dt_t x_t (x) B_t``, ``y_t =
+    S_t C_t``.  x (b,T,h,p), dt (b,T,h) float32 after softplus, A (h,)
+    float32 negative, Bm / Cm (b,T,g,n); head h reads group h // (h/g).
+    Inside a chunk the recurrence is two matmuls through the decay
+    matrix; between chunks a scan carries the (h, p, n) state in
+    ``state_dtype``.  Returns (y (b,T,h,p) float32, final state)."""
+    b, T, h, p = x.shape
+    g, n = Bm.shape[2:]
+    k, L, nc = h // g, chunk, T // chunk
+    assert nc * L == T, (T, chunk)
+    xdt = (x.astype(F32) * dt[..., None]).reshape(b, nc, L, g, k, p)
+    tril = jnp.tril(jnp.ones((L, L), bool))
+    # cumulative log-decay inside each chunk, as a product with the
+    # triangle of ones: the chip's cumsum (a reduce-window) took as long as
+    # the layer's largest matmul
+    cum = jnp.einsum("bcsgk,ls->bcgkl", (dt * A).reshape(b, nc, L, g, k),
+                     tril.astype(F32),
+                     precision=jax.lax.Precision.HIGHEST)  # (b,c,g,k,L)
+    Bc = Bm.astype(cd).reshape(b, nc, L, g, n)
+    Cc = Cm.astype(cd).reshape(b, nc, L, g, n)
+    # inside a chunk: y_l += sum_{s<=l} (C_l . B_s) exp(cum_l - cum_s) x_s
+    G = jnp.einsum("bclgn,bcsgn->bcgls", Cc, Bc, preferred_element_type=F32)
+    decay = jnp.exp(jnp.where(tril, cum[..., :, None] - cum[..., None, :],
+                              -jnp.inf))                   # (b,c,g,k,l,s)
+    y = jnp.einsum("bcgkls,bcsgkp->bclgkp",
+                   (G[:, :, :, None] * decay).astype(cd), xdt.astype(cd),
+                   preferred_element_type=F32)
+    # what each chunk adds to the state by its end
+    to_end = jnp.exp(cum[..., -1:] - cum)                  # (b,c,g,k,s)
+    xw = (xdt * jnp.moveaxis(to_end, -1, 2)[..., None]).astype(cd)
+    S_local = jnp.einsum("bcsgn,bcsgkp->bcgkpn", Bc, xw,
+                         preferred_element_type=F32)
+    chunk_decay = jnp.exp(cum[..., -1])                    # (b,c,g,k)
+
+    def carry_state(S, inp):
+        with jax.named_scope(SCOPE_SSM):
+            S_add, dec = inp
+            S_next = (dec[..., None, None] * S.astype(F32)
+                      + S_add).astype(state_dtype)
+        return S_next, S
+
+    S_end, S_prev = jax.lax.scan(
+        carry_state, jnp.zeros((b, g, k, p, n), state_dtype),
+        (jnp.moveaxis(S_local, 1, 0), jnp.moveaxis(chunk_decay, 1, 0)))
+    S_prev = jnp.moveaxis(S_prev, 0, 1)                    # (b,c,g,k,p,n)
+    y_off = jnp.einsum("bclgn,bcgkpn->bclgkp", Cc, S_prev.astype(cd),
+                       preferred_element_type=F32)
+    y = y + y_off * jnp.moveaxis(jnp.exp(cum), -1, 2)[..., None]
+    return y.reshape(b, T, h, p), S_end.reshape(b, h, p, n)
+
+
+def _split_in_proj(zxbcdt, c: HybridPreset):
+    z, xBC, dt = jnp.split(
+        zxbcdt, [c.d_inner, c.d_inner + c.conv_dim], axis=-1)
+    return z, xBC, dt
+
+
+def _split_xbc(xBC, c: HybridPreset):
+    gn = c.ssm_groups * c.ssm_state
+    x, Bm, Cm = jnp.split(xBC, [c.d_inner, c.d_inner + gn], axis=-1)
+    lead = x.shape[:-1]
+    return (x.reshape(*lead, c.ssm_heads, c.ssm_head_dim),
+            Bm.reshape(*lead, c.ssm_groups, c.ssm_state),
+            Cm.reshape(*lead, c.ssm_groups, c.ssm_state))
+
+
+def _ssm_out(p, y, x, z, c: HybridPreset, cd):
+    """``+ D x``, the gate, the grouped norm and the out projection."""
+    y = y + p["D"][:, None] * x.astype(F32)
+    y = y.reshape(*y.shape[:-2], c.d_inner)
+    y = rms_norm(y * jax.nn.silu(z.astype(F32)), p["gate_norm"],
+                 c.norm_eps, groups=c.ssm_groups)
+    return _mm(y, p["w_out"], cd)
+
+
+def mamba_window(p, u, c: HybridPreset, cd):
+    """One M mixer over (b, T, d) normed input, zero state at t = 0; T
+    padded up to whole chunks (causal, and ``dt`` = 0 in the padding, so it
+    changes neither an output nor the state).  Returns (out (b, T, d)
+    float32, the state after position T - 1 (b, h, p, n) float32)."""
+    with jax.named_scope(SCOPE_SSM):
+        b, T, _ = u.shape
+        pad = -T % c.chunk
+        z, xBC, dt = _split_in_proj(_mm(u, p["w_in"], cd), c)
+        # causal depth-wise conv: tap j reads position t - (K-1) + j
+        K = c.conv_kernel
+        xp = jnp.pad(xBC, ((0, 0), (K - 1, 0), (0, 0)))
+        xBC = jax.nn.silu(sum(xp[:, j:j + T] * p["conv_w"][j]
+                              for j in range(K)) + p["conv_b"])
+        x, Bm, Cm = _split_xbc(xBC, c)
+        dt = jax.nn.softplus(dt + p["dt_bias"])
+        A = -jnp.exp(p["A_log"])
+        grow = lambda t: jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
+        y, S = ssd_chunked(grow(x), grow(dt), A, grow(Bm), grow(Cm),
+                           c.chunk, cd)
+        return _ssm_out(p, y[:, :T], x, z, c, cd), S
+
+
+def mamba_step(p, u, tail, S, c: HybridPreset, cd):
+    """One position: u (b, d); tail (b, K-1, conv_dim) the conv's last
+    inputs; S (b, h, p, n) float32."""
+    with jax.named_scope(SCOPE_SSM):
+        z, xBC, dt = _split_in_proj(_mm(u, p["w_in"], cd), c)
+        taps = jnp.concatenate([tail, xBC[:, None]], axis=1)   # (b, K, .)
+        xBC = jax.nn.silu(jnp.einsum("bkc,kc->bc", taps, p["conv_w"])
+                          + p["conv_b"])
+        x, Bm, Cm = _split_xbc(xBC, c)
+        dt = jax.nn.softplus(dt + p["dt_bias"])                # (b, h)
+        k = c.ssm_heads // c.ssm_groups
+        Bh, Ch = (jnp.repeat(t, k, axis=1) for t in (Bm, Cm))  # (b, h, n)
+        xdt = x * dt[..., None]
+        S = (jnp.exp(dt * -jnp.exp(p["A_log"]))[..., None, None] * S
+             + xdt[..., None] * Bh[:, :, None, :])
+        y = jnp.einsum("bhpn,bhn->bhp", S, Ch)
+        return _ssm_out(p, y, x, z, c, cd), taps[:, 1:], S
+
+
+def _attend(q, k, v, mask, cd):
+    """softmax(q k^T / sqrt(hd)) v for one block of queries: q (b, kv, r,
+    Tq, hd), k / v (b, kv, Tk, hd), mask (.., Tq, Tk) or None.  Scores and
+    softmax in float32."""
+    s = jnp.einsum("bgrqd,bgkd->bgrqk", q.astype(cd), k.astype(cd),
+                   preferred_element_type=F32) / math.sqrt(q.shape[-1])
+    if mask is not None:
+        s = jnp.where(mask, s, -jnp.inf)
+    w = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("bgrqk,bgkd->bgrqd", w.astype(cd), v.astype(cd),
+                      preferred_element_type=F32)
+
+
+def _qkv(p, u, c: HybridPreset, cd):
+    lead = u.shape[:-1]
+    q = _mm(u, p["w_q"], cd).reshape(*lead, c.attn_heads, c.attn_head_dim)
+    k = _mm(u, p["w_k"], cd).reshape(*lead, c.kv_heads, c.attn_head_dim)
+    v = _mm(u, p["w_v"], cd).reshape(*lead, c.kv_heads, c.attn_head_dim)
+    return q, k, v
+
+
+def attention_window(p, u, c: HybridPreset, cd):
+    """One * mixer over (b, T, d): causal, each key-value head shared by
+    ``attn_heads / kv_heads`` query heads, block of queries by block, a
+    block reading only the keys up to its own end (the causal half)."""
+    with jax.named_scope(SCOPE_ATTN):
+        b, T, _ = u.shape
+        r, Q = c.attn_heads // c.kv_heads, min(c.attn_block, T)
+        q, k, v = _qkv(p, u, c, cd)
+        q = q.reshape(b, T, c.kv_heads, r, -1).transpose(0, 2, 3, 1, 4)
+        k, v = (t.transpose(0, 2, 1, 3) for t in (k, v))       # (b,kv,T,hd)
+
+        @jax.checkpoint
+        def block(qb, kb, vb, lo):
+            with jax.named_scope(SCOPE_ATTN):
+                rows = lo + jnp.arange(qb.shape[3])[:, None]
+                return _attend(qb, kb, vb,
+                               jnp.arange(kb.shape[2])[None, :] <= rows, cd)
+
+        out = [block(q[:, :, :, lo:lo + Q], k[:, :, :lo + Q], v[:, :, :lo + Q],
+                     lo) for lo in range(0, T, Q)]
+        o = jnp.concatenate(out, axis=3)                       # (b,kv,r,T,hd)
+        o = o.transpose(0, 3, 1, 2, 4).reshape(b, T, -1)
+        return _mm(o, p["w_o"], cd)
+
+
+def attention_step(p, u, kc, vc, count, c: HybridPreset, cd):
+    """One position against the caches kc / vc (b, W, kv, hd), a ring of
+    the last W keys (the attention is position-free, so their order in the
+    ring does not matter); ``count`` (b,) positions seen before this one."""
+    with jax.named_scope(SCOPE_ATTN):
+        b, W = kc.shape[:2]
+        r = c.attn_heads // c.kv_heads
+        q, k, v = _qkv(p, u, c, cd)
+        at = (count % W).astype(jnp.int32)
+        put = jax.vmap(lambda cache, new, i:
+                       jax.lax.dynamic_update_slice_in_dim(cache, new[None],
+                                                           i, 0))
+        kc, vc = put(kc, k.astype(kc.dtype), at), put(vc, v.astype(vc.dtype), at)
+        valid = jnp.arange(W)[None, :] < jnp.minimum(count + 1, W)[:, None]
+        o = _attend(q.reshape(b, c.kv_heads, r, 1, -1),
+                    kc.transpose(0, 2, 1, 3), vc.transpose(0, 2, 1, 3),
+                    valid[:, None, None, None, :], cd)
+        return _mm(o.reshape(b, -1), p["w_o"], cd), kc, vc
+
+
+def route(p, u, c: HybridPreset):
+    """Router in float32 over ALL experts: (chosen (N, k) int32, weights
+    (N, k) float32, load (E,) int32: the tokens that chose each expert).
+    Selected with ``b_sel``, weighed without."""
+    with jax.named_scope(SCOPE_MOE_ROUTE):
+        s = jax.nn.sigmoid(jnp.matmul(u.astype(F32), p["router"],
+                                      precision=jax.lax.Precision.HIGHEST))
+        _, chosen = jax.lax.top_k(s + jax.lax.stop_gradient(p["b_sel"]),
+                                  c.top_k)
+        w = jnp.take_along_axis(s, chosen, axis=-1)
+        w = w / jnp.sum(w, axis=-1, keepdims=True) * c.route_scale
+        load = jnp.sum(jax.nn.one_hot(chosen, c.n_experts, dtype=jnp.int32),
+                       axis=(0, 1))
+        return chosen.astype(jnp.int32), w, load
+
+
+def _tile(dim: int, most: int) -> int:
+    """A tile of at most ``most`` for a dimension the kernel may mask: the
+    largest multiple of 128 that divides it, else ``most`` itself (or the
+    whole dimension where that is smaller)."""
+    if dim <= most:
+        return dim
+    return next((t for t in range(most, 0, -128) if dim % t == 0), most)
+
+
+def grouped_dot(x, w, sizes, kernel: str = "auto"):
+    """``x[rows of group g] @ w[g]`` for rows sorted by group: x (R, k), w
+    (H, k, n), sizes (H,) summing to R; float32 out.  On a TPU the Pallas
+    grouped matmul that ships with JAX (``megablox.gmm``, kernels ``gmm``
+    and ``tgmm`` in a trace), at tiles of 256 rows by up to 896: the
+    compiler's own ``jax.lax.ragged_dot`` kernel, at 512 x 128 x 128 tiles,
+    took 3.3 times as long (PERF.md section 6).  Elsewhere
+    ``jax.lax.ragged_dot``.  ``kernel``: "auto", "xla" or "interpret" (the
+    Pallas kernel under the interpreter: tests)."""
+    if kernel == "auto":
+        kernel = "pallas" if jax.default_backend() == "tpu" else "xla"
+    if kernel == "xla":
+        return jax.lax.ragged_dot(x, w, sizes, preferred_element_type=F32)
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    R, k = x.shape
+    tiling = (_tile(R, 256), _tile(k, 896), _tile(w.shape[2], 896))
+    return gmm(x, w, sizes, F32, tiling, interpret=kernel == "interpret")
+
+
+def _grouped_ffn(p, u, tok, w_pair, sizes, cd, kernel="auto"):
+    """The held experts' part for a run of sorted rows ``tok`` (R,) (rows
+    of one expert adjacent, ``sizes`` rows each, summing to R; the rows
+    that belong to no expert here point at token N): two grouped matmuls,
+    then each row's result is added to its token.  (N, d) float32.  Rows
+    of no token are zero going in, and each grouped matmul's output is
+    masked before anything reads it, which masks its cotangents too: the
+    chip's kernels leave rows outside every group unwritten, and what
+    stands there must reach neither a token nor, times a zero, a
+    gradient."""
+    N = u.shape[0]
+    valid = (tok < N)[:, None]
+    keep = lambda t: jnp.where(valid, t, 0.0)
+    x = keep(jnp.take(u, tok, axis=0, mode="fill", fill_value=0))
+    hid = relu2(keep(grouped_dot(x.astype(cd), p["w_up"].astype(cd), sizes,
+                                 kernel)))
+    y = keep(grouped_dot(hid.astype(cd), p["w_down"].astype(cd), sizes,
+                         kernel)) * w_pair[:, None]
+    return jnp.zeros((N, y.shape[1]), F32).at[tok].add(y, mode="drop")
+
+
+def expert_runs(c: HybridPreset, pairs: int) -> Tuple[int, ...]:
+    """Lengths of the runs the sorted (token, choice) pairs go through the
+    held experts in: the first holds twice the rows a balanced router sends
+    here (``pairs * experts_held / n_experts``), each further one as many
+    as all before it, until every pair has a place: whatever the skew no
+    row is dropped, and memory is the largest run's.  In whole tiles of
+    the kernel's 256 rows (of 8 where a run is shorter)."""
+    first = -(-2 * pairs * c.experts_held // c.n_experts)
+    first = -(-first // 256) * 256 if first >= 256 else -(-first // 8) * 8
+    runs = [first]
+    while sum(runs) < pairs:
+        runs.append(sum(runs))
+    return tuple(runs)
+
+
+def routed_experts(p, u, chosen, w, sizes, c: HybridPreset, cd,
+                   kernel="auto"):
+    """The weighted sum over those of each token's chosen experts that are
+    held here, (N, d) float32; ``sizes`` (H,) the rows each held expert
+    received.  Dropless at any skew: the (token, choice) pairs are sorted
+    by held expert, the pairs of absent experts last, and go through the
+    grouped matmuls in runs (``expert_runs``); a run that starts past the
+    last row routed here is skipped, a run that holds one is computed
+    WHOLE (its tail of no expert rides as zero rows of the last expert).
+    Device work follows the rows routed here in steps of a run, and below
+    the first run's end a step's time does not follow the router: Adam's
+    first updates move a layer's rows by tens of per cent from one update
+    to the next (PERF.md section 6).  The price is the first run's empty
+    tail; handing the kernel each expert's true count instead (drop the
+    ``at[-1].add``: rows outside every group are masked already) saves
+    that and makes every step as long as its routing.  The runs are
+    unrolled: a ``scan`` over them would save each run's inputs, the
+    weights among them, once a run for the backward pass."""
+    N, H, k = u.shape[0], c.experts_held, c.top_k
+    local = chosen.reshape(-1) - c.first_expert                # (N k,)
+    key = jnp.where((local >= 0) & (local < H), local, H)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    ends = jnp.cumsum(sizes)
+    rows = ends[-1]
+    runs = expert_runs(c, N * k)
+    fill = sum(runs) - N * k
+    tok = jnp.pad(order // k, (0, fill), constant_values=N)
+    w_sorted = jnp.pad(jnp.take(w.reshape(-1), order), (0, fill))
+    p = {name: p[name].astype(cd) for name in ("w_up", "w_down")}
+
+    def run(lo, R):
+        """The part of the sorted rows [lo, lo + R)."""
+        @jax.checkpoint
+        def part(p, u, w_sorted):
+            with jax.named_scope(SCOPE_MOE), \
+                    jax.named_scope(SCOPE_MOE_EXPERTS):
+                here = jnp.clip(jnp.minimum(ends, lo + R)
+                                - jnp.maximum(ends - sizes, lo), 0)
+                here = here.at[-1].add(R - jnp.sum(here))
+                return _grouped_ffn(
+                    p, u, jnp.where(lo + jnp.arange(R) < rows,
+                                    tok[lo:lo + R], N),
+                    w_sorted[lo:lo + R], here, cd, kernel)
+        return part(p, u, w_sorted)
+
+    routed, lo = jnp.zeros((N, u.shape[1]), F32), 0
+    for R in runs:
+        routed = jax.lax.cond(lo < rows,
+                              lambda a, lo=lo, R=R: a + run(lo, R),
+                              lambda a: a, routed)
+        lo += R
+    return routed
+
+
+def moe_apply(p, u, c: HybridPreset, cd, kernel="auto"):
+    """One E mixer over (N, d) normed tokens: the shared expert plus the
+    routed experts held here.  Returns (out (N, d) float32, the tokens
+    that chose each of ALL experts (E,) int32); the rows of the experts
+    held here are ``held_load`` of that."""
+    with jax.named_scope(SCOPE_MOE):
+        chosen, w, load = route(p, u, c)
+        with jax.named_scope(SCOPE_MOE_EXPERTS):
+            routed = routed_experts(p, u, chosen, w, held_load(load, c), c,
+                                    cd, kernel)
+        with jax.named_scope(SCOPE_MOE_SHARED):
+            shared = _mm(relu2(_mm(u, p["w_shared_up"], cd)),
+                         p["w_shared_down"], cd)
+        return routed + shared, load
+
+
+def held_load(load, c: HybridPreset):
+    """The rows each expert held here received, (H,), of the (E,) load."""
+    return load[c.first_expert:c.first_expert + c.experts_held]
+
+
+def moe_stats(load: Dict[int, jnp.ndarray], c: HybridPreset
+              ) -> Dict[str, jnp.ndarray]:
+    """The program's routing counters as step metrics, from each E layer's
+    load (the tokens that chose each expert): ``learner/moe_rows_here``
+    (the rows the held experts received, mean over the E layers; per layer
+    as ``.../E<layer>``), ``learner/moe_rows_absent_share`` (mean: the
+    (token, choice) pairs whose expert is on another chip) and
+    ``learner/moe_load_max_over_mean`` (the busiest held expert over the
+    mean load of ALL experts, the worst layer: 1 is a balanced router)."""
+    if not load:
+        return {}
+    sizes = {i: held_load(n, c) for i, n in load.items()}
+    n_pairs = jnp.sum(next(iter(load.values()))).astype(F32)
+    mean = n_pairs / c.n_experts
+    rows = {i: jnp.sum(n).astype(F32) for i, n in sizes.items()}
+    here = jnp.mean(jnp.stack(list(rows.values())))
+    out = {f"learner/moe_rows_here/E{i}": r for i, r in rows.items()}
+    out["learner/moe_rows_here"] = here
+    out["learner/moe_rows_absent_share"] = 1.0 - here / n_pairs
+    out["learner/moe_load_max_over_mean"] = jnp.max(jnp.stack([
+        jnp.max(n) / mean for n in sizes.values()]))
+    return out
+
+
+def bias_step(b_sel, load, rate: float):
+    """One step of an E layer's selection bias against its load: up by
+    ``rate`` for the experts that received fewer tokens than the mean, down
+    for those that received more (the sign of the difference, nothing of
+    its size): the balancing without an auxiliary loss that the family is
+    trained with."""
+    load = load.astype(F32)
+    return b_sel + rate * jnp.sign(jnp.mean(load) - load)
+
+
+def balance_selection_bias(params, load: Dict[int, jnp.ndarray],
+                           c: HybridPreset):
+    """The update ``b_sel`` gets in place of a gradient, after every
+    optimizer step: ``bias_step`` in each E layer.  ``load`` = {layer
+    index: (E,) tokens per expert, this update}."""
+    layers = dict(params["params"])
+    for i, n in load.items():
+        layer = layers[f"layers_{i}"]
+        layers[f"layers_{i}"] = dict(layer, b_sel=bias_step(
+            layer["b_sel"], n, c.bias_rate))
+    return dict(params, params=layers)
+
+
+# ---------------------------------------------------------------------------
+# parameters and the model
+# ---------------------------------------------------------------------------
+
+_lecun = nn.initializers.lecun_normal()
+_lecun_experts = nn.initializers.variance_scaling(
+    1.0, "fan_in", "truncated_normal", in_axis=-2, out_axis=-1, batch_axis=0)
+_ones = nn.initializers.ones
+
+
+def _dt_bias_init(c: HybridPreset):
+    def init(key, shape, dtype=F32):
+        dt = jnp.exp(jax.random.uniform(key, shape, dtype)
+                     * (math.log(c.dt_max) - math.log(c.dt_min))
+                     + math.log(c.dt_min))
+        dt = jnp.maximum(dt, c.dt_floor)
+        return dt + jnp.log(-jnp.expm1(-dt))       # softplus^-1
+    return init
+
+
+def _a_log_init(key, shape, dtype=F32):
+    return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
+
+
+def _b_sel_init(key, shape, dtype=F32):
+    return 0.01 * jax.random.normal(key, shape, dtype)
+
+
+def layer_param_specs(kind: str, c: HybridPreset):
+    """name -> (initialiser, shape) of one layer's parameters."""
+    d = c.d_model
+    if kind == "M":
+        return {
+            "w_in": (_lecun, (d, 2 * c.d_inner + 2 * c.ssm_groups
+                              * c.ssm_state + c.ssm_heads)),
+            "conv_w": (_lecun, (c.conv_kernel, c.conv_dim)),
+            "conv_b": (nn.initializers.zeros, (c.conv_dim,)),
+            "dt_bias": (_dt_bias_init(c), (c.ssm_heads,)),
+            "A_log": (_a_log_init, (c.ssm_heads,)),
+            "D": (_ones, (c.ssm_heads,)),
+            "gate_norm": (_ones, (c.d_inner,)),
+            "w_out": (_lecun, (c.d_inner, d)),
+        }
+    if kind == "*":
+        hq, hk = c.attn_heads * c.attn_head_dim, c.kv_heads * c.attn_head_dim
+        return {"w_q": (_lecun, (d, hq)), "w_k": (_lecun, (d, hk)),
+                "w_v": (_lecun, (d, hk)), "w_o": (_lecun, (hq, d))}
+    if kind == "E":
+        H = c.experts_held
+        return {
+            "router": (_lecun, (d, c.n_experts)),
+            # selection has no gradient, so Adam never moves it: it is
+            # balance_selection_bias's to move
+            "b_sel": (_b_sel_init, (c.n_experts,)),
+            "w_up": (_lecun_experts, (H, d, c.expert_width)),
+            "w_down": (_lecun_experts, (H, c.expert_width, d)),
+            "w_shared_up": (_lecun, (d, c.shared_width)),
+            "w_shared_down": (_lecun, (c.shared_width, d)),
+        }
+    raise ValueError(f"unknown layer kind {kind!r} in a hybrid pattern")
+
+
+class _Layer(nn.Module):
+    """A layer's parameters (its pre-norm scale and its mixer's)."""
+
+    kind: str
+    preset: HybridPreset
+
+    def setup(self):
+        self.norm = self.param("norm", _ones, (self.preset.d_model,))
+        for name, (init, shape) in layer_param_specs(
+                self.kind, self.preset).items():
+            setattr(self, name, self.param(name, init, shape))
+
+    def params_dict(self):
+        return {name: getattr(self, name) for name in
+                ("norm", *layer_param_specs(self.kind, self.preset))}
+
+
+class HybridQModel(nn.Module):
+    """Frame embed -> the pattern's layers -> final norm -> Q head."""
+
+    action_space: int
+    state_shape: Tuple[int, ...] = ()    # the env's (C, H, W) frame stack
+    window: int = 32                     # T + 1 positions of a segment
+    preset: HybridPreset = PRESETS["tiny"]
+    norm_val: float = 255.0
+    compute_dtype: Any = jnp.bfloat16
+
+    def setup(self):
+        c = self.preset
+        H, W = self.state_shape[-2:]
+        self.w_embed = self.param("w_embed", _lecun, (H * W, c.d_model))
+        self.layers = [_Layer(kind, c) for kind in c.pattern]
+        self.final_norm = self.param("final_norm", _ones, (c.d_model,))
+        self.head_w = self.param("head_w", nn.initializers.zeros,
+                                 (c.d_model, self.action_space))
+        self.head_b = self.param("head_b", nn.initializers.zeros,
+                                 (self.action_space,))
+
+    # -- shared ends -------------------------------------------------------
+
+    def _embed(self, frames):
+        """(.., H, W) uint8 or float frames -> (.., d) in the compute dtype."""
+        with jax.named_scope(SCOPE_EMBED):
+            x = frames.astype(F32) / self.norm_val
+            x = x.reshape(*frames.shape[:-2], -1)
+            return _mm(x, self.w_embed, self.compute_dtype).astype(
+                self.compute_dtype)
+
+    def _head(self, x):
+        with jax.named_scope(SCOPE_HEAD):
+            x = rms_norm(x, self.final_norm, self.preset.norm_eps)
+            return jnp.matmul(x, self.head_w,
+                              precision=jax.lax.Precision.HIGHEST) + self.head_b
+
+    # -- learner path --------------------------------------------------------
+
+    def window_pass(self, frames):
+        """One causal pass over (B, T, H, W) frames from a zero state:
+        (Q (B, T, A) float32, {E layer index: (E,) tokens that chose each
+        expert}, {M layer index: (B, h, p, n) float32 state after the last
+        position})."""
+        c, cd = self.preset, self.compute_dtype
+        x = self._embed(frames)
+        B, T, d = x.shape
+        load, states = {}, {}
+        for i, layer in enumerate(self.layers):
+            p = layer.params_dict()
+            if layer.kind == "E":
+                @jax.checkpoint
+                def mix(p, x):
+                    u = rms_norm(x, p["norm"], c.norm_eps).reshape(B * T, d)
+                    out, n = moe_apply(p, u, c, cd)
+                    return x + out.reshape(B, T, d).astype(cd), n
+                x, load[i] = mix(p, x)
+            elif layer.kind == "M":
+                @jax.checkpoint
+                def mix(p, x):
+                    out, S = mamba_window(
+                        p, rms_norm(x, p["norm"], c.norm_eps), c, cd)
+                    return x + out.astype(cd), S
+                x, states[i] = mix(p, x)
+            else:
+                @jax.checkpoint
+                def mix(p, x):
+                    u = rms_norm(x, p["norm"], c.norm_eps)
+                    return x + attention_window(p, u, c, cd).astype(cd)
+                x = mix(p, x)
+        return self._head(x), load, states
+
+    def window_q(self, frames):
+        return self.window_pass(frames)[0]
+
+    def target_copy(self, params):
+        """How the train state keeps this model's target network."""
+        return bf16_target(params)
+
+    def train_parts(self, pack_frames: int = 0):
+        """What factory.build_train_state_and_step hands the sequence
+        train step: ``window_applies``."""
+        return window_applies(self, pack_frames)
+
+    # -- acting path ---------------------------------------------------------
+
+    @property
+    def act_window(self) -> int:
+        """Keys the acting cache keeps: the trained positions [0, T)."""
+        return self.window - 1
+
+    def zero_carry(self, batch: int):
+        """A flat tuple, every leaf leading with the batch dimension: per M
+        layer (conv tail, SSM state), per * layer (keys, values), then the
+        count of positions seen."""
+        c, out = self.preset, []
+        for kind in c.pattern:
+            if kind == "M":
+                out += [jnp.zeros((batch, c.conv_kernel - 1, c.conv_dim), F32),
+                        jnp.zeros((batch, c.ssm_heads, c.ssm_head_dim,
+                                   c.ssm_state), F32)]
+            elif kind == "*":
+                kv = (batch, self.act_window, c.kv_heads, c.attn_head_dim)
+                out += [jnp.zeros(kv, self.compute_dtype),
+                        jnp.zeros(kv, self.compute_dtype)]
+        return tuple(out) + (jnp.zeros((batch,), jnp.int32),)
+
+    def state_for_segment(self, carry, j: int):
+        """No stored state in the ring: a segment starts from zero state
+        and its burn-in prefix is context (as every ``dtqn*`` model)."""
+        return (np.zeros(1, np.float32), np.zeros(1, np.float32))
+
+    def __call__(self, obs, carry=None):
+        """One acting step: obs (B, C, H, W), the env's frame stack, of
+        which the newest frame is this position's; -> (Q (B, A), carry')."""
+        c, cd = self.preset, self.compute_dtype
+        if carry is None:
+            carry = self.zero_carry(obs.shape[0])
+        carry, count = list(carry[:-1]), carry[-1]
+        x = self._embed(obs[:, -1])
+        at = 0
+        for layer in self.layers:
+            p = layer.params_dict()
+            u = rms_norm(x, p["norm"], c.norm_eps)
+            if layer.kind == "M":
+                out, carry[at], carry[at + 1] = mamba_step(
+                    p, u, carry[at], carry[at + 1], c, cd)
+                at += 2
+            elif layer.kind == "*":
+                out, carry[at], carry[at + 1] = attention_step(
+                    p, u, carry[at], carry[at + 1], count, c, cd)
+                at += 2
+            else:
+                # a handful of rows, on whichever backend the actor,
+                # evaluator or tester was pinned to: never the TPU kernel
+                out = moe_apply(p, u, c, cd, kernel="xla")[0]
+            x = x + out.astype(cd)
+        return self._head(x), tuple(carry) + (count + 1,)
+
+
+LOAD_KEY = "moe_load/E"      # + layer index: an E layer's (E,) load
+
+
+def window_applies(model: HybridQModel, pack_frames: int = 0):
+    """The learner's parts for ops/sequence_losses.py
+    build_dtqn_train_step: ``online(params, obs) -> (Q, aux)``, where aux
+    holds the routing counters of the E layers as step metrics (scalars)
+    and each E layer's load under ``LOAD_KEY``; ``target(params, obs) ->
+    Q``; ``after_update(params, aux) -> params``, the step ``b_sel`` takes
+    against that load.  ``pack_frames`` = C: obs arrives frame-packed (B,
+    T + C, H, W) as the ring stores it, and position t reads frame t + C -
+    1, the newest of its stack."""
+    newest = lambda obs: obs[:, pack_frames - 1:] if pack_frames else obs
+
+    def online(params, obs):
+        q, load, _ = model.apply(params, newest(obs),
+                                 method=model.window_pass)
+        aux = moe_stats(load, model.preset)
+        aux.update({f"{LOAD_KEY}{i}": n for i, n in load.items()})
+        return q, aux
+
+    def target(params, obs):
+        return model.apply(params, newest(obs), method=model.window_q)
+
+    def after_update(params, aux):
+        return balance_selection_bias(
+            params, {int(k[len(LOAD_KEY):]): n for k, n in aux.items()
+                     if k.startswith(LOAD_KEY)}, model.preset)
+
+    return online, target, after_update

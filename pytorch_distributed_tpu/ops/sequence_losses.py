@@ -267,6 +267,7 @@ def build_dtqn_train_step(
     axis_name: str | None = None,
     aux_weight: float = 0.0,
     target_window_apply: Callable | None = None,
+    after_update: Callable | None = None,
     guard: bool = True,
 ) -> Callable[[TrainState, SegmentBatch],
               Tuple[TrainState, Dict[str, jnp.ndarray], jnp.ndarray]]:
@@ -280,7 +281,13 @@ def build_dtqn_train_step(
     MoE models (models/moe.py) pass a ``window_apply`` returning
     ``(q, aux)`` instead — the auxiliary load-balancing loss joins the TD
     loss with weight ``aux_weight`` and surfaces as
-    ``learner/moe_aux``.  ``target_window_apply``, when given, evaluates
+    ``learner/moe_aux``.  A ``window_apply`` returning ``(q, {name:
+    array})`` (models/hybrid.py: the routing counters of its expert
+    layers) has the dict's scalars joined to the step's metrics instead,
+    and the whole dict handed to ``after_update(params, dict) -> params``
+    once the optimizer has stepped: the place for what a model updates by
+    a rule of its own and not by a gradient.
+    ``target_window_apply``, when given, evaluates
     the target-network pass — MoE passes a q-only apply here so the
     frozen pass skips the mutable sow collection whose aux value is
     discarded anyway (round-2 advisor finding)."""
@@ -289,9 +296,14 @@ def build_dtqn_train_step(
     h_inv = value_unrescale if rescale_values else (lambda x: x)
 
     def split_apply(params, obs):
+        """-> (q, aux loss, step metrics)."""
         out = window_apply(params, obs)
         # tuple-vs-array is static python structure, resolved at trace time
-        return out if isinstance(out, tuple) else (out, jnp.float32(0.0))
+        if not isinstance(out, tuple):
+            return out, jnp.float32(0.0), {}
+        q, aux = out
+        return (q, jnp.float32(0.0), aux) if isinstance(aux, dict) \
+            else (q, aux, {})
 
     def target_apply(params, obs):
         if target_window_apply is not None:
@@ -314,7 +326,7 @@ def build_dtqn_train_step(
             m_tm = jnp.moveaxis(batch.mask, 0, 1)[burn_in:]
 
         def loss_fn(params):
-            q, aux = split_apply(params, batch.obs)
+            q, aux, stats = split_apply(params, batch.obs)
             q_tm = to_tm(q)
             q_sel = jnp.take_along_axis(
                 q_tm[:train_len], a_tm[..., None].astype(jnp.int32),
@@ -326,12 +338,20 @@ def build_dtqn_train_step(
             loss, seq_pr = _masked_loss_and_priority(
                 q_sel, target, m_tm, batch.weight, priority_eta)
             loss = loss + aux_weight * aux
-            return loss, (seq_pr, jnp.mean(jnp.max(q_tm, axis=-1)), aux)
+            return loss, (seq_pr, jnp.mean(jnp.max(q_tm, axis=-1)), aux,
+                          stats)
 
-        (loss, (seq_pr, q_mean, aux)), grads = online_grad(
+        (loss, (seq_pr, q_mean, aux, stats)), grads = online_grad(
             loss_fn, has_aux=True)(state.params)
-        extra = {"learner/moe_aux": aux} if aux_weight else None
-        return _apply_update(state, grads, loss, seq_pr, q_mean, tx,
-                             target_model_update, axis_name, extra)
+        extra = {k: v for k, v in stats.items() if jnp.ndim(v) == 0}
+        if aux_weight:
+            extra["learner/moe_aux"] = aux
+        new, metrics, seq_pr = _apply_update(
+            state, grads, loss, seq_pr, q_mean, tx, target_model_update,
+            axis_name, extra)
+        if after_update is not None:
+            with jax.named_scope(PHASE_OPTIMIZER):
+                new = new._replace(params=after_update(new.params, stats))
+        return new, metrics, seq_pr
 
     return finite_guard(step) if guard else step

@@ -27,7 +27,8 @@ def soft_update(target: PyTree, online: PyTree, tau: float) -> PyTree:
     """Polyak averaging ``t <- (1-tau) t + tau o`` — reference
     utils/helpers.py:20-23 (the tau<1 branch, used by DDPG)."""
     return jax.tree_util.tree_map(
-        lambda t, o: (1.0 - tau) * t + tau * o, target, online
+        lambda t, o: ((1.0 - tau) * t + tau * o).astype(t.dtype),
+        target, online
     )
 
 
@@ -38,7 +39,7 @@ def periodic_update(target: PyTree, online: PyTree, step: jnp.ndarray,
     helper internally gates on ``step % period == 0``."""
     do = (step % period) == 0
     return jax.tree_util.tree_map(
-        lambda t, o: jnp.where(do, o, t), target, online
+        lambda t, o: jnp.where(do, o.astype(t.dtype), t), target, online
     )
 
 

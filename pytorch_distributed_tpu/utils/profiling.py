@@ -59,6 +59,18 @@ DEVICE_PHASES = (PHASE_DRAW, PHASE_GATHER, PHASE_TARGET, PHASE_ONLINE,
 # the recurrent family nests these inside train.target / train.online
 SCOPE_BURN_IN = "burn_in"
 SCOPE_UNROLL = "unroll"
+# the hybrid trunk (models/hybrid.py) names its layers inside train.target /
+# train.online, INSIDE each jax.checkpoint and scan body: the model's parts
+# cut the same device time as the two phases another way
+SCOPE_EMBED = "model.embed"
+SCOPE_SSM = "model.ssm"
+SCOPE_ATTN = "model.attn"
+SCOPE_MOE = "model.moe"               # holds the three below
+SCOPE_MOE_ROUTE = "moe.route"
+SCOPE_MOE_EXPERTS = "moe.experts"
+SCOPE_MOE_SHARED = "moe.shared"
+SCOPE_HEAD = "model.head"
+MODEL_SCOPES = (SCOPE_EMBED, SCOPE_SSM, SCOPE_ATTN, SCOPE_MOE, SCOPE_HEAD)
 
 
 @functools.lru_cache(maxsize=None)
