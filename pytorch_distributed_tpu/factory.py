@@ -659,8 +659,10 @@ def build_train_state_and_step(opt: Options, spec: EnvSpec, model, params,
                     p, obs, method=train_model.window_q)
             step = build_dtqn_train_step(window_apply, tx, **kw)
         else:
+            from pytorch_distributed_tpu.models.drqn import halves
+
             step = build_drqn_train_step(
-                model.apply, tx,
+                *halves(model), tx,
                 packed_frames=sequence_pack_frames(opt), **kw)
         return state, step
 

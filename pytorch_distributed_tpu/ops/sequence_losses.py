@@ -3,8 +3,10 @@
 The recurrent counterpart of ops/losses.py build_dqn_train_step: consumes
 a SegmentBatch (memory/sequence_replay.py), runs
 
-    burn-in unroll (stored state, gradients stopped)
-    -> train-window unroll (online + target nets)
+    per-observation half of each net over all of its frames at once
+    -> burn-in unroll of the recurrent half (stored state, gradients
+       stopped)
+    -> train-window unroll of it (online + target nets)
     -> within-window n-step double-DQN targets with value rescaling
     -> masked, IS-weighted MSE
     -> Adam -> target update
@@ -22,8 +24,14 @@ flag so ablations stay possible:
   valid steps, returned as ``td_abs`` for the replay's write-back — the
   same contract Batch-based steps use, so the learner loop is unchanged.
 
-``lax.scan`` carries the LSTM over time (compiler-friendly control flow —
-no Python loop over T); the n-step lookahead is a static unroll over
+A recurrent Q-network comes as TWO halves (models/drqn.py ``halves``):
+the per-observation ``embed`` (torso: stateless from one step to the next)
+and the recurrent ``core`` (LSTM cell + Q head).  The step runs ``embed``
+once per pass (burn-in prefix, train window) of each network, batched
+over every frame of it in the batch-major order the segments arrive in;
+only its ``(B, n, F)`` features are moved to time-major, and ``lax.scan``
+carries the LSTM alone over time (compiler-friendly control flow — no Python loop over T, no
+convolution inside one).  The n-step lookahead is a static unroll over
 ``nstep`` shifted views (nstep is small and static).
 """
 
@@ -42,7 +50,7 @@ from pytorch_distributed_tpu.utils.health import finite_guard
 from pytorch_distributed_tpu.utils.helpers import global_norm, update_target
 from pytorch_distributed_tpu.utils.profiling import (
     PHASE_GATHER, PHASE_ONLINE, PHASE_OPTIMIZER, PHASE_TARGET,
-    SCOPE_BURN_IN, SCOPE_UNROLL,
+    SCOPE_BURN_IN, SCOPE_EMBED, SCOPE_UNROLL,
 )
 
 PyTree = Any
@@ -74,23 +82,23 @@ def unpack_frame_stacks(frames: jnp.ndarray, C: int,
                      axis=2)
 
 
-def unroll(apply_fn: Callable, params: PyTree, carry, obs_tm: jnp.ndarray,
+def unroll(core_fn: Callable, params: PyTree, carry, x_tm: jnp.ndarray,
            phase: str | None = None) -> Tuple[Any, jnp.ndarray]:
-    """Scan the single-step recurrent apply over a time-major observation
-    sequence (T, B, *S) -> (carry_out, q_seq (T, B, A)).
+    """Scan the recurrent half ``core_fn(params, x, carry) -> (q, carry')``
+    over time-major features (T, B, F) -> (carry_out, q_seq (T, B, A)).
 
     ``phase`` names the device phase (utils/profiling.py) INSIDE the scan
     body as well: what JAX hoists out of a differentiated scan
     (loop-invariant casts of the weights) keeps the names entered in the
     body and loses every name entered around the scan."""
 
-    def step(c, o):
+    def step(c, x):
         with (jax.named_scope(phase) if phase
               else contextlib.nullcontext()):
-            q, c2 = apply_fn(params, o, c)
+            q, c2 = core_fn(params, x, c)
         return c2, q
 
-    return jax.lax.scan(step, carry, obs_tm)
+    return jax.lax.scan(step, carry, x_tm)
 
 
 def nstep_window_returns(boot: jnp.ndarray, r_tm: jnp.ndarray,
@@ -174,7 +182,8 @@ def _apply_update(state, grads, loss, seq_pr, q_mean, tx,
 
 
 def build_drqn_train_step(
-    apply_fn: Callable,
+    embed_fn: Callable,
+    core_fn: Callable,
     tx: optax.GradientTransformation,
     *,
     burn_in: int = 10,
@@ -191,6 +200,10 @@ def build_drqn_train_step(
               Tuple[TrainState, Dict[str, jnp.ndarray], jnp.ndarray]]:
     """Returns ``(state, batch) -> (state, metrics, seq_priorities)``.
 
+    ``embed_fn(params, obs (N, *S)) -> x (N, F)`` and ``core_fn(params,
+    x (B, F), carry) -> (q, carry')`` are the network's two halves
+    (models/drqn.py ``halves``).
+
     ``packed_frames=C``: ``batch.obs`` arrives frame-packed (B, T+C, H,
     W) and the stacks are rebuilt on device (unpack_frame_stacks) — the
     R2D2 pixel path's host->device transfer shrinks ~C-fold."""
@@ -198,31 +211,42 @@ def build_drqn_train_step(
     h = value_rescale if rescale_values else (lambda x: x)
     h_inv = value_unrescale if rescale_values else (lambda x: x)
 
+    def features(params, obs):
+        """The per-observation half over every frame of ``obs`` (B, n,
+        *S) at once, batch-major as it arrives -> time-major (n, B, F)."""
+        with jax.named_scope(SCOPE_EMBED):
+            x = embed_fn(params, obs.reshape(-1, *obs.shape[2:]))
+            return jnp.moveaxis(x.reshape(*obs.shape[:2], -1), 0, 1)
+
     def step(state: TrainState, batch: SegmentBatch):
         T = batch.action.shape[1]
         with jax.named_scope(PHASE_GATHER):
-            obs = batch.obs
+            obs = batch.obs                          # (B, T+1, *S)
             if packed_frames:
                 obs = unpack_frame_stacks(obs, packed_frames, T)
-            obs_tm = jnp.moveaxis(obs, 0, 1)        # (T+1, B, *S)
         train_len = T - burn_in
         carry0 = (batch.c0, batch.h0)
 
-        def burn_and_unroll(params, phase, refresh=lambda carry: carry):
-            """Refresh the stored state over the burn-in prefix, then
-            unroll the train window from it: (train_len+1, B, A)."""
-            with jax.named_scope(SCOPE_BURN_IN):
-                carry, _ = (unroll(apply_fn, params, carry0,
-                                   obs_tm[:burn_in], phase)
-                            if burn_in else (carry0, None))
-                carry = refresh(carry)
+        def burn_and_unroll(params, burn_params, phase):
+            """Refresh the stored state over the burn-in prefix (under
+            ``burn_params``; no gradient passes the refreshed state), then
+            unroll the train window from it: (train_len+1, B, A).  Each
+            stretch is its frames' features in one batched pass, then the
+            scan of the recurrent half."""
+            carry = carry0
+            if burn_in:
+                x_burn = features(burn_params, obs[:, :burn_in])
+                with jax.named_scope(SCOPE_BURN_IN):
+                    carry = jax.lax.stop_gradient(unroll(
+                        core_fn, burn_params, carry0, x_burn, phase)[0])
+            x_train = features(params, obs[:, burn_in:])
             with jax.named_scope(SCOPE_UNROLL):
-                return unroll(apply_fn, params, carry, obs_tm[burn_in:],
-                              phase)[1]
+                return unroll(core_fn, params, carry, x_train, phase)[1]
 
         # target-side state refresh + full unroll (no gradients flow here)
         with jax.named_scope(PHASE_TARGET):
-            q_target_tm = burn_and_unroll(state.target_params, PHASE_TARGET)
+            q_target_tm = burn_and_unroll(
+                state.target_params, state.target_params, PHASE_TARGET)
 
         # time-major views of the train window
         with jax.named_scope(PHASE_ONLINE):
@@ -232,8 +256,11 @@ def build_drqn_train_step(
             m_tm = jnp.moveaxis(batch.mask, 0, 1)[burn_in:]
 
         def loss_fn(params):
-            q_tm = burn_and_unroll(params, PHASE_ONLINE,
-                                   jax.lax.stop_gradient)
+            # the burn-in prefix only refreshes the state and no gradient
+            # passes that refresh: under frozen weights it is a forward
+            # pass, nothing of it kept for a backward
+            q_tm = burn_and_unroll(params, jax.lax.stop_gradient(params),
+                                   PHASE_ONLINE)
             q_sel = jnp.take_along_axis(
                 q_tm[:train_len], a_tm[..., None].astype(jnp.int32),
                 axis=-1)[..., 0]                                  # (L, B)

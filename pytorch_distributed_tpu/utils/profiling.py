@@ -56,7 +56,9 @@ PHASE_WRITEBACK = "replay.writeback"  # |TD| priority scatter + its guard
 PHASE_FEED = "replay.feed"            # ring writes (set-up, closed loop)
 DEVICE_PHASES = (PHASE_DRAW, PHASE_GATHER, PHASE_TARGET, PHASE_ONLINE,
                  PHASE_OPTIMIZER, PHASE_WRITEBACK, PHASE_FEED)
-# the recurrent family nests these inside train.target / train.online
+# the recurrent family nests these inside train.target / train.online:
+# the scans of the network's recurrent half (ops/sequence_losses.py); its
+# per-observation half runs before them under SCOPE_EMBED
 SCOPE_BURN_IN = "burn_in"
 SCOPE_UNROLL = "unroll"
 # the hybrid trunk (models/hybrid.py) names its layers inside train.target /

@@ -82,7 +82,7 @@ def test_writeback_then_new_rows_enter_at_max():
 
 
 def _drqn_setup(lstm=8):
-    from pytorch_distributed_tpu.models.drqn import DrqnMlpModel
+    from pytorch_distributed_tpu.models.drqn import DrqnMlpModel, halves
     from pytorch_distributed_tpu.ops.losses import (
         init_train_state, make_optimizer,
     )
@@ -94,7 +94,7 @@ def _drqn_setup(lstm=8):
     params = model.init(jax.random.PRNGKey(0), jnp.zeros((1, *S)))
     tx = make_optimizer(1e-3)
     ts = init_train_state(params, tx)
-    step = build_drqn_train_step(model.apply, tx, burn_in=1, nstep=2,
+    step = build_drqn_train_step(*halves(model), tx, burn_in=1, nstep=2,
                                  target_model_update=100)
     return ts, step
 

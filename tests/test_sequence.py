@@ -98,16 +98,16 @@ class TestSequenceReplay:
         assert batch.weight[batch.index == 7].max() < 1e-3
 
 
+def _linear_halves():
+    # linear "recurrent" net as the step takes it, in two halves: the
+    # per-observation one passes obs through, the recurrent one is
+    # q = W x + carry passthrough, so targets are hand-computable;
+    # carry = (c, h) each (B, 1)
+    return (lambda params, obs: obs,
+            lambda params, x, carry: (x @ params["w"], carry))  # (B, A)
+
+
 class TestSequenceLoss:
-    def _apply(self):
-        # linear "recurrent" net: q = W obs + carry passthrough, so targets
-        # are hand-computable; carry = (c, h) each (B, 1)
-        def apply(params, obs, carry=None):
-            q = obs @ params["w"]  # (B, A)
-            if carry is None:
-                carry = (jnp.zeros((obs.shape[0], 1)),) * 2
-            return q, carry
-        return apply
 
     def test_nstep_window_targets_match_hand_computation(self):
         from pytorch_distributed_tpu.memory.sequence_replay import (
@@ -122,12 +122,11 @@ class TestSequenceLoss:
         import optax
 
         T, nstep, gamma = 4, 2, 0.5
-        apply = self._apply()
         params = {"w": jnp.eye(1, 3)}  # q(obs)[a] = obs for a=0 else 0
         tx = optax.sgd(0.0)  # zero lr: inspect td via returned priorities
         state = init_train_state(params, tx)
         step = build_drqn_train_step(
-            apply, tx, burn_in=0, nstep=nstep, gamma=gamma,
+            *_linear_halves(), tx, burn_in=0, nstep=nstep, gamma=gamma,
             enable_double=False, target_model_update=10 ** 9,
             rescale_values=False, priority_eta=1.0)
 
@@ -161,12 +160,11 @@ class TestSequenceLoss:
         from pytorch_distributed_tpu.ops.losses import init_train_state
         import optax
 
-        apply = self._apply()
         params = {"w": jnp.eye(1, 3) * 0.0}  # q == 0 everywhere
         tx = optax.sgd(0.0)
         state = init_train_state(params, tx)
         step = build_drqn_train_step(
-            apply, tx, burn_in=0, nstep=3, gamma=0.5,
+            *_linear_halves(), tx, burn_in=0, nstep=3, gamma=0.5,
             enable_double=False, target_model_update=10 ** 9,
             rescale_values=False, priority_eta=1.0)
         # episode ends at t=1 with reward 10; tail padded
@@ -200,17 +198,11 @@ class TestTruncationBootstrap:
         from pytorch_distributed_tpu.ops.losses import init_train_state
         import optax
 
-        def apply(params, obs, carry=None):
-            q = obs @ params["w"]
-            if carry is None:
-                carry = (jnp.zeros((obs.shape[0], 1)),) * 2
-            return q, carry
-
         params = {"w": jnp.eye(1, 3)}  # q(obs)[0] = obs
         tx = optax.sgd(0.0)
         state = init_train_state(params, tx)
         step = build_drqn_train_step(
-            apply, tx, burn_in=0, nstep=3, gamma=0.5,
+            *_linear_halves(), tx, burn_in=0, nstep=3, gamma=0.5,
             enable_double=False, target_model_update=10 ** 9,
             rescale_values=False, priority_eta=1.0)
         # 2 valid steps (truncated, NO terminal); bootstrap obs = 7 at
@@ -235,7 +227,7 @@ class TestTruncationBootstrap:
 
 class TestRecurrentModel:
     def test_unroll_matches_stepwise(self):
-        from pytorch_distributed_tpu.models.drqn import DrqnMlpModel
+        from pytorch_distributed_tpu.models.drqn import DrqnMlpModel, halves
         from pytorch_distributed_tpu.ops.sequence_losses import unroll
 
         model = DrqnMlpModel(action_space=3, hidden_dim=16, lstm_dim=8)
@@ -243,7 +235,9 @@ class TestRecurrentModel:
         params = model.init(jax.random.PRNGKey(0), obs)
         seq = jax.random.normal(jax.random.PRNGKey(1), (5, 2, 4))
         carry = model.zero_carry(2)
-        _, q_seq = unroll(model.apply, params, carry, seq)
+        embed, core = halves(model)
+        x_seq = embed(params, seq.reshape(10, 4)).reshape(5, 2, -1)
+        _, q_seq = unroll(core, params, carry, x_seq)
         c = carry
         for t in range(5):
             q_t, c = model.apply(params, seq[t], c)
@@ -260,6 +254,233 @@ class TestRecurrentModel:
         q_explicit, _ = model.apply(params, obs, model.zero_carry(2))
         np.testing.assert_allclose(np.asarray(q_default),
                                    np.asarray(q_explicit))
+
+
+def _whole_model_scan_loss(apply_fn, params, target_params, batch, *,
+                           burn_in, nstep, gamma, packed_frames):
+    """The update as it was before the model came in two halves, kept
+    here as the oracle: the WHOLE single-step apply (torso + LSTM + head)
+    scanned over time-major observations, four scans an update.
+    -> (loss, per-sequence priorities)."""
+    from pytorch_distributed_tpu.ops import sequence_losses as sl
+
+    T = batch.action.shape[1]
+    obs = batch.obs
+    if packed_frames:
+        obs = sl.unpack_frame_stacks(obs, packed_frames, T)
+    obs_tm = jnp.moveaxis(obs, 0, 1)
+
+    def scan(p, carry, o_tm):
+        def body(c, o):
+            q, c2 = apply_fn(p, o, c)
+            return c2, q
+        return jax.lax.scan(body, carry, o_tm)
+
+    def burn_and_unroll(p, refresh):
+        carry = (batch.c0, batch.h0)
+        if burn_in:
+            carry = refresh(scan(p, carry, obs_tm[:burn_in])[0])
+        return scan(p, carry, obs_tm[burn_in:])[1]
+
+    q_target_tm = burn_and_unroll(target_params, lambda c: c)
+    q_tm = burn_and_unroll(params, jax.lax.stop_gradient)
+    tm = lambda x: jnp.moveaxis(x, 0, 1)[burn_in:]
+    a_tm, r_tm, d_tm, m_tm = (tm(x) for x in (
+        batch.action, batch.reward, batch.terminal, batch.mask))
+    q_sel = jnp.take_along_axis(
+        q_tm[:T - burn_in], a_tm[..., None].astype(jnp.int32),
+        axis=-1)[..., 0]
+    boot = sl._bootstrap_values(q_tm, q_target_tm, True, sl.value_unrescale)
+    target = sl.value_rescale(sl.nstep_window_returns(
+        boot, r_tm, d_tm, m_tm, nstep=nstep, gamma=gamma))
+    return sl._masked_loss_and_priority(q_sel, target, m_tm, batch.weight,
+                                        0.9)
+
+
+def _find_eqns(jaxpr, name, in_scan=False):
+    """[(inside a scan body?, eqn)] of every ``name`` equation of a jaxpr,
+    sub-jaxprs (calls, scans, custom derivatives, branches) included."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == name:
+            found.append((in_scan, eqn))
+        inner = in_scan or eqn.primitive.name == "scan"
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    found += _find_eqns(sub, name, inner)
+    return found
+
+
+class TestTwoHalves:
+    """The recurrent Q-network as ``embed`` (per observation, batched over
+    every frame of an update) + ``core`` (the LSTM and the head, alone in
+    the time scans): models/drqn.py, ops/sequence_losses.py."""
+
+    B, T, C = 3, 6, 4
+
+    def _model(self, kind):
+        from pytorch_distributed_tpu.models.drqn import (
+            DrqnCnnModel, DrqnMlpModel,
+        )
+
+        if kind == "cnn":   # 36x36: the least the Nature convs leave a 1x1
+            return DrqnCnnModel(action_space=3, lstm_dim=8,
+                                compute_dtype=jnp.float32), (36, 36)
+        return DrqnMlpModel(action_space=3, hidden_dim=16, lstm_dim=8,
+                            norm_val=255.0), (5, 5)
+
+    def _batch(self, hw, packed_frames, seed=0):
+        from pytorch_distributed_tpu.memory.sequence_replay import (
+            SegmentBatch,
+        )
+
+        B, T, C = self.B, self.T, self.C
+        rng = np.random.default_rng(seed)
+        shape = (B, T + C, *hw) if packed_frames else (B, T + 1, C, *hw)
+        mask = np.ones((B, T), np.float32)
+        mask[0, T - 1:] = 0.0       # one truncated tail
+        return SegmentBatch(
+            obs=rng.integers(0, 256, shape).astype(np.uint8),
+            action=rng.integers(0, 3, (B, T)).astype(np.int32),
+            reward=rng.normal(size=(B, T)).astype(np.float32) * mask,
+            terminal=(rng.random((B, T)) < 0.1).astype(np.float32) * mask,
+            mask=mask,
+            c0=rng.normal(size=(B, 8)).astype(np.float32) * 0.1,
+            h0=rng.normal(size=(B, 8)).astype(np.float32) * 0.1,
+            weight=rng.random(B).astype(np.float32) + 0.5,
+            index=np.arange(B, dtype=np.int32))
+
+    def _state_and_step(self, kind, burn_in, packed_frames, **kw):
+        import optax
+
+        from pytorch_distributed_tpu.models.drqn import halves
+        from pytorch_distributed_tpu.ops.losses import init_train_state
+        from pytorch_distributed_tpu.ops.sequence_losses import (
+            build_drqn_train_step,
+        )
+
+        model, hw = self._model(kind)
+        params = model.init(jax.random.PRNGKey(0),
+                            np.zeros((1, self.C, *hw), np.uint8))
+        tx = optax.sgd(1.0)         # new params = params - gradient
+        state = init_train_state(params, tx)
+        # a target net that differs from the online one
+        state = state._replace(target_params=jax.tree_util.tree_map(
+            lambda x: 0.9 * x, params))
+        step = build_drqn_train_step(
+            *halves(model), tx, burn_in=burn_in, nstep=3, gamma=0.9,
+            target_model_update=10 ** 9, packed_frames=packed_frames, **kw)
+        return model, hw, state, step
+
+    @pytest.mark.parametrize("packed_frames", [0, 4])
+    @pytest.mark.parametrize("burn_in", [0, 2])
+    @pytest.mark.parametrize("kind", ["mlp", "cnn"])
+    def test_step_matches_the_whole_model_scan(self, kind, burn_in,
+                                               packed_frames):
+        model, hw, state, step = self._state_and_step(
+            kind, burn_in, packed_frames)
+        batch = self._batch(hw, packed_frames)
+        new, metrics, seq_pr = jax.jit(step)(state, batch)
+        (loss, want_pr), want_grads = jax.jit(jax.value_and_grad(
+            lambda p: _whole_model_scan_loss(
+                model.apply, p, state.target_params, batch,
+                burn_in=burn_in, nstep=3, gamma=0.9,
+                packed_frames=packed_frames), has_aux=True))(state.params)
+        np.testing.assert_allclose(float(metrics["learner/critic_loss"]),
+                                   float(loss), rtol=1e-5)
+        np.testing.assert_allclose(np.asarray(seq_pr), np.asarray(want_pr),
+                                   rtol=1e-5, atol=1e-6)
+        grads = jax.tree_util.tree_map(lambda a, b: a - b, state.params,
+                                       new.params)
+        got = jax.tree_util.tree_leaves_with_path(grads)
+        want = jax.tree_util.tree_leaves_with_path(want_grads)
+        assert [k for k, _ in got] == [k for k, _ in want]
+        assert any(float(jnp.max(jnp.abs(w))) > 1e-4 for _, w in want)
+        for (path, g), (_, w) in zip(got, want):
+            np.testing.assert_allclose(
+                np.asarray(g), np.asarray(w), rtol=1e-4, atol=2e-6,
+                err_msg=jax.tree_util.keystr(path))
+
+    @pytest.mark.parametrize("kind,shapes", [
+        ("cnn", {"Conv_0": {"kernel": (8, 8, 4, 32), "bias": (32,)},
+                 "Conv_1": {"kernel": (4, 4, 32, 64), "bias": (64,)},
+                 "Conv_2": {"kernel": (3, 3, 64, 64), "bias": (64,)},
+                 "Dense_0": {"kernel": (64, 8), "bias": (8,)},
+                 "OptimizedLSTMCell_0": dict(
+                     {f"i{g}": {"kernel": (8, 8)} for g in "ifgo"},
+                     **{f"h{g}": {"kernel": (8, 8), "bias": (8,)}
+                        for g in "ifgo"}),
+                 "Dense_1": {"kernel": (8, 3), "bias": (3,)}}),
+        ("mlp", {"Dense_0": {"kernel": (100, 16), "bias": (16,)},
+                 "OptimizedLSTMCell_0": dict(
+                     {f"i{g}": {"kernel": (16, 8)} for g in "ifgo"},
+                     **{f"h{g}": {"kernel": (8, 8), "bias": (8,)}
+                        for g in "ifgo"}),
+                 "Dense_1": {"kernel": (8, 3), "bias": (3,)}}),
+    ])
+    def test_apply_is_core_of_embed_on_the_same_tree(self, kind, shapes):
+        """Acting (``apply``) is the recurrent half applied to the
+        per-observation half, and the parameter tree has the names and
+        shapes it had when one compact call made it: a checkpoint saved
+        before the split loads."""
+        from pytorch_distributed_tpu.models.drqn import halves
+
+        model, hw = self._model(kind)
+        obs = np.random.default_rng(1).integers(
+            0, 256, (2, self.C, *hw)).astype(np.uint8)
+        params = model.init(jax.random.PRNGKey(0), obs)
+        assert jax.tree_util.tree_map(jnp.shape, params) == {
+            "params": shapes}
+        carry = tuple(jax.random.normal(jax.random.PRNGKey(i), (2, 8))
+                      for i in (2, 3))
+        embed, core = halves(model)
+        x = embed(params, obs)
+        assert x.shape == (2, 8 if kind == "cnn" else 16)
+        assert x.dtype == jnp.float32
+        for got, want in zip(
+                jax.tree_util.tree_leaves(core(params, x, carry)),
+                jax.tree_util.tree_leaves(model.apply(params, obs, carry))):
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        q0, _ = model.apply(params, obs)
+        q0_halves, _ = core(params, x, model.zero_carry(2))
+        np.testing.assert_array_equal(np.asarray(q0), np.asarray(q0_halves))
+
+    def test_no_convolution_inside_a_time_scan(self):
+        """Does the mechanism engage: in the drqn-cnn step every
+        convolution runs OUTSIDE the scans, once per pass over all of its
+        frames, and the online burn-in frames get a forward only."""
+        burn_in = 2
+        model, hw, state, step = self._state_and_step(
+            "cnn", burn_in, 4, guard=False)
+        batch = self._batch(hw, 4)
+        jaxpr = jax.make_jaxpr(step)(state, batch).jaxpr
+        convs = _find_eqns(jaxpr, "conv_general_dilated")
+        scans = _find_eqns(jaxpr, "scan")
+        # target burn-in + unroll, online burn-in + unroll + its backward
+        assert len(scans) == 5
+        assert [e for inside, e in convs if inside] == []
+        # frames a pass: B * burn_in = 6 and B * (T + 1 - burn_in) = 15,
+        # numbers no other dimension of these shapes takes
+        n_burn, n_train = self.B * burn_in, self.B * (self.T + 1 - burn_in)
+
+        def over(n):
+            return sum(any(n in v.aval.shape for v in (*e.invars, *e.outvars))
+                       for _, e in convs)
+
+        # three convs, target + online, forward only: no transpose
+        assert over(n_burn) == 6
+        # the same six forward + the backward: Conv_1 and Conv_2 towards
+        # their input and their kernel, Conv_0 (pixels in) its kernel only
+        assert over(n_train) == 11
+        assert len(convs) == 17
+        # the oracle is what the step was: convolutions in every scan
+        old = jax.make_jaxpr(lambda p: _whole_model_scan_loss(
+            model.apply, p, state.target_params, batch, burn_in=burn_in,
+            nstep=3, gamma=0.9, packed_frames=4)[0])(state.params).jaxpr
+        assert sum(inside for inside, _ in _find_eqns(
+            old, "conv_general_dilated")) == 12
 
 
 @pytest.mark.slow
@@ -366,7 +587,7 @@ class TestFramePacking:
         from pytorch_distributed_tpu.memory.sequence_replay import (
             SegmentBatch,
         )
-        from pytorch_distributed_tpu.models.drqn import DrqnCnnModel
+        from pytorch_distributed_tpu.models.drqn import DrqnCnnModel, halves
         from pytorch_distributed_tpu.ops.losses import (
             init_train_state, make_optimizer,
         )
@@ -408,7 +629,7 @@ class TestFramePacking:
                 weight=np.ones(1, np.float32),
                 index=np.zeros(1, np.int32))
             step = jax.jit(build_drqn_train_step(
-                model.apply, tx, burn_in=2, nstep=3,
+                *halves(model), tx, burn_in=2, nstep=3,
                 target_model_update=100, packed_frames=packed_frames))
             _st, metrics, pr = step(init_train_state(params, tx), batch)
             losses[name] = (float(metrics["learner/critic_loss"]),
