@@ -543,7 +543,7 @@ def _drive_actor_loop(h: _ActorHarness, engine, clock: GlobalClock,
     aggregate of the two so dashboards compare across schedules.
     """
     timer = h.timer
-    h.engine = engine  # introspection: bench/tests read jit_cache_size
+    h.engine = engine  # introspection: tests read jit_cache_size
     # retrace detector: the fused act program must never recompile
     # after warmup (batched engines return None — the jit lives
     # server-side and the server registers its own)
@@ -647,7 +647,7 @@ def _drive_device_actor_loop(h: _ActorHarness, clock: GlobalClock,
     rollout = build_fused_rollout(h.model.apply, env, nstep=ap.nstep,
                                   gamma=ap.gamma, rollout_ticks=K,
                                   emit="chunk")
-    h.rollout_jit = rollout  # introspection: tests/bench read the cache
+    h.rollout_jit = rollout  # introspection: tests read the cache
     # perf plane: the fused rollout is a registered hot program (a
     # post-warmup recompile = a shape/dtype leak paying compile latency
     # on the hot path) and its per-frame FLOPs feed the actor-side MFU
@@ -810,7 +810,7 @@ def run_ddpg_actor(opt: Options, spec: EnvSpec, process_ind: int,
 
 
 # ---------------------------------------------------------------------------
-# In-process bounded runs (tests + bench.py actor-pipeline section)
+# In-process bounded runs (tests)
 # ---------------------------------------------------------------------------
 
 
@@ -831,8 +831,8 @@ def bounded_actor_run(opt: Options, ticks: int, spec: EnvSpec = None,
     ticks against a recording sink and a single fixed parameter snapshot.
 
     The harness behind the determinism tests (pipelined/batched streams
-    must be bit-identical to inline, tests/test_actor_pipeline.py) and
-    the bench's actor-pipeline section: no learner, no spawn — the param
+    must be bit-identical to inline, tests/test_actor_pipeline.py): no
+    learner, no spawn — the param
     store is pre-published once from ``init_params(seed=param_seed)``, so
     two runs over the same opt see identical weights.  Returns
     ``{"stream": [(item, priority), ...], "timer_ms": {...},

@@ -6,8 +6,10 @@ PR 7 put the env fleet on the device (envs/device_env.py) and fused
 policy + env physics + n-step assembly into one donated scan
 (models/policies.build_fused_rollout), but the device actor still ran
 as a separate CPU-pinned process shipping finished chunks through the
-spawn queue — ~56 KB per transition of pickle/pipe/H2D work while the
-chip idled (BENCH_r03).  Podracer's Anakin topology (Hessel et al.
+spawn queue — ~56 KB per transition of pickle/pipe/H2D work (two
+uint8 84x84x4 stacks a row) while the chip waits (in the closed loop
+92 % of the chip's idle time lay under ``learner/drain``: my chip run,
+PR 24, PERF.md section 5).  Podracer's Anakin topology (Hessel et al.
 2021) and Ape-X's own act→store→sample→learn cycle (Horgan et al.
 2018) both say the whole loop belongs in one program on one chip.
 This module is that loop:
@@ -95,7 +97,7 @@ class AnakinDriver:
     Owns the train state, the device env fleet, the fused rollout and
     fused learner programs, and the (single or double-buffered) HBM
     ring(s).  ``dispatch_rollout`` / ``dispatch_learn`` are exposed
-    individually so the parity tests and the bench can drive bounded
+    individually so the parity tests can drive bounded
     deterministic schedules; ``run`` is the production duty-cycle loop
     with the learner's usual cadences (publish / checkpoint / stats).
     """
@@ -108,21 +110,16 @@ class AnakinDriver:
 
         from pytorch_distributed_tpu.agents.clocks import ActorStats
         from pytorch_distributed_tpu.factory import (
-            anakin_eligible, build_device_env, build_megabatch_train_step,
-            build_model, build_train_state_and_step, init_params,
-            resolve_megabatch, resolve_steps_per_dispatch,
+            anakin_eligible, build_device_env, build_learner_core,
+            build_learner_dispatch,
         )
         from pytorch_distributed_tpu.memory.device_per import (
             per_write_masked,
         )
-        from pytorch_distributed_tpu.memory.device_replay import (
-            DevicePerIngest, build_uniform_fused_step, sample_rows,
-        )
         from pytorch_distributed_tpu.models.policies import (
             apex_epsilons, build_fused_rollout, init_rollout_carry,
         )
-        from pytorch_distributed_tpu.parallel.learner import ShardedLearner
-        from pytorch_distributed_tpu.parallel.mesh import make_mesh, replicated
+        from pytorch_distributed_tpu.parallel.mesh import replicated
         from pytorch_distributed_tpu.utils import checkpoint as ckpt
         from pytorch_distributed_tpu.utils import perf
         from pytorch_distributed_tpu.utils.metrics import MetricsWriter
@@ -147,31 +144,21 @@ class AnakinDriver:
         self.actor_stats = (actor_stats if actor_stats is not None
                             else ActorStats())
         self.process_ind = process_ind
-        pp = opt.parallel_params
         ap = self.ap
 
-        # ---- model + train state (the learner half, as run_learner) ----
-        mesh = None
-        if len(jax.devices()) > 1:
-            mesh = make_mesh(pp.dp_size, pp.mp_size, pp.sp_size,
-                             pp.ep_size, pp.pp_size)
-        self.mesh = mesh
+        # ---- mesh, model, train state (the learner half: the factory's
+        # one assembly, as run_learner; a model split asked of the dqn
+        # family is refused there) ----
+        core, self.state = build_learner_core(opt, spec)
+        self.mesh = mesh = core.mesh
+        self.model = core.model
+        self._learner = core.learner
         # every small device-resident operand (keys, eps, tick, prov,
         # beta, carry) is placed EXPLICITLY in the mesh's replicated
         # layout at creation — the compiled programs' input shardings —
         # so dispatches stage zero implicit reshards and the transfer
         # audit stays clean under a mesh exactly as on one device
         self._sharding = replicated(mesh) if mesh is not None else None
-        self.model = build_model(opt, spec)
-        params = init_params(opt, spec, self.model, seed=opt.seed)
-        if opt.model_file:
-            path = ckpt.params_path(opt.model_file) \
-                if not opt.model_file.endswith(".msgpack") else opt.model_file
-            params = ckpt.load_params(path, params)
-        state, step_fn = build_train_state_and_step(opt, spec, self.model,
-                                                    params, mesh=mesh)
-        self._learner = ShardedLearner(step_fn, mesh, donate=pp.donate)
-        self.state = self._learner.place(state)
 
         # ---- resume: newest complete epoch's train state + counters.
         # The anakin driver keeps resume SIMPLE — state, clocks and the
@@ -201,11 +188,12 @@ class AnakinDriver:
         self._epoch = epoch
 
         # ---- ring(s): single, or double-buffered halves ----
-        self.is_per = isinstance(memory, DevicePerIngest)
         if self.an.double_buffer:
             self.rings = list(memory.attach_halves(mesh=mesh))
         else:
             self.rings = [memory.attach(mesh=mesh)]
+        # a prioritized ring: rollouts stamp fresh rows at the running max
+        self.is_per = hasattr(self.rings[0].state, "priority")
         self.sample_ix = 0
         self.write_ix = 0
         self._fresh = 0  # rows into the write half since the last swap
@@ -245,47 +233,13 @@ class AnakinDriver:
             jnp.asarray(process_key(opt.seed, "actor", 0)))
         self.tick0 = self._place(jnp.int32(0))
 
-        # ---- the fused learner program (the run_learner device path's
-        # EXACT constructions, so a co-located step is the same XLA
-        # program a split-process learner dispatches — the parity
-        # oracle's ground) ----
-        K = resolve_steps_per_dispatch(opt)
-        # ISSUE-13 megabatching: the SAME factory resolution the
-        # split-process learner uses, so the co-located twin's learner
-        # dispatch is the same XLA program (the parity oracle's ground)
-        M, K_mb = resolve_megabatch(opt, K)
-        mega_step = None
-        if M > 1:
-            mega_step = build_megabatch_train_step(opt, self.model)
-            if mega_step is None:
-                print(f"[anakin] megabatch={M} unsupported for "
-                      f"agent_type={opt.agent_type}; sequential fused "
-                      f"step at steps_per_dispatch={K}", flush=True)
-                M = 1
-            else:
-                # only an ENGAGED megabatch inflates the dispatch
-                # quantum (and K_learn/duty-cycle accounting)
-                K = K_mb
-        mb_kw = (dict(megabatch=M, megabatch_step=mega_step)
-                 if M > 1 else {})
-        self.K_learn = K
+        # ---- the fused learner program: the SAME factory assembly the
+        # split-process learner dispatches (the parity oracle's ground);
+        # built from ring 0, run against either half's state ----
+        self._prog = prog = build_learner_dispatch(core, self.rings[0], opt,
+                                                   role="anakin")
+        self.K_learn = K = prog.K
         self._beta = None
-        if self.is_per:
-            self._fused_per = self.rings[0].build_fused_step(
-                step_fn, ap.batch_size, donate=pp.donate,
-                steps_per_call=K, **mb_kw)
-            self._fused = None
-        else:
-            self._fused_per = None
-            if K > 1:
-                self._fused = build_uniform_fused_step(
-                    step_fn, ap.batch_size, steps_per_call=K,
-                    donate=pp.donate, **mb_kw)
-            else:
-                self._fused = jax.jit(
-                    lambda ts, rs, key: step_fn(
-                        ts, sample_rows(rs, key, ap.batch_size)),
-                    donate_argnums=(0,) if pp.donate else ())
 
         from pytorch_distributed_tpu.agents.learner import announce_startup
 
@@ -313,8 +267,7 @@ class AnakinDriver:
             if _cd is not None:
                 self.perf.set_compute_dtype(jnp.dtype(_cd).name)
             self.perf.register_jit("fused_step",
-                                   getattr(self._fused_per or self._fused,
-                                           "_cache_size", None))
+                                   getattr(prog.fused, "_cache_size", None))
             self.perf.register_jit("anakin_rollout",
                                    self.rollout._cache_size)
             # seed-derived even though these keys only feed .lower()
@@ -326,15 +279,10 @@ class AnakinDriver:
             _pkeys = (_pkeys.reshape(K, *_pkeys.shape[1:]) if K > 1
                       else _pkeys[0])
             rs0 = self.rings[0].state
-            if self.is_per:
-                _pbeta = jax.device_put(
-                    np.float32(self.rings[0].beta(0)))
-                self.perf.capture_flops(
-                    lambda: self._fused_per.lower(self.state, rs0,
-                                                  _pkeys, _pbeta))
-            else:
-                self.perf.capture_flops(
-                    lambda: self._fused.lower(self.state, rs0, _pkeys))
+            _pbeta = ((jax.device_put(np.float32(self.rings[0].beta(0))),)
+                      if prog.takes_beta else ())
+            self.perf.capture_flops(
+                lambda: prog.fused.lower(self.state, rs0, _pkeys, *_pbeta))
             self.perf.capture_frame_flops(
                 lambda: self.rollout.lower(
                     self.state.params, self.carry, rs0, self.base_key,
@@ -526,32 +474,20 @@ class AnakinDriver:
             rest = self._place(keys[1:])  # one bulk placement / 64
             self._key_buf = (list(rest.reshape(64, K, *rest.shape[1:]))
                              if K > 1 else list(rest))
-            if self.is_per:
+            if self._prog.takes_beta:
                 self._beta = self._place(
                     np.float32(self.rings[0].beta(self.lstep)))
         key = self._key_buf.pop()
         t0 = time.perf_counter()
-        if self.is_per:
-            if self.audit is not None:
-                self.state, ring.state, m = self.audit.run(
-                    self._fused_per, self.state, ring.state, key,
-                    self._beta)
-            else:
-                self.state, ring.state, m = self._fused_per(
-                    self.state, ring.state, key, self._beta)
-        elif self.K_learn > 1:
-            if self.audit is not None:
-                self.state, m = self.audit.run(self._fused, self.state,
-                                               ring.state, key)
-            else:
-                self.state, m = self._fused(self.state, ring.state, key)
-        else:
-            if self.audit is not None:
-                self.state, m, _td = self.audit.run(
-                    self._fused, self.state, ring.state, key)
-            else:
-                self.state, m, _td = self._fused(self.state, ring.state,
-                                                 key)
+        prog = self._prog
+        args = (self.state, ring.state, key,
+                *((self._beta,) if prog.takes_beta else ()))
+        out = dict(zip(prog.returns,
+                       self.audit.run(prog.fused, *args)
+                       if self.audit is not None else prog.fused(*args)))
+        self.state, m = out["state"], out["metrics"]
+        if "ring" in out:
+            ring.state = out["ring"]
         if self._block:
             jax.block_until_ready(self.state.params)
         dt = time.perf_counter() - t0

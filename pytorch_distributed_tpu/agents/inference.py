@@ -4,10 +4,10 @@ The inline/pipelined actor loops run rollout inference on each actor
 process's OWN host CPU (utils/helpers.pin_to_cpu — the learner alone owns
 the accelerator).  That is the right call when the accelerator is remote
 or contended, but it leaves the chip idle between learner dispatches and
-burns the actor host's cores on convnet forwards: BENCH_r03 shows the
-flagship e2e topology pacing at ~475 env frames/s with ``time_act_ms``
-(13.45) dwarfing ``time_env_ms`` (0.55) — the actor fleet is inference-
-bound on a CPU while a TPU idles (ISSUE 4 motivation).
+burns the actor host's cores on convnet forwards: an actor's tick is
+mostly batched CNN inference on a CPU while a TPU idles (ISSUE 4
+motivation; the split of a tick on the directly attached chip's host is
+not measured: no benchmark cell runs the actor plane, PERF.md section 7).
 
 ``actor_backend=batched`` flips the topology to the SEED architecture
 (Espeholt et al. 2019; PAPERS.md): actor processes stop holding model
@@ -183,7 +183,7 @@ class InferenceServer:
         # client_id -> device array, or ("host", rows) parked seed
         self._stacks: Dict[int, Any] = {}
         # observability: swept into the learner-side metrics by whoever
-        # owns the server (bench reads them off the object directly)
+        # owns the server (tests read them off the object directly)
         self.stats = {"requests": 0, "batches": 0, "rows": 0,
                       "widest_batch": 0, "param_refreshes": 0}
         # perf plane (utils/perf.py): served-rows counter + retrace

@@ -32,17 +32,11 @@ import numpy as np
 
 from pytorch_distributed_tpu.config import Options
 from pytorch_distributed_tpu.factory import (
-    EnvSpec, build_model, build_train_state_and_step, init_params,
-    published_params,
+    EnvSpec, build_learner_core, build_learner_dispatch, build_model,
+    init_params, published_params,
 )
 from pytorch_distributed_tpu.agents.clocks import GlobalClock, LearnerStats
 from pytorch_distributed_tpu.agents.param_store import ParamStore
-from pytorch_distributed_tpu.memory.device_replay import (
-    DevicePerIngest, DeviceReplayIngest,
-)
-from pytorch_distributed_tpu.memory.device_sequence import (
-    DeviceSequenceIngest,
-)
 from pytorch_distributed_tpu.memory.feeder import QueueOwner
 from pytorch_distributed_tpu.utils import checkpoint as ckpt
 from pytorch_distributed_tpu.utils import (
@@ -139,72 +133,12 @@ def run_learner(opt: Options, spec: EnvSpec, process_ind: int, memory: Any,
     import jax.numpy as jnp
     from jax.flatten_util import ravel_pytree
 
-    from pytorch_distributed_tpu.parallel.learner import ShardedLearner
-    from pytorch_distributed_tpu.parallel.mesh import make_mesh
-
     ap = opt.agent_params
-    pp = opt.parallel_params
 
-    # ---- model + train state (reference dqn_learner.py:21-39) ----
-    # mesh first: sequence-parallel train steps (DTQN ring attention over
-    # the sp axis) are built against it
-    mesh = None
-    if len(jax.devices()) > 1:
-        mesh = make_mesh(pp.dp_size, pp.mp_size, pp.sp_size, pp.ep_size,
-                         pp.pp_size)
-    model = build_model(opt, spec)
-    params = init_params(opt, spec, model, seed=opt.seed)
-    if opt.model_file:
-        # finetune-from-file (reference main.py:45)
-        path = ckpt.params_path(opt.model_file) \
-            if not opt.model_file.endswith(".msgpack") else opt.model_file
-        params = ckpt.load_params(path, params)
-    state, step_fn = build_train_state_and_step(opt, spec, model, params,
-                                                mesh=mesh)
-    state_shardings = None
-    if mesh is not None and pp.mp_size > 1:
-        # the one family wide enough for tensor parallelism: Megatron-split
-        # DTQN FFN over mp (parallel/tensor_parallel.py)
-        # exact match: the moe/pipe families have no _Block_ param paths,
-        # so dtqn_state_shardings would silently no-op on them (their
-        # splits are ep and pp respectively)
-        assert opt.model_type == "dtqn-mlp", (
-            f"mp_size>1 is only supported for dtqn-mlp "
-            f"(got {opt.model_type})")
-        from pytorch_distributed_tpu.parallel.tensor_parallel import (
-            dtqn_state_shardings,
-        )
-
-        state_shardings = dtqn_state_shardings(state, mesh)
-    if mesh is not None and pp.ep_size > 1:
-        # expert parallelism: MoE expert kernels split over ep
-        # (parallel/expert_parallel.py); mutually exclusive with the mp
-        # split — the DTQN families are either dense (mp) or MoE (ep)
-        assert opt.model_type == "dtqn-moe", (
-            f"ep_size>1 is only supported for dtqn-moe "
-            f"(got {opt.model_type})")
-        assert pp.mp_size == 1, "ep and mp splits don't compose"
-        from pytorch_distributed_tpu.parallel.expert_parallel import (
-            moe_state_shardings,
-        )
-
-        state_shardings = moe_state_shardings(state, mesh)
-    if mesh is not None and pp.pp_size > 1:
-        # pipeline parallelism: stacked block layer axis over pp
-        # (parallel/pipeline.py); exclusive with the other model splits
-        assert opt.model_type == "dtqn-pipe", (
-            f"pp_size>1 is only supported for dtqn-pipe "
-            f"(got {opt.model_type})")
-        assert pp.mp_size == 1 and pp.ep_size == 1, (
-            "pp does not compose with mp/ep splits")
-        from pytorch_distributed_tpu.parallel.pipeline import (
-            pipeline_state_shardings,
-        )
-
-        state_shardings = pipeline_state_shardings(state, mesh)
-    learner = ShardedLearner(step_fn, mesh, donate=pp.donate,
-                             state_shardings=state_shardings)
-    state = learner.place(state)
+    # ---- mesh, model, train state, placement (reference
+    # dqn_learner.py:21-39): the factory's one assembly ----
+    core, state = build_learner_core(opt, spec)
+    mesh, model, learner = core.mesh, core.model, core.learner
 
     # ---- resume: newest complete checkpoint epoch, else the legacy
     # single snapshot (utils/checkpoint.py docstring).  Epoch extras
@@ -309,13 +243,10 @@ def run_learner(opt: Options, spec: EnvSpec, process_ind: int, memory: Any,
         _publish_async = _publish
 
     is_per = isinstance(memory, QueueOwner)
-    # the HBM segment ring presents the same fused-priority surface as the
-    # HBM PER ring (attach / build_fused_step / beta / drain), so the
-    # learner drives both through one path (memory/device_sequence.py)
-    is_device_per = isinstance(memory, (DevicePerIngest,
-                                        DeviceSequenceIngest))
-    is_device = isinstance(memory, DeviceReplayIngest) and not is_device_per
-    on_device = is_device or is_device_per
+    # the three HBM rings (uniform, PER, segments) present one surface:
+    # attach / drain; which fused program and which call signature they
+    # get is the factory's to say (build_learner_dispatch)
+    on_device = hasattr(memory, "attach")
     # perf plane monitor (utils/perf.py, TPU_APEX_PERF=1): created for
     # every memory path — rates/watermarks/gauges work everywhere; the
     # FLOPs capture below is device-path only (the host path's step
@@ -350,70 +281,18 @@ def run_learner(opt: Options, spec: EnvSpec, process_ind: int, memory: Any,
         # (memory/device_replay.py build_uniform_fused_step docstring).
         replay = memory.attach(mesh=mesh)
         beta_dev = None
-        from pytorch_distributed_tpu.factory import (
-            resolve_steps_per_dispatch,
-        )
+        prog = build_learner_dispatch(core, replay, opt, role="learner")
+        K, fused = prog.K, prog.fused
 
-        K = resolve_steps_per_dispatch(opt)
-        # ISSUE-13 megabatching: group the K scanned updates into K/M
-        # widened-gather groups (one lane-filling batched backward per
-        # group); the group step comes from the factory so the
-        # sequential and megabatch paths share torso/optimizer gates
-        from pytorch_distributed_tpu.factory import (
-            build_megabatch_train_step, resolve_megabatch,
-        )
-
-        M, K_mb = resolve_megabatch(opt, K)
-        mega_step = None
-        if M > 1:
-            mega_step = build_megabatch_train_step(opt, model)
-            if mega_step is None:
-                print(f"[learner] megabatch={M} is not supported for "
-                      f"agent_type={opt.agent_type} (dqn/decoupled-ddpg "
-                      f"only); running the sequential fused step at "
-                      f"steps_per_dispatch={K}", flush=True)
-                M = 1
-            else:
-                # only an ENGAGED megabatch inflates the dispatch
-                # quantum — a downgrade keeps the configured K
-                K = K_mb
-        mb_kw = (dict(megabatch=M, megabatch_step=mega_step)
-                 if M > 1 else {})
-        if is_device_per:
-            fused_per = replay.build_fused_step(step_fn, ap.batch_size,
-                                                donate=pp.donate,
-                                                steps_per_call=K,
-                                                **mb_kw)
-
-            def device_step(keys):
-                nonlocal state
-                state, replay.state, m = fused_per(state, replay.state,
-                                                   keys, beta_dev)
-                return m
-        else:
-            from pytorch_distributed_tpu.memory.device_replay import (
-                build_uniform_fused_step, sample_rows,
-            )
-
-            if K > 1:
-                fused = build_uniform_fused_step(
-                    step_fn, ap.batch_size, steps_per_call=K,
-                    donate=pp.donate, **mb_kw)
-
-                def device_step(keys):
-                    nonlocal state
-                    state, m = fused(state, replay.state, keys)
-                    return m
-            else:
-                fused = jax.jit(
-                    lambda ts, rs, key: step_fn(
-                        ts, sample_rows(rs, key, ap.batch_size)),
-                    donate_argnums=(0,) if pp.donate else ())
-
-                def device_step(key):
-                    nonlocal state
-                    state, m, _td = fused(state, replay.state, key)
-                    return m
+        def device_step(keys):
+            nonlocal state
+            out = dict(zip(prog.returns, fused(
+                state, replay.state, keys,
+                *((beta_dev,) if prog.takes_beta else ()))))
+            state = out["state"]
+            if "ring" in out:
+                replay.state = out["ring"]
+            return out["metrics"]
 
         # Capture the fused program's per-update FLOPs off its cost
         # analysis ONCE at startup — the same executable the loop
@@ -423,9 +302,8 @@ def run_learner(opt: Options, spec: EnvSpec, process_ind: int, memory: Any,
         # retrace detector: this program must never recompile after
         # warmup.
         if perf_mon.enabled:
-            _pf = fused_per if is_device_per else fused
             perf_mon.register_jit("fused_step",
-                                  getattr(_pf, "_cache_size", None))
+                                  getattr(fused, "_cache_size", None))
             # seed-derived even though these keys only feed .lower()
             # for the FLOP capture (apexlint rng-key-reuse: no literal-
             # seed streams outside utils.rngs)
@@ -435,14 +313,10 @@ def run_learner(opt: Options, spec: EnvSpec, process_ind: int, memory: Any,
                 K + 1)[1:]
             _pkeys = (_pkeys.reshape(K, *_pkeys.shape[1:]) if K > 1
                       else _pkeys[0])
-            if is_device_per:
-                _pbeta = jax.device_put(np.float32(replay.beta(0)))
-                perf_mon.capture_flops(
-                    lambda: fused_per.lower(state, replay.state, _pkeys,
-                                            _pbeta))
-            else:
-                perf_mon.capture_flops(
-                    lambda: fused.lower(state, replay.state, _pkeys))
+            _pbeta = ((jax.device_put(np.float32(replay.beta(0))),)
+                      if prog.takes_beta else ())
+            perf_mon.capture_flops(
+                lambda: fused.lower(state, replay.state, _pkeys, *_pbeta))
         if perf_mon.audit is not None:
             # transfer audit (opt-in): the fused dispatch is transfer-
             # free by construction — state, ring and keys are all
@@ -768,7 +642,7 @@ def run_learner(opt: Options, spec: EnvSpec, process_ind: int, memory: Any,
                     # group into 64 dispatches of K either way
                     key_buf = (list(rest.reshape(64, K, *rest.shape[1:]))
                                if K > 1 else list(rest))
-                    if is_device_per:
+                    if prog.takes_beta:
                         beta_dev = jax.device_put(
                             np.float32(replay.beta(lstep)))
             t_enqueue = time.perf_counter()

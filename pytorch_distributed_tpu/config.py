@@ -32,7 +32,7 @@ from typing import Any, Optional, Tuple
 #   [agent_type, env_type, game, memory_type, model_type]
 # Rows 0 is the reference's only row (dqn/atari/pong/shared/dqn-cnn).  The
 # extra rows cover the driver BASELINE.json tracked configs plus self-
-# contained debug/bench envs that need no ALE install.  Rows 12, 14 and 20
+# contained debug envs that need no ALE install.  Rows 12, 14 and 20
 # are the benchmark's configurations (benchmark/configs/); rows 15, 17 and
 # 18 are toy-sized families on the host sequence replay.
 # ---------------------------------------------------------------------------
@@ -250,13 +250,6 @@ class MemoryParams:
     # resume leg the reference lacks, SURVEY.md §5).  Off by default:
     # image replays serialize to large files; written once at run end.
     checkpoint_replay: bool = False
-    # NHWC (channels-last) storage for HBM device rings — a per-hardware
-    # A/B knob (--set device_channels_last=true), NOT a tuning default:
-    # measured ~13% SLOWER on the TPU v5 lite (XLA pads the 4-wide minor
-    # channel axis to the 128 vector lanes) but kept live for hardware
-    # where the trade flips (factory.device_ring_channels_last docstring
-    # has the measurement).
-    device_channels_last: bool = False
     # NOTE: device-resident (HBM) replay is selected via
     # ``memory_type="device"`` (CONFIGS row 8), not a flag here: the buffer
     # is sharded across the learner mesh's dp axis and sampled on device
@@ -606,7 +599,7 @@ class FlowParams:
     The plane is ON by default but INERT until the gateway's pressure
     signal crosses ``throttle_at``: in the healthy state no credits
     ride the wire, no chunk is ever shed, and the per-chunk cost is a
-    few dict/float ops (bench.py ``flow_overhead``)."""
+    few dict/float ops."""
 
     # Master switch.  Off = the pre-ISSUE-11 behaviour everywhere: no
     # credits, no admission control, blocking local feeders.
@@ -660,8 +653,7 @@ class BandwidthParams:
     flow/perf/metrics planes use.
 
     ON by default, counter-only hot path: one dict lookup + two
-    integer adds per frame (bench.py ``wire_overhead`` gates it under
-    the 0.02 absolute overhead band)."""
+    integer adds per frame."""
 
     # Master switch.  Off = no counters, no wire/* series, no byte
     # legs in the flow conservation ledger.
@@ -885,8 +877,9 @@ class LearnerPerfParams:
     # Pallas fused conv-stack/Q-head torso for dqn-cnn
     # (ops/pallas_torso.py): the learner's train apply runs the torso
     # as hand-tiled 128-lane MXU matmul kernels (im2col) instead of
-    # XLA's conv lowering, bypassing the ~25% of device time
-    # mfu_probe.py attributes to XLA re-tiling.  Loud downgrade to the
+    # XLA's conv lowering and its re-tiling between conv ops (what that
+    # buys on the chip is not measured: no benchmark cell turns it on).
+    # Loud downgrade to the
     # XLA apply when Pallas/TPU is unavailable (unless
     # ``pallas_interpret``).  Actors/evaluators keep the standard
     # apply — the param tree is identical.

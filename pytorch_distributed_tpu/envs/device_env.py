@@ -5,8 +5,8 @@ The actor plane's throughput ceiling so far has been the HOST env step:
 (and ``native_pong.py`` one C call at a time), so every tick pays N
 Python frames of work and the policy's device dispatch round-trips the
 obs through host memory, while the chip idles waiting for experience
-(BENCH_r03; how far behind the host plane runs on a directly attached
-chip is not measured).  Podracer (Hessel et al. 2021) names the
+(how far behind the host plane runs on a directly attached chip is not
+measured).  Podracer (Hessel et al. 2021) names the
 fix: put the environments ON the device as pure functions and advance
 thousands of them per XLA dispatch, fused with the policy step (the
 Sebulba/Anakin actor plane).

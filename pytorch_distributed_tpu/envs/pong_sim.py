@@ -4,7 +4,7 @@ Why this exists: the BASELINE north star is DQN on Pong, but ALE
 (atari_py/ale_py) is not installed in this image.  This env reimplements the
 *game* of Pong (ball, two paddles, scoring to 21) as a small numpy
 simulation and runs it through exactly the preprocessing contract of the
-reference Atari path so models/replay/bench exercise identical shapes and
+reference Atari path so models/replay/benchmark exercise identical shapes and
 dtypes: 84x84 grayscale uint8 frames, action-repeat 4 with a max-pool over
 the last two raw frames, 4-frame history stack, norm_val 255
 (reference core/envs/atari_env.py:53-61, 89-104).

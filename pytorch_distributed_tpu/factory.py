@@ -13,7 +13,7 @@ train-step closures — not stateful torch modules.
 
 from __future__ import annotations
 
-from dataclasses import dataclass
+from dataclasses import dataclass, replace
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import numpy as np
@@ -107,10 +107,8 @@ ACTOR_BACKENDS = ("inline", "pipelined", "batched", "device", "anakin")
 
 def anakin_eligible(opt: Options) -> Tuple[bool, str]:
     """Whether this Options can run the co-located Anakin loop (ISSUE
-    12): the dqn family, a pure-JAX env implementation, a device replay
-    ring for the in-graph scatter, and NCHW ring storage (the fused
-    rollout scatters raw rows; the NHWC ingest transpose lives on the
-    host feed path it bypasses).  Returns ``(ok, reason)`` so callers
+    12): the dqn family, a pure-JAX env implementation and a device replay
+    ring for the in-graph scatter.  Returns ``(ok, reason)`` so callers
     can warn with the actual blocker."""
     from pytorch_distributed_tpu.envs.device_env import (
         device_env_supported,
@@ -125,9 +123,6 @@ def anakin_eligible(opt: Options) -> Tuple[bool, str]:
         return False, (f"memory_type={opt.memory_type!r} (the fused "
                        f"rollout scatters into a device ring: use "
                        f"'device' or 'device-per')")
-    if device_ring_channels_last(opt):
-        return False, ("device_channels_last=true (the in-graph scatter "
-                       "writes NCHW rows)")
     return True, ""
 
 
@@ -740,20 +735,15 @@ def select_torso(opt: Options) -> str:
 
 def _dqn_train_apply(opt: Options, model):
     """The learner-side apply for the dqn family: the model's own apply,
-    re-based for NHWC ring storage when that knob is live, and swapped
-    for the Pallas fused torso (ops/pallas_torso.py) when
+    swapped for the Pallas fused torso (ops/pallas_torso.py) when
     ``select_torso`` says so.  Decided HERE — one gate shared by the
     sequential step and the megabatch step — so the two programs can
     never train through different torsos.  Actors and evaluators never
     route through this: the param tree is identical, so they keep the
     standard apply."""
-    nhwc = device_ring_channels_last(opt)
     torso = select_torso(opt)
     if torso == "xla":
-        # the HBM ring may store rows NHWC (same param tree, transpose
-        # moved from 3x per update to once per ingest — see
-        # memory/device_replay.py chunk_to_nhwc)
-        return model.clone(nhwc_input=True).apply if nhwc else model.apply
+        return model.apply
     from pytorch_distributed_tpu.ops.pallas_torso import (
         build_pallas_torso_apply,
     )
@@ -762,7 +752,6 @@ def _dqn_train_apply(opt: Options, model):
     return build_pallas_torso_apply(
         norm_val=model.norm_val,
         compute_dtype=jnp.dtype(opt.model_params.compute_dtype),
-        nhwc_input=nhwc,
         interpret=torso == "pallas-interpret")
 
 
@@ -854,6 +843,153 @@ def resolve_megabatch(opt: Options, steps_per_call: int
     return M, K
 
 
+@dataclass(frozen=True)
+class LearnerProgram:
+    """The learner's device program for one ``Options``: what
+    ``build_learner_core`` assembles, and, once a ring is attached, the
+    ONE fused dispatch ``build_learner_dispatch`` adds.  The split
+    learner (agents/learner.py) and the Anakin loop (agents/anakin.py)
+    both hold this and nothing of their own, so a config can not train
+    through two different programs.  The TrainState is not kept here:
+    callers donate it on every dispatch and replace it on resume."""
+
+    mesh: Any                   # None on one device
+    model: Any
+    step_fn: Callable           # (TrainState, Batch) -> (TrainState, m, td)
+    learner: Any                # ShardedLearner(step_fn, mesh): place, step
+    # -- set by build_learner_dispatch --
+    K: int = 1                  # updates one call of ``fused`` applies
+    fused: Optional[Callable] = None  # jitted; keys (K, ..) or one key at K=1
+    takes_beta: bool = False    # fused(state, ring, keys[, beta])
+    returns: Tuple[str, ...] = ()  # fused's outputs in order, of
+    #                                "state", "ring", "metrics", "td"
+
+
+def build_learner_core(opt: Options, spec: EnvSpec):
+    """``(LearnerProgram, placed TrainState)`` for ``opt``: the mesh (any
+    time more than one device is visible), the model, seeded params with
+    ``opt.model_file`` loaded over them (finetune-from-file, reference
+    main.py:45) BEFORE the optimizer state and target copy are made of
+    them, the train step, and the one model split the mesh asks for
+    (mp: Megatron FFN, ep: MoE experts, pp: stacked blocks), each refused
+    for any family but its own."""
+    import jax
+
+    from pytorch_distributed_tpu.parallel.learner import ShardedLearner
+    from pytorch_distributed_tpu.parallel.mesh import make_mesh
+    from pytorch_distributed_tpu.utils import checkpoint as ckpt
+
+    pp = opt.parallel_params
+    # mesh first: sequence-parallel train steps (DTQN ring attention over
+    # the sp axis) are built against it
+    mesh = None
+    if len(jax.devices()) > 1:
+        mesh = make_mesh(pp.dp_size, pp.mp_size, pp.sp_size, pp.ep_size,
+                         pp.pp_size)
+    model = build_model(opt, spec)
+    params = init_params(opt, spec, model, seed=opt.seed)
+    if opt.model_file:
+        path = opt.model_file if opt.model_file.endswith(".msgpack") \
+            else ckpt.params_path(opt.model_file)
+        params = ckpt.load_params(path, params)
+    state, step_fn = build_train_state_and_step(opt, spec, model, params,
+                                                mesh=mesh)
+    state_shardings = None
+    if mesh is not None and pp.mp_size > 1:
+        # exact match: the moe/pipe families have no _Block_ param paths,
+        # so dtqn_state_shardings would silently no-op on them (their
+        # splits are ep and pp respectively)
+        assert opt.model_type == "dtqn-mlp", (
+            f"mp_size>1 is only supported for dtqn-mlp "
+            f"(got {opt.model_type})")
+        from pytorch_distributed_tpu.parallel.tensor_parallel import (
+            dtqn_state_shardings,
+        )
+
+        state_shardings = dtqn_state_shardings(state, mesh)
+    if mesh is not None and pp.ep_size > 1:
+        # the DTQN families are either dense (mp) or MoE (ep)
+        assert opt.model_type == "dtqn-moe", (
+            f"ep_size>1 is only supported for dtqn-moe "
+            f"(got {opt.model_type})")
+        assert pp.mp_size == 1, "ep and mp splits don't compose"
+        from pytorch_distributed_tpu.parallel.expert_parallel import (
+            moe_state_shardings,
+        )
+
+        state_shardings = moe_state_shardings(state, mesh)
+    if mesh is not None and pp.pp_size > 1:
+        assert opt.model_type == "dtqn-pipe", (
+            f"pp_size>1 is only supported for dtqn-pipe "
+            f"(got {opt.model_type})")
+        assert pp.mp_size == 1 and pp.ep_size == 1, (
+            "pp does not compose with mp/ep splits")
+        from pytorch_distributed_tpu.parallel.pipeline import (
+            pipeline_state_shardings,
+        )
+
+        state_shardings = pipeline_state_shardings(state, mesh)
+    learner = ShardedLearner(step_fn, mesh, donate=pp.donate,
+                             state_shardings=state_shardings)
+    return (LearnerProgram(mesh=mesh, model=model, step_fn=step_fn,
+                           learner=learner),
+            learner.place(state))
+
+
+def build_learner_dispatch(core: LearnerProgram, replay, opt: Options,
+                           role: str = "learner") -> LearnerProgram:
+    """``core`` with the ONE fused program for the attached HBM ring
+    ``replay``: sampling (and on the prioritized rings the |TD|
+    write-back) fused into the train step, ``K`` scanned updates a call
+    (``resolve_steps_per_dispatch``), regrouped by ``megabatch`` where the
+    family has a group step and said LOUDLY (``[role] ...``) where it has
+    none.  The record says how to call what it built: the PER and segment
+    rings take ``beta`` and hand their ring state back, the uniform ring
+    is read-only in the program, and its K = 1 form also returns |TD|."""
+    import jax
+
+    ap, pp = opt.agent_params, opt.parallel_params
+    K = resolve_steps_per_dispatch(opt)
+    M, K_mb = resolve_megabatch(opt, K)
+    mb_kw = {}
+    if M > 1:
+        mega_step = build_megabatch_train_step(opt, core.model)
+        if mega_step is None:
+            print(f"[{role}] megabatch={M} is not supported for "
+                  f"agent_type={opt.agent_type} (dqn/decoupled-ddpg "
+                  f"only); running the sequential fused step at "
+                  f"steps_per_dispatch={K}", flush=True)
+        else:
+            # only an ENGAGED megabatch inflates the dispatch quantum: a
+            # downgrade keeps the configured K
+            K = K_mb
+            mb_kw = dict(megabatch=M, megabatch_step=mega_step)
+    if hasattr(replay, "build_fused_step"):
+        fused = replay.build_fused_step(core.step_fn, ap.batch_size,
+                                        donate=pp.donate, steps_per_call=K,
+                                        **mb_kw)
+        takes_beta, returns = True, ("state", "ring", "metrics")
+    elif K > 1:
+        from pytorch_distributed_tpu.memory.device_replay import (
+            build_uniform_fused_step,
+        )
+
+        fused = build_uniform_fused_step(core.step_fn, ap.batch_size,
+                                         steps_per_call=K, donate=pp.donate,
+                                         **mb_kw)
+        takes_beta, returns = False, ("state", "metrics")
+    else:
+        from pytorch_distributed_tpu.memory.device_replay import sample_rows
+
+        step_fn, B = core.step_fn, ap.batch_size
+        fused = jax.jit(
+            lambda ts, rs, key: step_fn(ts, sample_rows(rs, key, B)),
+            donate_argnums=(0,) if pp.donate else ())
+        takes_beta, returns = False, ("state", "metrics", "td")
+    return replace(core, K=K, fused=fused, takes_beta=takes_beta,
+                   returns=returns)
+
+
 def build_replica_grad_apply(opt: Options, model):
     """The ISSUE-15 replica-plane twin of ``build_train_state_and_step``:
     the dqn update factored at the gradient boundary
@@ -922,30 +1058,6 @@ class MemoryHandles:
 
     actor_side: Any
     learner_side: Any
-
-
-def device_ring_channels_last(opt: Options) -> bool:
-    """Whether the HBM ring stores image rows channels-last (NHWC).
-
-    Decided here so build_memory (ring geometry, parent process) and
-    build_train_state_and_step (the NHWC train apply, learner process)
-    always agree.  Default OFF from measurement, not oversight: the XLA
-    profile showed ~25% of fused-step device time in layout copies, but
-    an interleaved A/B on the TPU v5 lite (2026-07-31,
-    tools/mfu_probe.py machinery) measured the channels-last ring ~13%
-    SLOWER (2078 -> 1807 updates/s) — TPU tiled layouts pad the minor
-    dimension to the 128 vector lanes, so (..., 84, 4) rows pad the
-    4-wide channel axis brutally while the NCHW profile's copies are
-    XLA's own (cheaper) preferred re-tilings.  The mechanism stays live
-    behind ``--set device_channels_last=true`` (DeviceReplay
-    channels_last + DqnCnnModel nhwc_input, layout-equivalence-tested)
-    so a per-hardware A/B never needs a source edit — and this predicate
-    carries ALL the eligibility conditions (fused device ring + the CNN
-    model that owns an nhwc_input switch), so host-replay configs and
-    MLP models can never see the NHWC apply regardless of the flag."""
-    eligible = (opt.memory_type in ("device", "device-per")
-                and opt.model_type == "dqn-cnn")
-    return eligible and opt.memory_params.device_channels_last
 
 
 def build_memory(opt: Options, spec: EnvSpec) -> MemoryHandles:
@@ -1068,7 +1180,6 @@ def build_memory(opt: Options, spec: EnvSpec) -> MemoryHandles:
             action_shape=spec.action_shape,
             state_dtype=state_dtype,
             action_dtype=spec.action_dtype,
-            channels_last=device_ring_channels_last(opt),
         )
         if opt.memory_type == "device-per":
             ingest = DevicePerIngest(

@@ -208,15 +208,13 @@ class DevicePerReplay(DeviceReplay):
                  priority_exponent: float = 0.6,
                  importance_weight: float = 0.4,
                  importance_anneal_steps: int = 500000,
-                 mesh: Optional[jax.sharding.Mesh] = None,
-                 channels_last: bool = False):
+                 mesh: Optional[jax.sharding.Mesh] = None):
         self.alpha = priority_exponent
         self.beta0 = importance_weight
         self.beta_steps = importance_anneal_steps
         super().__init__(round_capacity(capacity, mesh, label="device PER"),
                          state_shape, action_shape, state_dtype,
-                         action_dtype, mesh=mesh,
-                         channels_last=channels_last)
+                         action_dtype, mesh=mesh)
 
         # Pallas hierarchical sampler on unsharded TPU rings; the flat XLA
         # scheme everywhere else (dp-sharded rings address rows through
@@ -237,8 +235,7 @@ class DevicePerReplay(DeviceReplay):
             self.sampler = "pallas"
 
         self._feed_fn = jit_feed(
-            functools.partial(per_feed, capacity=self.capacity),
-            self.channels_last)
+            functools.partial(per_feed, capacity=self.capacity))
         self._sample_fn = jax.jit(
             functools.partial(per_sample, sample_fn=self._draw_fn),
             static_argnames="batch_size")

@@ -229,27 +229,12 @@ def ring_write_masked(state, chunk: Transition, valid,
     return state._replace(**repl), total
 
 
-def chunk_to_nhwc(chunk: Transition) -> Transition:
-    """Transpose a chunk's (N, C, H, W) states to (N, H, W, C) — runs
-    inside the jitted feed, so a channels-last ring pays the transpose
-    ONCE per ingested row instead of every time the row is sampled (each
-    row is trained on ~replay_ratio times).  What that buys on the chip
-    is not measured."""
-    t = lambda x: jnp.transpose(x, (0, 2, 3, 1))
-    with jax.named_scope(PHASE_FEED):
-        return chunk._replace(state0=t(chunk.state0),
-                              state1=t(chunk.state1))
-
-
-def jit_feed(feed_fn, channels_last: bool = False):
+def jit_feed(feed_fn):
     """The jitted, donating feed program of a ring, under a name a trace
     can show (``jit_feed_chunk``; a bare ``functools.partial`` compiles to
-    ``jit__unknown``).  Single point wrapping a channels-last ring's feed
-    with the ingest transpose — DeviceReplay and DevicePerReplay share it
-    so the layout contract lives in one place."""
+    ``jit__unknown``)."""
     def feed_chunk(state, chunk):
-        return feed_fn(state, chunk_to_nhwc(chunk) if channels_last
-                       else chunk)
+        return feed_fn(state, chunk)
 
     return jax.jit(feed_chunk, donate_argnums=0)
 
@@ -391,7 +376,7 @@ class DeviceReplay:
                  action_shape: Tuple[int, ...] = (),
                  state_dtype=np.uint8, action_dtype=np.int32,
                  mesh: Optional[jax.sharding.Mesh] = None,
-                 axis: str = "dp", channels_last: bool = False):
+                 axis: str = "dp"):
         self.capacity = capacity
         self.state_shape = tuple(state_shape)
         self.action_shape = tuple(action_shape)
@@ -399,14 +384,7 @@ class DeviceReplay:
         self.action_dtype = jnp.dtype(action_dtype)
         self.mesh = mesh
         self.axis = axis
-        # channels-last storage: rows live as (H, W, C) so the fused
-        # sampler hands the CNN NHWC batches directly (model nhwc_input);
-        # feeds transpose on device at ingest (chunk_to_nhwc), snapshots
-        # roll back to the public NCHW schema
-        self.channels_last = bool(channels_last and len(state_shape) == 3)
-        self.codec = RowCodec(
-            tuple(state_shape[1:]) + (state_shape[0],)
-            if self.channels_last else tuple(state_shape), self.state_dtype)
+        self.codec = RowCodec(tuple(state_shape), self.state_dtype)
 
         if mesh is not None:
             ndev = mesh.shape[axis]
@@ -421,8 +399,7 @@ class DeviceReplay:
             self._scalar_sharding = None
 
         self.state = self._init_state()
-        self._feed_fn = jit_feed(
-            functools.partial(_feed, capacity=capacity), self.channels_last)
+        self._feed_fn = jit_feed(functools.partial(_feed, capacity=capacity))
         self._sample_fn = jax.jit(
             sample_rows, static_argnames="batch_size", donate_argnums=())
 
@@ -468,8 +445,7 @@ class DeviceReplay:
     def snapshot(self) -> dict:
         """Pull the valid HBM rows to host in AGE order (when full, the
         cursor points at the oldest row; before that, [0, fill) is already
-        oldest-first).  The stored words are unpacked and channels-last
-        rings rolled back to the public NCHW schema, so checkpoints are
+        oldest-first).  The stored words are unpacked, so checkpoints are
         independent of the ring's format."""
         return self._aged_columns(jax.device_get(self.state),
                                   REPLAY_FIELDS + ("prov",))
@@ -483,9 +459,6 @@ class DeviceReplay:
                           axis=0)[:fill].copy() for k in names}
         for k in ("state0", "state1"):
             out[k] = self.codec.unpack(out[k])
-            if self.channels_last:  # the public schema is NCHW
-                out[k] = np.ascontiguousarray(
-                    np.transpose(out[k], (0, 3, 1, 2)))
         out["prov"] = out["prov"].astype(np.int64)
         return out
 
@@ -543,8 +516,7 @@ class DeviceReplayIngest:
     def __init__(self, capacity: int, state_shape: Tuple[int, ...],
                  action_shape: Tuple[int, ...] = (),
                  state_dtype=np.uint8, action_dtype=np.int32,
-                 chunk_size: int = 64, max_queue_chunks: int = 4096,
-                 channels_last: bool = False):
+                 chunk_size: int = 64, max_queue_chunks: int = 4096):
         import multiprocessing as mp
 
         self.capacity = capacity
@@ -553,7 +525,6 @@ class DeviceReplayIngest:
         self.state_dtype = np.dtype(state_dtype)
         self.action_dtype = np.dtype(action_dtype)
         self.chunk_size = chunk_size
-        self.channels_last = channels_last
         # Ingest sizes, largest-first: a deep backlog moves in few large
         # transfers (one jit trace per size) instead of many chunk_size
         # ones — host->device transfer count, not bytes, is what stalls a
@@ -602,8 +573,7 @@ class DeviceReplayIngest:
         never diverge on geometry."""
         return DeviceReplay(
             capacity, self.state_shape, self.action_shape,
-            self.state_dtype, self.action_dtype, mesh=mesh,
-            channels_last=self.channels_last)
+            self.state_dtype, self.action_dtype, mesh=mesh)
 
     def attach(self, mesh: Optional[jax.sharding.Mesh] = None
                ) -> DeviceReplay:
@@ -772,4 +742,4 @@ class DevicePerIngest(DeviceReplayIngest):
             priority_exponent=self.priority_exponent,
             importance_weight=self.importance_weight,
             importance_anneal_steps=self.importance_anneal_steps,
-            mesh=mesh, channels_last=self.channels_last)
+            mesh=mesh)

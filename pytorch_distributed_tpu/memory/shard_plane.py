@@ -244,7 +244,7 @@ class LocalShard:
     state and ledger legs the fault plane needs.  Server-side handler
     for the shard verbs (the gateway dispatches ``handle_ssample`` /
     ``handle_sprio`` to whatever ``shards=`` object it holds) AND the
-    in-process shard of a loopback plane (tests, bench, the co-located
+    in-process shard of a loopback plane (tests, the co-located
     shard-0 of a production learner host)."""
 
     # single-owner declaration (apexlint single-owner rule): the shard's
@@ -745,7 +745,7 @@ class ShardRegistry:
 # ---------------------------------------------------------------------------
 
 class LoopbackShardChannel:
-    """In-process channel to a LocalShard — the tier-1/bench path and
+    """In-process channel to a LocalShard — the tier-1 path and
     the co-located shard of a learner host.  Every answered call renews
     the shard's lease through ``registry.touch`` (served traffic is
     proof of life, the wire analog of renew-on-ack), so a drill that
@@ -1276,7 +1276,7 @@ def build_loopback_plane(params=None, capacity: int = 1024,
                          importance_anneal_steps: int = 500000,
                          shard_ids: Optional[List[int]] = None,
                          writer=None):
-    """N in-process shards + registry + plane — the tier-1/bench/
+    """N in-process shards + registry + plane — the tier-1/
     co-located topology (and the substrate the wire drill's shard hosts
     reuse one shard at a time).  ``capacity`` is the GLOBAL transition
     budget, split evenly across the expected shard count; at shards=1

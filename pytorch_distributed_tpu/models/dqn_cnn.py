@@ -30,22 +30,13 @@ class DqnCnnModel(nn.Module):
     norm_val: float = 255.0
     orthogonal_init: bool = True
     compute_dtype: jnp.dtype = jnp.bfloat16
-    # True = inputs arrive already channels-last (B, H, W, C) and the
-    # transpose is skipped.  The learner's fused HBM path stores replay
-    # rows NHWC (memory/device_replay.py channels_last) because the
-    # per-update NCHW->NHWC copies were ~25% of device time in the XLA
-    # profile (tools/mfu_probe.py, 2026-07-31); the param tree is
-    # identical either way, so actors/evaluators keep publishing and
-    # consuming the same weights with NCHW inputs.
-    nhwc_input: bool = False
 
     @nn.compact
     def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
         # x: (B, C, H, W) uint8/float -> NHWC compute in bf16
         x = x.astype(self.compute_dtype) / jnp.asarray(
             self.norm_val, dtype=self.compute_dtype)
-        if not self.nhwc_input:
-            x = jnp.transpose(x, (0, 2, 3, 1))
+        x = jnp.transpose(x, (0, 2, 3, 1))
         kw = {}
         if self.orthogonal_init:
             # sqrt(2) gain for ReLU trunk, 1.0 for the linear head — the

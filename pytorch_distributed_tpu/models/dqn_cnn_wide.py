@@ -2,15 +2,16 @@
 
 The Nature CNN (models/dqn_cnn.py) structurally underfills a 128-lane
 MXU: its 4/32/64-wide conv channels leave most lanes idle regardless of
-batch size or dtype (tools/mfu_probe.py lever sweep, BENCH_r03
-``mfu_bound``).  This family is the third front of the MFU campaign: an
+batch size or dtype (``mfu`` 13.7 % in ``apex_pong.learner_only``:
+ledger, PR 27).  This family is the third front of the MFU campaign: an
 IMPALA-style residual stack (Espeholt et al. 2018) whose channel widths
 are MULTIPLES OF 128 — sections (width, 2*width, 2*width) with
 ``width`` defaulting to 128 (ModelParams.cnn_wide_width) — so every
 conv GEMM's contraction and output lanes land on the MXU grid exactly.
-~50x the Nature torso's FLOPs per forward, spent at high utilization
-instead of idling lanes: on a dispatch-rich TPU the chip, not the
-program structure, becomes the bottleneck (the Podracer recipe).
+~50x the Nature torso's FLOPs per forward, meant to be spent at high
+utilization instead of idling lanes (the Podracer recipe); its rate and
+utilization on the chip are not measured: no benchmark cell runs CONFIGS
+row 19 (ROADMAP queue 3).
 
 Same external contract as DqnCnnModel — (B, C, H, W) uint8 frame
 stacks, /norm_val normalisation, compute-dtype forward with fp32

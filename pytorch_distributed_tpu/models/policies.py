@@ -300,7 +300,7 @@ def build_fused_rollout(apply_fn: Callable, env, *, nstep: int,
       device replay ``ReplayState`` carried through the program
       (memory/device_replay.ring_write_masked): experience lands in
       the learner-side HBM ring with ZERO host round-trip — the
-      co-located Sebulba topology, and the bench's fused section.
+      co-located Sebulba topology.
       Returns ``rollout(params, carry, ring_state, base_key, tick0,
       eps) -> (carry', ring_state', RolloutStats)`` with ``carry`` and
       ``ring_state`` donated.

@@ -1,11 +1,13 @@
 """Pallas TPU kernels: the fused dqn-cnn torso as hand-tiled MXU matmuls.
 
-The MFU probe (tools/mfu_probe.py, BENCH_r03) attributes the flagship
-learner's 0.15-0.17 MFU ceiling to two structural costs in XLA's conv
-lowering of the Nature CNN: the 4/32/64-wide conv channels underfill the
-128-lane MXU, and ~25% of device time goes to XLA's own re-tiling
-(layout copies between conv ops).  This module attacks the second cost:
-every GEMM in the torso — the three im2col'd convolutions, the FC-512
+XLA's conv lowering of the Nature CNN leaves the MXU underfilled: the
+4/32/64-wide conv channels do not fill 128 lanes (the flagship learner's
+``mfu`` is 13.7 %, and the first convolution's forward and backward are
+half of ``train.online``: ledger, PR 27, ``apex_pong.learner_only``;
+PERF.md section 5), and the compiler re-tiles activations between conv
+ops.  This module is the opt-in alternative (``pallas_torso``, default
+off; what it buys over XLA's convolutions on the chip is not measured:
+no benchmark cell turns it on, ROADMAP queue 3): every GEMM in the torso — the three im2col'd convolutions, the FC-512
 and the Q head — runs as ONE hand-tiled Pallas kernel each, with the
 contraction and lane dimensions padded to the 128-lane grid ONCE at the
 kernel boundary instead of re-tiled between every XLA op.  Patch
@@ -171,7 +173,6 @@ _CONV_LAYERS: Tuple[Tuple[str, int, int], ...] = (
 
 def build_pallas_torso_apply(norm_val: float = 255.0,
                              compute_dtype=jnp.bfloat16,
-                             nhwc_input: bool = False,
                              interpret: bool = False):
     """The learner-side ``(variables, obs) -> q`` apply running the
     whole dqn-cnn torso through the MXU matmul kernel.
@@ -187,8 +188,7 @@ def build_pallas_torso_apply(norm_val: float = 255.0,
         p = variables["params"]
         x = x.astype(compute_dtype) / jnp.asarray(norm_val,
                                                   dtype=compute_dtype)
-        if not nhwc_input:
-            x = jnp.transpose(x, (0, 2, 3, 1))
+        x = jnp.transpose(x, (0, 2, 3, 1))
         for name, k, stride in _CONV_LAYERS:
             ker = p[name]["kernel"]
             bias = p[name]["bias"]

@@ -2093,7 +2093,7 @@ class DcnGateway:
         failover-lost count — everything a warm restart or a promoting
         standby needs to continue the control plane without double
         counting.  One fsynced append per ``_ha_state_every`` window,
-        amortized across every chunk in it (bench: gateway_ha_overhead)."""
+        amortized across every chunk in it."""
         if not self._serving or self._journal_dead or self._term_fenced:
             return
         now = time.monotonic()

@@ -6,7 +6,7 @@ the DCN wire and replay HBM are the ceilings after compute (ROADMAP
 item 4), and INES (PAPERS.md) makes the case that *where bytes flow*
 decides distributed-RL scale.  Before this module the repo counted
 chunks, rows, and rejects everywhere but **bytes nowhere** — the
-compression campaign cannot be built, benched, or gated until
+compression campaign cannot be built, measured, or gated until
 bytes/transition and bytes/round are first-class, live-queryable
 series with an exact conservation story.  This module is that plane:
 
@@ -19,8 +19,7 @@ series with an exact conservation story.  This module is that plane:
   replay occupancy by column dtype, and per-artifact checkpoint-epoch
   sizes (utils/checkpoint.py).  The hot path is counter-only: one
   dict lookup + two integer adds under a lock that is never held
-  across I/O (bench.py ``wire_overhead`` gates it under the 0.02
-  absolute overhead band, directly timed per the PR-10 lesson).
+  across I/O (its share of a frame's cost is not measured).
 - **Socket registry** — ``socket.socket`` declares ``__slots__`` so
   transport identity cannot ride the object; a WeakKeyDictionary side
   table maps live sockets to ``(link, slot)`` without pinning them.
@@ -53,8 +52,9 @@ ON by default; disabled, every hook is a single module-flag check.
 
 Drilled by ``tools/chaos_soak.py --flood`` (byte ledger exact under
 brownout, bytes shed per rung) and ``--gateway-failover`` (journaled
-byte carry), benched by ``bench.py`` ``wire`` / ``wire_overhead``,
-and covered by tests/test_bandwidth.py.
+byte carry) and covered by tests/test_bandwidth.py, which also holds the
+deterministic counts (``wire/bytes_per_transition``,
+``wire/replica_bytes_per_round``).
 """
 
 from __future__ import annotations
@@ -414,7 +414,7 @@ def enabled() -> bool:
 
 def reset_for_tests() -> None:
     """Drop the process accountant so the next hook re-resolves from
-    the (possibly monkeypatched) environment.  Tests/bench only."""
+    the (possibly monkeypatched) environment.  Tests only."""
     global _ACCT, _RESOLVED, _ENABLED
     with _acct_lock:
         _ACCT = None

@@ -45,8 +45,7 @@ Knobs live in ``config.FlowParams``, env-overridable as
 the same spawn-inheritance contract the health/perf/metrics planes
 use.  The plane defaults ON but INERT: in the healthy state no credit
 field rides the wire, nothing is ever shed, and the hot-path cost is a
-few dict/float ops (bench.py ``flow_overhead`` gates it under the
-0.02 absolute overhead band).
+few dict/float ops (its share of a tick is not measured).
 
 Drilled by ``tools/chaos_soak.py --flood`` / ``--slow-learner-ingest``
 / ``--slow-slot`` (deadlock, unbounded memory, uncounted drops and

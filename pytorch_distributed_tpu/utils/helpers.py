@@ -59,8 +59,8 @@ _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
 
 def compile_cache_dir(environ: Optional[Mapping[str, str]] = None) -> str:
     """Where the persistent XLA compile cache lives — the ONE rule every
-    chip-owning entry point shares (main.py, fleet.py, bench.py,
-    tools/mfu_probe.py): ``JAX_COMPILATION_CACHE_DIR`` verbatim when the
+    chip-owning entry point shares (main.py, fleet.py, chip_smoke.py's
+    legs, benchmark/run.py): ``JAX_COMPILATION_CACHE_DIR`` verbatim when the
     environment sets it, else ``<checkout>/.jax_cache`` (git-ignored).
     Never a temp name, pid or timestamp: the directory is part of the
     cache key's reach, so a path that moves between runs never hits."""

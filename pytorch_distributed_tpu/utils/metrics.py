@@ -4,7 +4,7 @@ The reference logs scalars through a dedicated logger process into
 TensorBoard (tensorboardX SummaryWriter, reference
 core/single_processes/dqn_logger.py:15) with the global learner step as the
 x-axis for everything.  Here the writer is a small append-only JSONL sink
-(always on — machine-readable for bench/CI) plus TensorBoard event files via
+(always on — machine-readable for tools/CI) plus TensorBoard event files via
 ``torch.utils.tensorboard`` when available; scalar names match the reference
 so existing dashboards carry over (``evaluator/avg_reward``,
 ``actor/total_nframes``, ``learner/critic_loss``, ... — reference
@@ -239,7 +239,7 @@ def is_scalar_row(rec: dict) -> bool:
 
 
 def read_scalars(log_dir: str) -> List[dict]:
-    """Load all JSONL records from a run dir (tests/bench/tools use this).
+    """Load all JSONL records from a run dir (tests/tools use this).
     A SIGKILL mid-write leaves a torn trailing line — skip undecodable
     lines instead of raising, matching the torn-artifact philosophy of
     the checkpoint tier (utils/checkpoint.py: a torn epoch is skipped,

@@ -2,21 +2,22 @@
 device-memory watermarks, retrace + transfer auditing, on-demand
 profiling windows.
 
-Until this module, every performance number lived in offline artifacts —
-``bench.py`` one-line JSONs and ``tools/mfu_probe.py`` blobs — so the
-questions the ROADMAP's next levers hinge on ("is the learner's MFU
-moving?", "is the fleet actor-bound right now?") could only be answered
-by stopping the fleet and re-benching.  Podracer (Hessel et al. 2021)
+This is the LIVE plane: what a running fleet reports about itself ("is
+the learner's MFU moving?", "is the fleet actor-bound right now?")
+without being stopped.  It is not the yardstick: what a change does to
+the system's speed is measured on the chip by ``benchmark/run.py`` and
+recorded in PERF.md and ``PERF_LEDGER.jsonl``; ``benchmark/harness/
+peaks.py`` is that benchmark's own peak table, ``PEAK_FLOPS`` below is
+this plane's.  Podracer (Hessel et al. 2021)
 treats continuous device-utilization accounting as part of the training
 loop itself, and Ape-X tunes its actor/learner balance off live
 throughput ratios; this module gives the fleet the same continuously
 exported signals:
 
-- **FLOPs capture** (``flops_of_compiled``): the XLA ``cost_analysis()``
-  extraction previously duplicated in ``bench.py`` (micro + families)
-  and ``mfu_probe.py`` lives here once.  A ``PerfMonitor`` captures the
-  fused learner program's per-update FLOPs at compile time, so MFU is
-  one multiplication per stats window forever after — no re-bench.
+- **FLOPs capture** (``flops_of_compiled``): the one XLA
+  ``cost_analysis()`` extraction.  A ``PerfMonitor`` captures the fused
+  learner program's per-update FLOPs at compile time, so MFU is one
+  multiplication per stats window forever after.
 - **Live rates** (``PerfMonitor``): each role counts its work units
   (learner updates, actor env frames) with one integer add on the hot
   path; the drain on the role's normal metrics cadence turns them into
@@ -61,8 +62,7 @@ import traceback
 from typing import Any, Callable, Dict, List, Optional
 
 # ---------------------------------------------------------------------------
-# peak FLOP/s + cost-analysis FLOPs extraction (shared with bench.py and
-# tools/mfu_probe.py — previously three inline copies)
+# peak FLOP/s + cost-analysis FLOPs extraction
 # ---------------------------------------------------------------------------
 
 # Peak dense bf16 FLOP/s per chip by device_kind, for the MFU estimate.
@@ -83,8 +83,8 @@ PEAK_FLOPS = {
 # MXU runs fp32 matmuls at half the bf16 rate (two passes), so an fp32
 # run scored against the bf16 peak under-reports MFU by 2x (ISSUE-13
 # satellite: config.compute_dtype admits fp32, and a denominator that
-# ignores it makes the fp32 lever in mfu_probe.py look like an MFU
-# collapse instead of the same chip at its fp32 peak).
+# ignores it makes an fp32 run look like an MFU collapse instead of the
+# same chip at its fp32 peak).
 DTYPE_PEAK_SCALE = {
     "bfloat16": 1.0,
     "float32": 0.5,
@@ -117,10 +117,9 @@ def flops_of_compiled(compiled) -> Optional[float]:
     """Per-call FLOPs off a ``cost_analysis()``-bearing jax stage — an
     AOT-compiled executable, or a ``Lowered`` program where the
     backend supports pre-compile analysis (same figures, no XLA
-    compile).  XLA counts a scan/while body ONCE (verified in bench.py
-    micro across K=1/8/64), so for a fused multi-update program the
-    figure is per-UPDATE, not per-dispatch.  Best-effort: backends
-    without cost analysis return None."""
+    compile).  XLA counts a scan/while body ONCE, so for a fused
+    multi-update program the figure is per-UPDATE, not per-dispatch.
+    Best-effort: backends without cost analysis return None."""
     try:
         cost = compiled.cost_analysis()
         c = cost[0] if isinstance(cost, (list, tuple)) else cost
@@ -469,7 +468,7 @@ class PerfMonitor:
         plane: keep the fused rollout's per-env-frame FLOPs, so the
         device actor's MFU rides the SAME frames counter the
         env-frames/s rate uses (ISSUE 7: the rollout program's
-        utilization is a live-plane read, not a bench artifact).
+        utilization is a live-plane read, not an offline artifact).
 
         Cost analysis is read off the LOWERED program when the backend
         supports it (lowering is tracing-only — no XLA compile), so

@@ -176,8 +176,8 @@ def sanitize_label(label: str) -> str:
 
 
 # one profiler per process: jax.profiler.trace raises on a nested
-# start, which used to turn an inner library trace (mfu_probe inside a
-# TPU_APEX_PROFILE'd run) into a crash of the OUTER capture
+# start, which used to turn an inner library trace (a T_PROFILE window
+# inside a TPU_APEX_PROFILE'd run) into a crash of the OUTER capture
 _trace_lock = threading.Lock()
 _trace_active = False
 

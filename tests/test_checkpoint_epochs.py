@@ -382,35 +382,6 @@ class TestCrossFamily:
         batch = host.sample(8, np.random.default_rng(1))
         assert np.isfinite(batch.weight).all()
 
-    def test_device_ring_nchw_nhwc_snapshot_parity(self):
-        from pytorch_distributed_tpu.memory.device_replay import DeviceReplay
-
-        g = dict(capacity=16, state_shape=(2, 4, 4), action_shape=(),
-                 state_dtype=np.uint8, action_dtype=np.int32)
-        rng = np.random.default_rng(0)
-        n = 10
-        chunk = Transition(
-            state0=rng.integers(0, 255, (n, 2, 4, 4)).astype(np.uint8),
-            action=np.zeros(n, np.int32),
-            reward=np.arange(n, dtype=np.float32),
-            gamma_n=np.full(n, 0.99, np.float32),
-            state1=rng.integers(0, 255, (n, 2, 4, 4)).astype(np.uint8),
-            terminal1=np.zeros(n, np.float32))
-        a = DeviceReplay(**g, channels_last=False)
-        b = DeviceReplay(**g, channels_last=True)
-        a.feed_chunk(chunk)
-        b.feed_chunk(chunk)
-        sa, sb = a.snapshot(), b.snapshot()
-        assert set(sa) == set(sb)
-        for k in sa:  # checkpoints are layout-independent (public NCHW)
-            np.testing.assert_array_equal(sa[k], sb[k])
-        # an NCHW snapshot restores into an NHWC ring and round-trips
-        c = DeviceReplay(**g, channels_last=True)
-        assert c.restore(sa) == n
-        sc = c.snapshot()
-        for k in sa:
-            np.testing.assert_array_equal(sc[k], sa[k])
-
     def test_host_device_sequence_interchange(self):
         import jax
 

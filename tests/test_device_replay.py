@@ -122,104 +122,41 @@ def test_multi_step_dispatch_topology(tmp_path):
     assert "learner/critic_loss" in tags
 
 
-def test_channels_last_ring_matches_nchw_training():
-    """NHWC-resident ring + nhwc_input model == NCHW ring + default model:
-    same ingested transitions, same sampling keys -> identical sampled
-    contents and identical train-step losses (the layout is an internal
-    storage detail; factory.device_ring_channels_last wires it)."""
-    from pytorch_distributed_tpu.models import DqnCnnModel
-    from pytorch_distributed_tpu.ops.losses import (
-        build_dqn_train_step, init_train_state, make_optimizer,
-    )
-
-    rng = np.random.default_rng(7)
-    n, shape = 32, (4, 12, 12)
-    chunk = Transition(
-        state0=rng.integers(0, 255, (n, *shape)).astype(np.uint8),
-        action=rng.integers(0, 4, n).astype(np.int32),
-        reward=rng.normal(size=n).astype(np.float32),
-        gamma_n=np.full(n, 0.99, np.float32),
-        state1=rng.integers(0, 255, (n, *shape)).astype(np.uint8),
-        terminal1=(rng.random(n) < 0.2).astype(np.float32),
-    )
-    key = jax.random.PRNGKey(3)
-
-    losses = {}
-    for cl in (False, True):
-        ring = DeviceReplay(capacity=n, state_shape=shape,
-                            state_dtype=np.uint8, channels_last=cl)
-        ring.feed_chunk(chunk)
-        batch = jax.tree_util.tree_map(np.asarray,
-                                       ring.sample(16, key))
-        # same rows drawn regardless of layout...
-        assert batch.state0.shape == ((16, 12, 12, 4) if cl
-                                      else (16, *shape))
-        model = DqnCnnModel(action_space=4, norm_val=255.0,
-                            nhwc_input=cl, compute_dtype=jnp.float32)
-        params = model.init(jax.random.PRNGKey(0),
-                            np.zeros((1, 12, 12, 4) if cl
-                                     else (1, *shape), np.uint8))
-        tx = make_optimizer(lr=1e-3)
-        state = init_train_state(params, tx)
-        step = jax.jit(build_dqn_train_step(model.apply, tx,
-                                            target_model_update=10))
-        _state, metrics, _td = step(state, ring.sample(16, key))
-        losses[cl] = float(metrics["learner/critic_loss"])
-    # ...and the training math is layout-invariant (params init from the
-    # same seed produce the same tree either way)
-    assert losses[False] == pytest.approx(losses[True], rel=1e-5)
-
-
-def test_channels_last_snapshot_is_nchw():
-    """Checkpoints stay layout-independent: a channels-last ring's
-    snapshot rolls back to the public NCHW schema and restores into a
-    NCHW ring (and vice versa)."""
-    rng = np.random.default_rng(11)
-    n, shape = 8, (4, 6, 6)
-    chunk = Transition(
-        state0=rng.integers(0, 255, (n, *shape)).astype(np.uint8),
-        action=np.zeros(n, np.int32),
-        reward=np.arange(n, dtype=np.float32),
-        gamma_n=np.full(n, 0.99, np.float32),
-        state1=rng.integers(0, 255, (n, *shape)).astype(np.uint8),
-        terminal1=np.zeros(n, np.float32),
-    )
-    a = DeviceReplay(capacity=n, state_shape=shape, state_dtype=np.uint8,
-                     channels_last=True)
-    a.feed_chunk(chunk)
-    snap = a.snapshot()
-    assert snap["state0"].shape == (n, *shape)  # public NCHW schema
-    np.testing.assert_array_equal(snap["state0"], chunk.state0)
-    b = DeviceReplay(capacity=n, state_shape=shape, state_dtype=np.uint8,
-                     channels_last=False)
-    b.restore(snap)
-    np.testing.assert_array_equal(
-        b.codec.unpack(np.asarray(b.state.state0[:n])), chunk.state0)
-
-
 # ---------------------------------------------------------------------------
 # the stored row format (RowCodec): exact, and invisible from outside
 # ---------------------------------------------------------------------------
 
 def _frames(rng, n, shape=(4, 12, 12), dtype=np.uint8):
+    if np.dtype(dtype).kind == "f":
+        return rng.standard_normal((n, *shape)).astype(dtype)
     return rng.integers(0, 256, (n, *shape)).astype(dtype)
 
 
-def _frame_chunk(rng, start, n, shape=(4, 12, 12)):
+def _frame_chunk(rng, start, n, shape=(4, 12, 12), dtype=np.uint8):
     return Transition(
-        state0=_frames(rng, n, shape),
+        state0=_frames(rng, n, shape, dtype),
         action=np.zeros(n, np.int32),
         reward=np.arange(start, start + n, dtype=np.float32),
         gamma_n=np.full(n, 0.99, np.float32),
-        state1=_frames(rng, n, shape),
+        state1=_frames(rng, n, shape, dtype),
         terminal1=np.zeros(n, np.float32))
 
 
-def _ring(kind, capacity, shape=(4, 12, 12), **kw):
+def _ring(kind, capacity, shape=(4, 12, 12), dtype=np.uint8, **kw):
     if kind == "per":
         from pytorch_distributed_tpu.memory.device_per import DevicePerReplay
-        return DevicePerReplay(capacity, shape, state_dtype=np.uint8, **kw)
-    return DeviceReplay(capacity, shape, state_dtype=np.uint8, **kw)
+        return DevicePerReplay(capacity, shape, state_dtype=dtype, **kw)
+    return DeviceReplay(capacity, shape, state_dtype=dtype, **kw)
+
+
+# the two row kinds RowCodec adapts to from what it sees (PERF.md section
+# 6, PR 25): pixel rows packed into padded uint32 lines, and the float32
+# control-task rows stored as they are.  (shape, dtype, stored column of
+# an 8-row ring)
+ROWS = {
+    "pixels-u8": ((4, 12, 12), np.uint8, (np.uint32, (8, 256))),
+    "vector-f32": ((4,), np.float32, (np.float32, (8, 4))),
+}
 
 
 @pytest.mark.parametrize("shape,dtype,words", [
@@ -256,36 +193,33 @@ def test_codec_round_trip_is_exact(shape, dtype, words):
         np.asarray(codec.unpack(codec.pack(jnp.asarray(x))[None])), x[None])
 
 
-@pytest.mark.parametrize("channels_last", [False, True])
+@pytest.mark.parametrize("row", list(ROWS))
 @pytest.mark.parametrize("kind", ["uniform", "per"])
-def test_fed_rows_come_back_byte_identical_across_a_wrap(kind,
-                                                         channels_last):
+def test_fed_rows_come_back_byte_identical_across_a_wrap(kind, row):
     """12 rows through an 8-row ring in chunks of 4: snapshot and sample
     return exactly the bytes that were fed, whatever the ring stores."""
+    shape, dtype, (stored_dtype, stored_shape) = ROWS[row]
     rng = np.random.default_rng(1)
-    m = _ring(kind, 8, channels_last=channels_last)
-    assert m.state.state0.dtype == np.uint32       # packed at rest
-    assert m.state.state0.shape == (8, 256)        # 144 words -> 2 x 128
-    chunks = [_frame_chunk(rng, s, 4) for s in (0, 4, 8)]
+    m = _ring(kind, 8, shape, dtype)
+    assert m.state.state0.dtype == stored_dtype    # pixels: packed at rest
+    assert m.state.state0.shape == stored_shape    # 144 words -> 2 x 128
+    chunks = [_frame_chunk(rng, s, 4, shape, dtype) for s in (0, 4, 8)]
     for c in chunks:
         m.feed_chunk(c)
     kept = [np.concatenate([getattr(c, f) for c in chunks[1:]])
             for f in ("state0", "state1")]
-    snap = m.snapshot()                            # oldest first, NCHW
+    snap = m.snapshot()                            # oldest first
     np.testing.assert_array_equal(snap["reward"], np.arange(4, 12))
     np.testing.assert_array_equal(snap["state0"], kept[0])
     np.testing.assert_array_equal(snap["state1"], kept[1])
     b = jax.tree_util.tree_map(np.asarray,
                                m.sample(64, jax.random.PRNGKey(0)))
-    assert b.state0.dtype == np.uint8
-    assert b.state0.shape == (64, 12, 12, 4) if channels_last \
-        else (64, 4, 12, 12)
+    assert b.state0.dtype == dtype
+    assert b.state0.shape == (64, *shape)
     age = b.reward.astype(int) - 4                 # reward names the row
     assert len(set(age.tolist())) > 4              # both kept chunks drawn
-    want = [np.transpose(k, (0, 2, 3, 1)) if channels_last else k
-            for k in kept]
-    np.testing.assert_array_equal(b.state0, want[0][age])
-    np.testing.assert_array_equal(b.state1, want[1][age])
+    np.testing.assert_array_equal(b.state0, kept[0][age])
+    np.testing.assert_array_equal(b.state1, kept[1][age])
 
 
 @pytest.mark.parametrize("kind", ["uniform", "per"])
@@ -342,25 +276,26 @@ def test_packed_ring_on_the_mesh(kind):
         b.reward.astype(int) - 16])
 
 
-@pytest.mark.parametrize("channels_last", [False, True])
+@pytest.mark.parametrize("row", list(ROWS))
 @pytest.mark.parametrize("kind", ["uniform", "per"])
-def test_a_public_schema_snapshot_restores_into_the_packed_ring(
-        kind, channels_last):
+def test_a_public_schema_snapshot_restores_into_the_packed_ring(kind, row):
     """What a checkpoint written before the ring packed its rows holds:
-    plain NCHW uint8 columns.  It restores, and comes back as it went."""
+    plain columns in the public schema.  It restores, and comes back as it
+    went, into a ring of another capacity too."""
+    shape, dtype, _stored = ROWS[row]
     rng = np.random.default_rng(4)
-    c = _frame_chunk(rng, 0, 6)
+    c = _frame_chunk(rng, 0, 6, shape, dtype)
     old = {f: np.asarray(getattr(c, f)) for f in c._fields
            if getattr(c, f) is not None}
     if kind == "per":
         old["leaf_priority"] = np.linspace(0.1, 2.0, 6).astype(np.float32)
         old["max_priority_base"] = np.float64(3.0)
-    m = _ring(kind, 8, channels_last=channels_last)
+    m = _ring(kind, 8, shape, dtype)
     assert m.restore(old) == 6
     snap = m.snapshot()
     for k, v in old.items():
         np.testing.assert_allclose(snap[k], v, rtol=1e-6)
-    assert snap["state0"].dtype == np.uint8
-    again = _ring(kind, 8, channels_last=not channels_last)
+    assert snap["state0"].dtype == dtype
+    again = _ring(kind, 16, shape, dtype)
     again.restore(snap)
     np.testing.assert_array_equal(again.snapshot()["state1"], c.state1)

@@ -241,3 +241,228 @@ def test_donation_safe_with_init_train_state():
     assert int(state.step) == 2
     host = learner.host_params(state)
     assert isinstance(jax.tree_util.tree_leaves(host)[0], np.ndarray)
+
+
+# ---------------------------------------------------------------------------
+# the learner's device program, assembled in one place (factory.py
+# build_learner_core / build_learner_dispatch): what run_learner and the
+# Anakin driver both dispatch, and what the benchmark's mirror must lower
+# ---------------------------------------------------------------------------
+
+def _program_opts(tmp_path, row, **overrides):
+    from pytorch_distributed_tpu.config import build_options
+
+    base = dict(root_dir=str(tmp_path), refs="prog", visualize=False,
+                resume="never", memory_size=128, batch_size=8)
+    base.update(overrides)
+    return build_options(row, **base)
+
+
+_SEQ = dict(memory_type="device-sequence", seq_len=8, seq_overlap=4,
+            burn_in=2, nstep=2, memory_size=256)
+# id -> (CONFIGS row, overrides, K the dispatch must come out at)
+DISPATCH_CASES = {
+    "uniform-k1": (1, dict(memory_type="device", steps_per_dispatch=1), 1),
+    "uniform-k4": (1, dict(memory_type="device", steps_per_dispatch=4), 4),
+    "per-k4": (1, dict(memory_type="device-per", steps_per_dispatch=4), 4),
+    # K = 3 is rounded UP to a whole number of groups of 2
+    "per-megabatch2": (1, dict(memory_type="device-per",
+                               steps_per_dispatch=3, megabatch=2), 4),
+    "sequence-k2": (13, dict(steps_per_dispatch=2, **_SEQ), 2),
+    # decoupled DDPG: two optimizers, a split param tree
+    "ddpg-uniform-k2": (2, dict(memory_type="device",
+                                steps_per_dispatch=2), 2),
+    # one visible device: no mesh, the ring unsharded
+    "per-k2-one-device": (1, dict(memory_type="device-per",
+                                  steps_per_dispatch=2), 2),
+}
+
+
+def _feed(opt, spec, replay, n, seed=0):
+    """``n`` seeded rows of the ring's own schema through its own feed."""
+    from pytorch_distributed_tpu.utils.experience import Transition
+
+    rng = np.random.default_rng(seed)
+    pixels = opt.memory_params.state_dtype == "uint8"
+
+    def obs(shape):
+        return (rng.integers(0, 256, shape).astype(np.uint8) if pixels
+                else rng.standard_normal(shape).astype(np.float32))
+
+    if hasattr(replay, "T"):  # the segment ring
+        from pytorch_distributed_tpu.memory.device_sequence import (
+            SegmentChunk,
+        )
+
+        T = replay.T
+        replay.feed_chunk(SegmentChunk(
+            obs=obs((n, *replay.obs_shape)),
+            action=rng.integers(0, spec.num_actions, (n, T)).astype(np.int32),
+            reward=rng.standard_normal((n, T)).astype(np.float32),
+            terminal=np.zeros((n, T), np.float32),
+            mask=np.ones((n, T), np.float32),
+            c0=np.zeros((n, replay.lstm_dim), np.float32),
+            h0=np.zeros((n, replay.lstm_dim), np.float32)))
+        return
+    action = (rng.integers(0, spec.num_actions, n).astype(np.int32)
+              if spec.discrete else
+              rng.uniform(-1, 1, (n, spec.action_dim)).astype(np.float32))
+    replay.feed_chunk(Transition(
+        state0=obs((n, *spec.state_shape)), action=action,
+        reward=rng.standard_normal(n).astype(np.float32),
+        gamma_n=np.full(n, 0.99, np.float32),
+        state1=obs((n, *spec.state_shape)),
+        terminal1=(rng.random(n) < 0.1).astype(np.float32)))
+
+
+def _assemble(opt, role="learner"):
+    from pytorch_distributed_tpu import factory
+
+    spec = factory.probe_env(opt)
+    core, state = factory.build_learner_core(opt, spec)
+    replay = factory.build_memory(opt, spec).learner_side.attach(
+        mesh=core.mesh)
+    prog = factory.build_learner_dispatch(core, replay, opt, role=role)
+    return spec, prog, state, replay
+
+
+def _call_args(prog, state, replay, seed=0):
+    keys = jax.random.split(jax.random.PRNGKey(seed), prog.K)
+    return (state, replay.state, keys if prog.K > 1 else keys[0],
+            *((jnp.float32(replay.beta(0)),) if prog.takes_beta else ()))
+
+
+class TestLearnerProgram:
+    @pytest.mark.parametrize("case", list(DISPATCH_CASES))
+    def test_one_dispatch_is_k_updates(self, case, tmp_path, monkeypatch):
+        row, overrides, want_k = DISPATCH_CASES[case]
+        if case.endswith("one-device"):
+            one = jax.devices()[:1]
+            monkeypatch.setattr(jax, "devices", lambda *a, **k: one)
+        opt = _program_opts(tmp_path, row, **overrides)
+        spec, prog, state, replay = _assemble(opt)
+        assert (prog.mesh is None) == case.endswith("one-device")
+        assert prog.K == want_k
+        _feed(opt, spec, replay, 64)
+        prioritized = hasattr(replay.state, "priority")
+        assert prog.takes_beta == prioritized
+        before = (np.asarray(replay.state.priority) if prioritized
+                  else None)
+        step0 = int(state.step)
+        out = prog.fused(*_call_args(prog, state, replay))
+        # the record says what came back, in order
+        assert len(out) == len(prog.returns)
+        got = dict(zip(prog.returns, out))
+        assert int(got["state"].step) == step0 + prog.K
+        loss = got["metrics"]["learner/critic_loss"]
+        assert np.isfinite(float(loss))
+        assert ("ring" in got) == prioritized
+        if prioritized:
+            assert type(got["ring"]) is type(replay.state)
+            after = np.asarray(got["ring"].priority)
+            assert (after != before).any()      # |TD| written back
+            assert (after[before == 0] == 0).all()   # empty rows stay so
+        # only the uniform ring's single step hands |TD| out
+        assert ("td" in got) == (not prioritized and prog.K == 1)
+        if "td" in got:
+            assert got["td"].shape == (opt.agent_params.batch_size,)
+
+    @pytest.mark.parametrize("ring", ["per", "sequence"])
+    def test_the_benchmarks_mirror_lowers_the_programs_step(self, ring,
+                                                            tmp_path):
+        """``benchmark/harness/program.py`` assembles by hand what the
+        driver measures; the factory assembles what ``run_learner`` and
+        the Anakin loop run.  Same ``Options`` -> the same program text,
+        or the ledger stops describing the product."""
+        from benchmark.harness import program
+
+        cfg = {"per": {"row": 12, "overrides": {
+                   "memory_size": 512, "batch_size": 8,
+                   "steps_per_dispatch": 4}},
+               "sequence": {"row": 14, "overrides": {
+                   "memory_size": 256, "batch_size": 4, "seq_len": 8,
+                   "seq_overlap": 4, "burn_in": 2, "nstep": 2,
+                   "steps_per_dispatch": 2}}}[ring]
+        opt = program.build_opt(cfg, 3, str(tmp_path / "run"), "mirror")
+        lrn = program.build_learner(opt)
+        theirs = program.build_fused(lrn)
+        _spec, prog, state, replay = _assemble(opt)
+        assert prog.K == lrn.K and prog.takes_beta
+        assert prog.returns == ("state", "ring", "metrics")
+
+        def text(fused, st, rp):
+            keys = jax.random.split(jax.random.PRNGKey(0), prog.K)
+            return fused.lower(st, rp.state, keys,
+                               jnp.float32(rp.beta(0))).as_text()
+
+        assert text(prog.fused, state, replay) \
+            == text(theirs, lrn.state, lrn.replay)
+
+    @pytest.mark.parametrize("split,family", [
+        ("mp", "dtqn-mlp"), ("ep", "dtqn-moe"), ("pp", "dtqn-pipe"),
+        ("anakin-mp", "dtqn-mlp")])
+    def test_model_split_refused_for_the_wrong_family(self, split, family,
+                                                      tmp_path):
+        """Each model split serves one family; any other is refused where
+        the program is assembled, for every caller: the Anakin loop (dqn
+        only) used to skip the check and ran the config unsplit."""
+        from pytorch_distributed_tpu import factory
+
+        match = f"{split[-2:]}_size>1 is only supported for {family}"
+        if split.startswith("anakin"):
+            from test_anakin import _anakin_opts, _make_driver
+
+            with pytest.raises(AssertionError, match=match):
+                _make_driver(_anakin_opts(tmp_path, mp_size=2))
+            return
+        opt = _program_opts(tmp_path, 1, **{f"{split}_size": 2})
+        with pytest.raises(AssertionError, match=match):
+            factory.build_learner_core(opt, factory.probe_env(opt))
+
+    def test_model_file_is_loaded_before_the_state_is_built(self, tmp_path):
+        """Finetune-from-file: the optimizer state and the target copy are
+        made of the LOADED weights, in the split learner's assembly and in
+        the Anakin driver alike."""
+        from test_anakin import _anakin_opts, _make_driver
+
+        from pytorch_distributed_tpu import factory
+        from pytorch_distributed_tpu.utils import checkpoint as ckpt
+
+        opt = _anakin_opts(tmp_path)
+        spec = factory.probe_env(opt)
+        model = factory.build_model(opt, spec)
+        seeded = factory.init_params(opt, spec, model, seed=opt.seed)
+        other = factory.init_params(opt, spec, model, seed=opt.seed + 1)
+        # with and without the suffix, as --model-file is given
+        ckpt.save_params(str(tmp_path / "pre.msgpack"), other)
+
+        def same(a, b):
+            return all(np.array_equal(np.asarray(x), np.asarray(y))
+                       for x, y in zip(jax.tree_util.tree_leaves(a),
+                                       jax.tree_util.tree_leaves(b)))
+
+        assert not same(seeded, other)
+        for model_file in (str(tmp_path / "pre"),
+                           str(tmp_path / "pre.msgpack")):
+            opt.model_file = model_file
+            _core, state = factory.build_learner_core(opt, spec)
+            assert same(state.params, other)
+            assert same(state.target_params, other)
+            assert int(state.step) == 0
+        drv, _handles, _spec = _make_driver(opt)
+        assert same(drv.state.params, other)
+        assert same(drv.state.target_params, other)
+
+    @pytest.mark.parametrize("role", ["learner", "anakin"])
+    def test_megabatch_downgrade_is_loud_and_keeps_k(self, role, tmp_path,
+                                                     capsys):
+        """A family without a group step runs the sequential program at
+        the configured K and says so under the caller's name."""
+        opt = _program_opts(tmp_path, 13, steps_per_dispatch=3, megabatch=2,
+                            **_SEQ)
+        _spec, prog, _state, _replay = _assemble(opt, role=role)
+        assert prog.K == 3
+        said = capsys.readouterr().out
+        assert f"[{role}] megabatch=2 is not supported for " \
+               f"agent_type=r2d2" in said
+        assert "steps_per_dispatch=3" in said

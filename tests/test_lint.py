@@ -952,10 +952,87 @@ class TestDogfood:
 
     def test_check_sh_lint_stage(self):
         """The pre-PR gate's lint stage passes on the repo as checked
-        in (bench stages skipped: they have their own tier + budget)."""
+        in (the fencing drills skipped: they have their own tests)."""
         proc = subprocess.run(
             ["bash", os.path.join(REPO_ROOT, "tools", "check.sh")],
             env={**os.environ, "APEXLINT_ONLY": "1"},
             cwd=REPO_ROOT, capture_output=True, text=True)
         assert proc.returncode == 0, proc.stdout + proc.stderr
         assert "apexlint: PASS" in proc.stdout
+
+
+# ---------------------------------------------------------------------------
+# the gate script and the docs name only what the tree holds
+# ---------------------------------------------------------------------------
+
+_UNTRACKED_DIRS = {".git", "__pycache__", ".pytest_cache", ".hypothesis",
+                   "logs", "models", "runs", "chiprun_out", ".jax_cache",
+                   ".bench_run", ".bench_archive", "build"}
+
+
+def _tracked_files():
+    """Paths git would commit, relative to the repo root; where the
+    checkout is no repository, what a walk finds outside the directories
+    ``.gitignore`` lists."""
+    if os.path.isdir(os.path.join(REPO_ROOT, ".git")):
+        proc = subprocess.run(
+            ["git", "ls-files", "--cached", "--others",
+             "--exclude-standard"],
+            cwd=REPO_ROOT, capture_output=True, text=True)
+        if proc.returncode == 0:
+            return [f for f in proc.stdout.splitlines()
+                    if os.path.isfile(os.path.join(REPO_ROOT, f))]
+    found = []
+    for base, dirs, files in os.walk(REPO_ROOT):
+        dirs[:] = [d for d in dirs if d not in _UNTRACKED_DIRS]
+        found += [os.path.relpath(os.path.join(base, f), REPO_ROOT)
+                  for f in files if not f.endswith((".pyc", ".log"))]
+    return found
+
+
+def test_check_sh_runs_only_what_exists():
+    """``tools/check.sh`` parses, and every file it names is in the
+    tree: a stage whose script was deleted fails here, not before a
+    PR."""
+    import re
+
+    path = os.path.join(REPO_ROOT, "tools", "check.sh")
+    proc = subprocess.run(["bash", "-n", path], capture_output=True,
+                          text=True)
+    assert proc.returncode == 0, proc.stderr
+    text = re.sub(r"\$tmp/[\w.]+", "", open(path).read())
+    named = set(re.findall(
+        r"(?<![\w./-])([\w][\w./-]*\.(?:py|sh|json|jsonl|md))\b", text))
+    assert {"tools/apexlint.py", "tools/fleet_top.py",
+            "tools/chaos_soak.py"} <= named
+    tracked = set(_tracked_files())
+    assert sorted(n for n in named if n not in tracked) == []
+    # stages 1, 1b, 1c, 1d and nothing after them
+    assert re.findall(r"== stage (\w+):", text) == ["1", "1b", "1c", "1d"]
+
+
+# retired with the pre-chip measurement stack and the NHWC ring fork (PR
+# 28).  History may name them: CHANGES.md, ROADMAP.md, PERF.md from its
+# Findings on, and the issue that retired them; benchmark/ is not ours.
+RETIRED = ["bench.py", "bench_gate", "mfu_probe", "BENCH_SMOKE_BASELINE",
+           "device_channels_last", "nhwc_input"]
+_HISTORY = {"CHANGES.md", "ROADMAP.md", "ISSUE.md", "PERF_LEDGER.jsonl",
+            os.path.join("tests", "test_lint.py")}
+
+
+@pytest.mark.parametrize("name", RETIRED)
+def test_no_tracked_text_names_a_retired_path(name):
+    hits = []
+    for rel in _tracked_files():
+        if rel in _HISTORY or rel.split(os.sep)[0] == "benchmark":
+            continue
+        try:
+            text = open(os.path.join(REPO_ROOT, rel),
+                        encoding="utf-8").read()
+        except (UnicodeDecodeError, OSError):
+            continue  # not a text file
+        if rel == "PERF.md":
+            text = text.split("\n## 6. Findings")[0]
+        hits += [f"{rel}:{i}" for i, line in
+                 enumerate(text.splitlines(), 1) if name in line]
+    assert hits == []

@@ -134,18 +134,6 @@ class TestTorsoParity:
                                    np.asarray(model.apply(params, obs)),
                                    rtol=1e-4, atol=1e-4)
 
-    def test_nhwc_input_variant(self, cnn_setup):
-        model, params, obs = cnn_setup
-        nhwc_model = model.clone(nhwc_input=True)
-        obs_nhwc = np.transpose(obs, (0, 2, 3, 1))
-        ap = build_pallas_torso_apply(norm_val=255.0,
-                                      compute_dtype=jnp.float32,
-                                      nhwc_input=True, interpret=True)
-        np.testing.assert_allclose(
-            np.asarray(ap(params, obs_nhwc)),
-            np.asarray(nhwc_model.apply(params, obs_nhwc)),
-            rtol=1e-4, atol=1e-4)
-
 
 class TestFactoryGate:
     def _opt(self, **over):

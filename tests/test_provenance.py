@@ -512,37 +512,6 @@ class TestQuarantineCorrelation:
 
 
 # ---------------------------------------------------------------------------
-# bench gate wiring (satellite: provenance_overhead under the overhead band)
-# ---------------------------------------------------------------------------
-
-class TestBenchGateWiring:
-    def test_provenance_overhead_gated_with_absolute_band(self):
-        sys.path.insert(0, os.path.join(_REPO, "tools"))
-        import bench_gate
-
-        assert any(p == "provenance_overhead.provenance_overhead_frac"
-                   and d == "lower_abs" and s == "overhead"
-                   for p, d, s in bench_gate.SPECS)
-        base = {"provenance_overhead": {"provenance_overhead_frac": 0.001}}
-        ok = {"provenance_overhead": {"provenance_overhead_frac": 0.015}}
-        bad = {"provenance_overhead": {"provenance_overhead_frac": 0.05}}
-        assert not bench_gate.compare(ok, base)["regressions"]
-        report = bench_gate.compare(bad, base)
-        assert [r["key"] for r in report["regressions"]] == \
-            ["provenance_overhead.provenance_overhead_frac"]
-
-    def test_bench_exposes_provenance_mode(self):
-        import bench
-
-        assert hasattr(bench, "bench_provenance_overhead")
-        # the smoke variant shares the measurement logic (CI-sized)
-        import inspect
-
-        assert "smoke" in inspect.signature(
-            bench.bench_provenance_overhead).parameters
-
-
-# ---------------------------------------------------------------------------
 # fleet_top data line
 # ---------------------------------------------------------------------------
 

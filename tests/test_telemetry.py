@@ -677,34 +677,6 @@ class TestKnobs:
 
 
 # ---------------------------------------------------------------------------
-# bench/gate wiring (ISSUE 10 satellite)
-# ---------------------------------------------------------------------------
-
-class TestBenchGateWiring:
-    def test_metrics_overhead_key_is_gated(self):
-        import bench_gate
-
-        base = {"bench_schema": 4,
-                "metrics_overhead": {"metrics_overhead_frac": 0.0}}
-        good = {"bench_schema": 4,
-                "metrics_overhead": {"metrics_overhead_frac": 0.015}}
-        bad = {"bench_schema": 4,
-               "metrics_overhead": {"metrics_overhead_frac": 0.03}}
-        assert not bench_gate.compare(good, base)["regressions"]
-        reg = bench_gate.compare(bad, base)["regressions"]
-        assert [r["key"] for r in reg] == \
-            ["metrics_overhead.metrics_overhead_frac"]
-        assert reg[0]["direction"] == "lower_abs"
-
-    def test_checked_in_baseline_carries_the_section(self):
-        with open(os.path.join(_REPO,
-                               "BENCH_SMOKE_BASELINE.json")) as f:
-            baseline = json.load(f)
-        frac = baseline["metrics_overhead"]["metrics_overhead_frac"]
-        assert frac is not None and frac < 0.02
-
-
-# ---------------------------------------------------------------------------
 # the acceptance drill: seeded chaos_soak learner stall, end to end
 # ---------------------------------------------------------------------------
 

@@ -56,7 +56,7 @@ match anything are ``baseline-stale`` findings so the file is pruned
 forward.  Suppress a single line in code with
 ``# apexlint: ignore[rule-id]`` (bare ``ignore`` silences all rules).
 
-Exit codes (bench_gate-compatible): 0 clean, 1 findings (or stale
+Exit codes: 0 clean, 1 findings (or stale
 baseline entries), 2 usage/config error.
 
 Usage:
