@@ -168,8 +168,11 @@ def leg_train(t_end: float) -> dict:
     # what the learner selected for the device count it found: one chip
     # keeps the ring whole and samples with the Pallas kernel; more shard
     # ring rows over dp, which only the XLA sampler can address
-    want = (dict(mesh=None, per_sampler="pallas") if n == 1
-            else dict(mesh={"dp": n}, per_sampler="xla"))
+    # and hands each chip its share of the batch (128 rows: CMD's)
+    want = (dict(mesh=None, per_sampler="pallas", batch_rows="128x1")
+            if n == 1 else
+            dict(mesh={"dp": n}, per_sampler="xla",
+                 batch_rows=f"{128 // n}x{n}dp"))
     want.update(steps_per_dispatch=32, torso="xla", publish="async")
     for k, v in want.items():
         check(learner[k] == v, f"learner start-up {k}={learner[k]!r}, "

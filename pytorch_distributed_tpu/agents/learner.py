@@ -52,9 +52,12 @@ def announce_startup(opt: Options, *, mesh, steps_per_dispatch: int,
                      replay: Any = None, publish: str = "inline") -> dict:
     """The learner's ONE start-up line: what the chip path resolved to —
     platform, device kind and count, mesh axes, ``steps_per_dispatch``,
-    which PER sampler and which torso were selected, how parameters are
-    published, the format an HBM ring keeps its observation rows in with
-    the bytes it holds (``replay/hbm_bytes``), and the HBM bytes each
+    which PER sampler and which torso were selected, how the fused step's
+    batch lies on the chips (``batch_rows=128x4dp``: rows a chip trains x
+    chips, read off the ring like the sampler; ``512x1`` on one device),
+    how parameters are published, the format an HBM ring keeps its
+    observation rows in with the bytes it holds (``replay/hbm_bytes``),
+    and the HBM bytes each
     device holds once the ring is attached.  Printed once and appended
     to ``<log_dir>/startup.jsonl`` (utils/helpers.record_startup), so no
     branch the learner takes on the backend it found is silent;
@@ -73,6 +76,8 @@ def announce_startup(opt: Options, *, mesh, steps_per_dispatch: int,
         opt.log_dir, "learner", mesh=axes,
         steps_per_dispatch=int(steps_per_dispatch),
         per_sampler=getattr(replay, "sampler", "n/a"),
+        batch_rows=(replay.batch_rows(opt.agent_params.batch_size)
+                    if hasattr(replay, "batch_rows") else "n/a"),
         torso=select_torso(opt) if opt.agent_type == "dqn" else "xla",
         publish=publish, ring_rows=getattr(replay, "stored_rows", "n/a"),
         replay_hbm_bytes=replay_nbytes(getattr(replay, "state", None)),
@@ -83,7 +88,8 @@ def announce_startup(opt: Options, *, mesh, steps_per_dispatch: int,
           f"device_kind={rec['device_kind']!r} "
           f"devices={rec['device_count']} mesh={mesh_s} "
           f"steps_per_dispatch={rec['steps_per_dispatch']} "
-          f"per_sampler={rec['per_sampler']} torso={rec['torso']} "
+          f"per_sampler={rec['per_sampler']} "
+          f"batch_rows={rec['batch_rows']} torso={rec['torso']} "
           f"publish={publish} ring_rows={rec['ring_rows']} "
           f"replay/hbm_bytes={rec['replay_hbm_bytes']} "
           f"hbm_bytes_in_use={hbm}", flush=True)

@@ -27,8 +27,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from pytorch_distributed_tpu.memory.device_replay import (
-    DeviceReplay, RowCodec, gather_rows, jit_feed, ring_write,
-    ring_write_masked, round_capacity,
+    DeviceReplay, RowCodec, gather_rows, group_step_on, jit_feed,
+    ring_write, ring_write_masked, round_capacity,
 )
 from pytorch_distributed_tpu.utils.experience import (
     REPLAY_FIELDS, Batch, Transition,
@@ -319,7 +319,8 @@ class DevicePerReplay(DeviceReplay):
                     batches = jax.vmap(
                         lambda k: per_sample(rs, k, batch_size, beta,
                                              sample_fn=draw_fn))(kset)
-                ts, metrics, td_abs, ok = megabatch_step(ts, batches)
+                ts, metrics, td_abs, ok = group_step_on(
+                    rs, megabatch_step)(ts, batches)
 
                 def land(pri, x):
                     idx, td, ok_i = x
@@ -412,5 +413,7 @@ class DevicePerReplay(DeviceReplay):
 
     def sample(self, batch_size: int, key: jax.Array,
                beta: float = 1.0) -> Batch:
+        """As ``DeviceReplay.sample``, drawn in proportion to priority:
+        on a mesh ``batch_size`` must be a multiple of the data axis."""
         return self._sample_fn(self.state, key, batch_size=batch_size,
                                beta=jnp.asarray(beta))

@@ -66,9 +66,15 @@ class RowCodec:
     directions, independent of byte order, and with no 4-wide minor
     dimension for the compiler to tile.
     A static pytree node: it rides in the ring state, costs no leaf, and
-    tells every jitted reader and writer the format it was built with."""
+    tells every jitted reader and writer the format it was built with.
+
+    ``rows`` is where the ring's rows live: the ``NamedSharding`` that
+    splits every per-row column over the mesh's data axis, None on one
+    device.  ``gather_rows`` reads it and hands out the batch split the
+    same way, so whatever trains on the batch is data-parallel."""
     row_shape: Tuple[int, ...]   # one row in store order: (C,H,W)/(H,W,C)
     dtype: np.dtype
+    rows: Optional[jax.sharding.NamedSharding] = None
 
     @property
     def words(self) -> int:
@@ -273,19 +279,112 @@ def gather_rows(state, idx: jax.Array, weight: jax.Array) -> Batch:
     """Rows ``idx`` of any ring state as a ``Batch`` in the public (store
     order) shapes and dtypes: the one reader of the stored observation
     columns inside a program — the gathered words are unpacked here, on
-    the batch only (RowCodec)."""
-    unpack = state.codec.unpack
+    the batch only (RowCodec).
+
+    On a row-sharded ring (``codec.rows``) the batch comes out sharded the
+    same way, ``index`` and ``weight`` included: chip ``c`` holds rows
+    ``[c*B/dp, (c+1)*B/dp)`` of every leaf, unpacks and trains those, and
+    the compiler partitions the train step behind it (batch-parallel
+    forward and backward, an all-reduce of the gradients).  The DRAW is
+    not touched: ``idx`` arrives replicated, drawn over the whole ring."""
+    codec, unpack = state.codec, state.codec.unpack
     with jax.named_scope(PHASE_GATHER):
+        cols = {k: getattr(state, k) for k in REPLAY_FIELDS}
+        take = lambda col: col[idx]
+        if codec.rows is not None:
+            cols, idx, weight = _exchange_rows(cols, idx, weight, codec.rows)
+            take = lambda col: col          # the batch's rows already
         return Batch(
-            state0=unpack(state.state0[idx]),
-            action=state.action[idx],
-            reward=state.reward[idx],
-            gamma_n=state.gamma_n[idx],
-            state1=unpack(state.state1[idx]),
-            terminal1=state.terminal1[idx],
+            state0=unpack(take(cols["state0"])),
+            action=take(cols["action"]),
+            reward=take(cols["reward"]),
+            gamma_n=take(cols["gamma_n"]),
+            state1=unpack(take(cols["state1"])),
+            terminal1=take(cols["terminal1"]),
             weight=weight,
             index=idx,
         )
+
+
+def _exchange_rows(cols, idx: jax.Array, weight: jax.Array, rows):
+    """Rows ``idx`` (replicated, global) of columns sharded by ``rows``,
+    with ``idx`` and ``weight`` themselves, as a batch sharded the same
+    way: ``(cols, idx, weight)``.  Written out and not left to the
+    partitioner, which answers a gather from a sharded operand with a
+    masked local gather and an ALL-REDUCE that leaves the whole batch on
+    every chip (PERF.md PR 30).  Each chip gathers the drawn rows it owns
+    (zeros elsewhere) and sends block ``c`` of them to chip ``c``; a chip
+    sums the ``dp`` blocks it receives, of which one holds each row, so
+    the sum is exact for every dtype.  That is a reduce-scatter spelled
+    as an ``all_to_all``, because the TPU compiler has no integer
+    reduce-scatter: ``psum_scatter`` of the packed words compiles to the
+    all-reduce again, with a slice behind it."""
+    axis = rows.spec[0]
+    ndev = rows.mesh.shape[axis]
+    if idx.shape[0] % ndev:
+        raise ValueError(
+            f"a batch of {idx.shape[0]} rows does not split over mesh axis "
+            f"{axis}={ndev}: on a row-sharded ring every chip takes an "
+            f"equal share of the batch")
+    share = idx.shape[0] // ndev
+
+    def exchange(cols, idx, weight):
+        chip = jax.lax.axis_index(axis)
+        n = cols["reward"].shape[0]             # rows this chip holds
+        local = idx - chip * n
+        own = (local >= 0) & (local < n)
+        local = jnp.clip(local, 0, n - 1)
+
+        def one(col):
+            got = col[local]
+            mask = own.reshape(-1, *(1,) * (got.ndim - 1))
+            got = jax.lax.all_to_all(
+                jnp.where(mask, got, jnp.zeros((), got.dtype)), axis,
+                split_axis=0, concat_axis=0, tiled=True)
+            return got.reshape(ndev, share, *got.shape[1:]).sum(
+                axis=0, dtype=got.dtype)
+
+        mine = lambda x: jax.lax.dynamic_slice_in_dim(x, chip * share, share)
+        return jax.tree_util.tree_map(one, cols), mine(idx), mine(weight)
+
+    P = jax.sharding.PartitionSpec
+    return jax.shard_map(
+        exchange, mesh=rows.mesh, in_specs=(rows.spec, P(), P()),
+        out_specs=rows.spec)(cols, idx, weight)
+
+
+def group_step_on(state, megabatch_step):
+    """``megabatch_step`` as the fused programs call it on a group drawn
+    from ring ``state``.  On one device: itself.  On a row-sharded ring the
+    group's ``(M, B)`` batch is split over the chips along ``B``, and a
+    megabatch step merges ``M`` into the batch (``vmap`` of the per-row
+    work; the filter gradients become batch-GROUPED convolutions), which
+    the partitioner answers by training the whole group on every chip.  So
+    the step runs per chip on its share of every minibatch
+    (``shard_map``), and is told the axis to reduce its gradients, losses
+    and guard flags over (the ``axis_name`` argument of the steps that
+    ops/losses.py's megabatch builders return; this is its one caller):
+    train state in and out replicated, |TD| sharded like the batch.
+
+    Where it stands (PR 30): held on the virtual CPU mesh by
+    tests/test_device_per.py (compiled HLO; every metric and the
+    parameters against one device) and tests/test_megabatch.py, and
+    compiled at dp4's real size for a described v5e:2x2; no benchmark cell
+    runs a megabatch on a mesh, so it has never RUN on a chip."""
+    rows = state.codec.rows
+    if rows is None:
+        return megabatch_step
+    P, axis = jax.sharding.PartitionSpec, rows.spec[0]
+    # check_vma off: the step reduces by hand (pmean / pmin over
+    # ``axis_name``); with the check on, differentiating through the
+    # replicated parameters would sum the chips' gradients a second time.
+    # The price: ``out_specs=P()`` then hands out chip 0's value of
+    # anything the step forgot to reduce, which is why the tier-1 test
+    # holds EVERY metric to the one-device program's
+    return jax.shard_map(
+        functools.partial(megabatch_step, axis_name=axis), mesh=rows.mesh,
+        in_specs=(P(), P(None, axis)),
+        out_specs=(P(), P(), P(None, axis), P()), check_vma=False)
 
 
 def provenance_sample(state: ReplayState, key: jax.Array,
@@ -342,7 +441,8 @@ def build_uniform_fused_step(step_fn, batch_size: int,
                     batches = jax.vmap(
                         lambda k: sample_rows(ring_state, k,
                                               batch_size))(kset)
-                ts, metrics, _td, _ok = megabatch_step(ts, batches)
+                ts, metrics, _td, _ok = group_step_on(
+                    ring_state, megabatch_step)(ts, batches)
                 return ts, metrics
 
             ts, metrics = jax.lax.scan(one_group, ts, gkeys)
@@ -369,7 +469,10 @@ class DeviceReplay:
     """Convenience stateful wrapper around the functional ring.
 
     ``mesh``/``axis`` shard every buffer row-wise across the data axis so
-    each device holds capacity/n_dev rows of the ring and gathers ride ICI.
+    each device holds capacity/n_dev rows of the ring and gathers ride
+    ICI; a sampled batch then leaves the ring sharded over the same axis
+    (``gather_rows``, told by ``codec.rows``), which is what makes the
+    fused step behind it data-parallel.
     """
 
     def __init__(self, capacity: int, state_shape: Tuple[int, ...],
@@ -384,7 +487,6 @@ class DeviceReplay:
         self.action_dtype = jnp.dtype(action_dtype)
         self.mesh = mesh
         self.axis = axis
-        self.codec = RowCodec(tuple(state_shape), self.state_dtype)
 
         if mesh is not None:
             ndev = mesh.shape[axis]
@@ -397,6 +499,8 @@ class DeviceReplay:
         else:
             self._row_sharding = None
             self._scalar_sharding = None
+        self.codec = RowCodec(tuple(state_shape), self.state_dtype,
+                              rows=self._row_sharding)
 
         self.state = self._init_state()
         self._feed_fn = jit_feed(functools.partial(_feed, capacity=capacity))
@@ -439,6 +543,21 @@ class DeviceReplay:
         line: ``uint32[100000,7168]`` is the packed format."""
         col = self.state.state0
         return f"{col.dtype}[{','.join(map(str, col.shape))}]"
+
+    def batch_rows(self, batch_size: int) -> str:
+        """How a sampled batch of ``batch_size`` rows is ASKED to lie on
+        the chips, for the learner's start-up line: ``128x4dp`` = each of
+        four chips gets 128 rows (what ``gather_rows`` is told by
+        ``codec.rows``), ``512x1`` = one device holds them all.  Read off
+        the ring's sharding, not off a compiled program: that the train
+        step behind the batch is partitioned the same way is held by
+        tests/test_device_per.py
+        ``test_fused_step_of_a_sharded_ring_is_partitioned_over_dp``
+        (the compiled HLO of ``one`` / ``multi`` / ``multi_mega``)."""
+        if self._row_sharding is None:
+            return f"{batch_size}x1"
+        ndev = self.mesh.shape[self.axis]
+        return f"{batch_size // ndev}x{ndev}{self.axis}"
 
     # -- checkpoint (utils/checkpoint.py save_replay/load_replay) -----------
 
@@ -493,6 +612,11 @@ class DeviceReplay:
         self.state = self._feed_fn(self.state, chunk)
 
     def sample(self, batch_size: int, key: jax.Array) -> Batch:
+        """``batch_size`` uniformly drawn rows.  On a mesh the batch comes
+        back sharded over the data axis (``gather_rows``), so
+        ``batch_size`` must be a multiple of the axis size there: another
+        size is refused (``ValueError``), where before PR 30 it returned a
+        replicated batch."""
         return self._sample_fn(self.state, key, batch_size=batch_size)
 
 

@@ -273,7 +273,6 @@ def build_dqn_megabatch_step(
     enable_double: bool = False,
     target_model_update: float = 250,
     huber: bool = False,
-    axis_name: str | None = None,
     guard: bool = True,
 ) -> Callable:
     """ISSUE-13 megabatch group step: ``(state, batches) -> (state,
@@ -297,14 +296,23 @@ def build_dqn_megabatch_step(
     minibatch skips its own update (params/opt/target/step pass
     through), its td_abs row is zeroed, and ``metrics[SKIPPED_KEY]``
     counts the group's skips; ``ok`` (M,) float lets the PER write-back
-    suppress exactly the skipped rows."""
+    suppress exactly the skipped rows.
+
+    ``axis_name`` is an argument of the returned STEP, ``step(state,
+    batches, axis_name=...)``, and of nothing else: the fused programs of
+    a row-sharded HBM ring run the step under ``shard_map`` on each
+    chip's share of every minibatch (memory/device_replay.py
+    ``group_step_on``, the one caller that names an axis).  Gradients,
+    losses, q-means and the guard's flags are then reduced over that
+    axis, so every chip applies the same updates to its replicated
+    state."""
     from pytorch_distributed_tpu.utils.health import SKIPPED_KEY
 
     def minibatch_loss(params, target_params, batch: Batch):
         return _dqn_loss(apply_fn, params, target_params, batch,
                          enable_double, huber)
 
-    def step(state: TrainState, batches: Batch):
+    def step(state: TrainState, batches: Batch, axis_name=None):
         grad_fn = jax.value_and_grad(minibatch_loss, has_aux=True)
         with jax.named_scope(PHASE_ONLINE):  # around vmap: its own
             # batching transposes carry no inner name
@@ -312,13 +320,15 @@ def build_dqn_megabatch_step(
                 grad_fn, in_axes=(None, None, 0))(
                     state.params, state.target_params, batches)
         with jax.named_scope(PHASE_OPTIMIZER):
-            return _apply_group(state, grads, losses, td_abs, q_means)
+            return _apply_group(state, grads, losses, td_abs, q_means,
+                                axis_name)
 
-    def _apply_group(state, grads, losses, td_abs, q_means):
+    def _apply_group(state, grads, losses, td_abs, q_means, axis_name):
         """The M sequential optimizer applies (``train.optimizer``)."""
-        grads = _pmean(grads, axis_name)
+        grads, losses, q_means = _pmean((grads, losses, q_means), axis_name)
         M = losses.shape[0]
-        ok = (_per_minibatch_ok(losses, td_abs, q_means, grads=grads)
+        ok = (_pmin(_per_minibatch_ok(losses, td_abs, q_means, grads=grads),
+                    axis_name)
               if guard else jnp.ones((M,), jnp.float32))
 
         def apply_one(carry, x):
@@ -391,7 +401,6 @@ def build_ddpg_megabatch_step(
     *,
     target_model_update: float = 1e-3,
     huber: bool = False,
-    axis_name: str | None = None,
     guard: bool = True,
 ) -> Callable:
     """Decoupled-DDPG twin of ``build_dqn_megabatch_step``: same
@@ -413,6 +422,8 @@ def build_ddpg_megabatch_step(
     (critic & actor stages) gates the actor/target/step chain, zeroes
     td_abs rows and is the returned ``ok`` — so a minibatch whose
     actor stage alone is non-finite keeps its (finite) critic update.
+
+    ``axis_name``: the step's argument, as in ``build_dqn_megabatch_step``.
     """
     from pytorch_distributed_tpu.utils.health import SKIPPED_KEY
 
@@ -426,7 +437,7 @@ def build_ddpg_megabatch_step(
         return _ddpg_actor_loss(actor_apply_fn, critic_apply_fn,
                                 actor_params, critic_params, batch)
 
-    def step(state: TrainState, batches: Batch):
+    def step(state: TrainState, batches: Batch, axis_name=None):
         params, target = state.params, state.target_params
         target_full = merge_ddpg_params(target["actor"], target["critic"])
 
@@ -449,10 +460,11 @@ def build_ddpg_megabatch_step(
             return (new_cp, sel(new_opt, copt)), new_cp
 
         with jax.named_scope(PHASE_OPTIMIZER):
-            cgrads = _pmean(cgrads, axis_name)
+            cgrads, closs = _pmean((cgrads, closs), axis_name)
             M = closs.shape[0]
             ones = jnp.ones((M,), jnp.float32)
-            ok_c = (_per_minibatch_ok(closs, td_abs, grads=cgrads)
+            ok_c = (_pmin(_per_minibatch_ok(closs, td_abs, grads=cgrads),
+                          axis_name)
                     if guard else ones)
             (final_critic, critic_opt), critics = jax.lax.scan(
                 capply, (params["critic"], state.opt_state["critic"]),
@@ -480,7 +492,7 @@ def build_ddpg_megabatch_step(
                     jnp.where(keep, new_step, step_c)), None
 
         with jax.named_scope(PHASE_OPTIMIZER):
-            agrads = _pmean(agrads, axis_name)
+            agrads, aloss = _pmean((agrads, aloss), axis_name)
             ok = ok_c * (_per_minibatch_ok(aloss, grads=agrads)
                          if guard else ones)
             (final_actor, actor_opt, new_target, new_step), _ = \
@@ -683,3 +695,11 @@ def _pmean(tree: PyTree, axis_name: str | None) -> PyTree:
     if axis_name is None:
         return tree
     return jax.lax.pmean(tree, axis_name=axis_name)
+
+
+def _pmin(x: jnp.ndarray, axis_name: str | None) -> jnp.ndarray:
+    """A guard flag that holds on EVERY shard (``_pmean``'s twin): one
+    chip's non-finite rows skip the update on all of them."""
+    if axis_name is None:
+        return x
+    return jax.lax.pmin(x, axis_name=axis_name)

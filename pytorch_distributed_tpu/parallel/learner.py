@@ -9,6 +9,21 @@ backward per chip and inserts the gradient all-reduce over ICI.  Params,
 optimizer state and the target net are replicated; donated so the whole
 TrainState updates in place in HBM.
 
+That sentence holds for BOTH steps.  The unfused step gets its batch
+sharding here (``in_shardings`` below, the host batch placed by
+``shard_batch``).  The fused step (memory/device_per.py
+``build_fused_step``, device_replay.py ``build_uniform_fused_step``: what
+every TPU default runs) is a plain ``jax.jit`` whose batch is born inside
+the program; it gets its sharding where it leaves the row-sharded HBM
+ring, in ``device_replay.gather_rows`` (each chip receives its ``B / dp``
+rows of the global draw), and the compiler partitions the train step
+behind it the same way.  With no sharding stated there every chip would
+train the whole batch (PERF.md, PR 30).  Where the partitioner puts the
+gradient all-reduce is its choice: under bf16 compute it sums the chips'
+partial gradients as bf16 leaves, before their cast to the float32 of
+the parameters (seen in the compiled dp4 program; PERF.md section 6,
+PR 30, has what that costs in agreement with a float32 reference).
+
 Usage:
     learner = ShardedLearner(step_fn, mesh)          # step_fn from ops.losses
     state = learner.place(state)                     # replicate onto mesh

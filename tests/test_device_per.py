@@ -265,3 +265,208 @@ def test_uniform_fused_step_carries_no_ring_column(megabatch):
     jaxpr = jax.make_jaxpr(fused)(jnp.float32(0), m.state, keys).jaxpr
     assert list(scans_of(jaxpr))
     assert carried_with_leading(jaxpr, 24) == []
+
+
+# ---------------------------------------------------------------------------
+# a row-sharded ring on the 8 virtual CPU devices: the batch leaves the
+# ring sharded over dp (device_replay.gather_rows), so the fused step
+# behind it is data-parallel, and draws exactly what one device draws
+# ---------------------------------------------------------------------------
+
+DP = 4
+# (name, K, megabatch): the three fused programs of build_fused_step
+FUSED_PROGRAMS = [("one", 1, 1), ("multi", 4, 1), ("multi_mega", 4, 2)]
+
+
+def _dp_mesh():
+    from pytorch_distributed_tpu.parallel.mesh import make_mesh
+
+    return make_mesh(dp_size=DP, devices=jax.devices()[:DP])
+
+
+def _pixel_ring(mesh, capacity, shape, seed=0):
+    """A PER ring of packed uint8 frames, full, its priorities spread;
+    the same rows and priorities with or without a mesh."""
+    rng = np.random.default_rng(seed)
+    m = DevicePerReplay(capacity, shape, state_dtype=np.uint8, mesh=mesh)
+    m.feed_chunk(Transition(
+        state0=rng.integers(0, 256, (capacity, *shape)).astype(np.uint8),
+        action=rng.integers(0, 6, capacity).astype(np.int32),
+        reward=rng.normal(size=capacity).astype(np.float32),
+        gamma_n=np.full(capacity, 0.9, np.float32),
+        state1=rng.integers(0, 256, (capacity, *shape)).astype(np.uint8),
+        terminal1=(rng.random(capacity) < 0.1).astype(np.float32)))
+    pri = (np.abs(rng.normal(size=capacity)) + 0.01).astype(np.float32)
+    m.state = m.state._replace(priority=jax.device_put(
+        pri, m.state.priority.sharding))
+    return m
+
+
+def _dqn_steps(model, sample_obs):
+    """(TrainState, step, megabatch step) of a DQN over ``model``, with
+    plain SGD: the update is linear in the gradient, so two summation
+    orders stay a float rounding apart (Adam's first step is lr * sign(g))."""
+    import optax
+
+    from pytorch_distributed_tpu.ops.losses import (
+        build_dqn_megabatch_step, build_dqn_train_step, init_train_state,
+    )
+
+    tx = optax.sgd(0.05)
+    params = model.init(jax.random.PRNGKey(0), sample_obs)
+    return (init_train_state(params, tx),
+            build_dqn_train_step(model.apply, tx),
+            build_dqn_megabatch_step(model.apply, tx))
+
+
+def _run_fused(m, ts, step, mega, B, K, megabatch, mesh):
+    """One blocking call of the ring's fused program; ``(compiled, outputs)``."""
+    from pytorch_distributed_tpu.parallel.mesh import replicated
+
+    fused = m.build_fused_step(
+        step, B, donate=False, steps_per_call=K, megabatch=megabatch,
+        megabatch_step=mega if megabatch > 1 else None)
+    keys = (jax.random.split(jax.random.PRNGKey(3), K) if K > 1
+            else jax.random.PRNGKey(3))
+    if mesh is not None:
+        ts = jax.device_put(ts, replicated(mesh))
+    args = (ts, m.state, keys, jnp.float32(0.5))
+    compiled = fused.lower(*args).compile()
+    # block on each call: queued multi-device programs starve each other
+    # in the CPU backend's rendezvous (ShardedLearner._serialize_collectives)
+    return compiled, jax.block_until_ready(compiled(*args))
+
+
+def _hlo_shapes(text):
+    """``{name: dims}`` of every array-valued instruction of an HLO text."""
+    import re
+
+    return {m.group(1): tuple(int(d) for d in m.group(2).split(",") if d)
+            for m in re.finditer(
+                r"%([\w.\-]+) = \w+\[([\d,]*)\]", text)}
+
+
+@pytest.mark.parametrize("name,K,megabatch", FUSED_PROGRAMS)
+def test_fused_step_of_a_sharded_ring_is_partitioned_over_dp(
+        name, K, megabatch):
+    """From the compiled program: every convolution runs on a chip's share
+    of the batch, the gradients are reduced across the chips, and the
+    gathered rows reach a chip by an all-to-all, never as a whole batch
+    behind an all-reduce."""
+    import re
+
+    from pytorch_distributed_tpu.models import DqnCnnModel
+
+    B = 24                      # 6 rows a chip; 24, 48 are no other dim
+    mesh = _dp_mesh()
+    m = _pixel_ring(mesh, 64, (4, 84, 84))
+    assert m.batch_rows(B) == "6x4dp"
+    ts, step, mega = _dqn_steps(
+        DqnCnnModel(action_space=6), jnp.zeros((1, 4, 84, 84), jnp.uint8))
+    compiled, (ts2, rs2, metrics) = _run_fused(m, ts, step, mega, B, K,
+                                               megabatch, mesh)
+    assert np.isfinite(float(metrics["learner/critic_loss"]))
+    # the train state comes back replicated, the ring as it was sharded
+    for leaf in jax.tree_util.tree_leaves(ts2):
+        assert leaf.sharding.is_fully_replicated
+    assert rs2.state0.sharding == m.state.state0.sharding
+
+    text = compiled.as_text()
+    shapes = _hlo_shapes(text)
+    whole, share = {B, B * megabatch}, (B // DP) * megabatch
+    convs = re.findall(r"%([\w.\-]+) = \S+ convolution\(%([\w.\-]+), "
+                       r"%([\w.\-]+)\)", text)
+    assert convs
+    seen_share = False
+    for names in convs:
+        dims = {d for n in names for d in shapes[n]}
+        assert not dims & whole, (names, [shapes[n] for n in names])
+        seen_share |= share in dims
+    assert seen_share
+    reduces = [l for l in text.splitlines() if " all-reduce(" in l]
+    # the FC kernel's gradient (3136 x 512) crosses the chips
+    assert any("3136" in l.split(" all-reduce(")[0] for l in reduces)
+    # no all-reduce hands every chip the gathered words of the whole batch
+    words = m.state.state0.shape[1]
+    for line in reduces:
+        for dims in re.findall(r"\w+\[([\d,]+)\]",
+                               line.split(" all-reduce(")[0]):
+            dims = {int(d) for d in dims.split(",")}
+            assert not (words in dims and dims & whole), line
+    assert any(str(words) in l for l in text.splitlines()
+               if " all-to-all(" in l)
+
+
+@pytest.mark.parametrize("name,K,megabatch", FUSED_PROGRAMS)
+def test_fused_step_of_a_sharded_ring_draws_and_trains_as_one_device(
+        name, K, megabatch):
+    """Same ring contents, same keys: the mesh program and the one-device
+    program draw the same rows, rewrite the same priority rows, and agree
+    in EVERY metric (a value the megabatch step's ``shard_map`` forgot to
+    reduce would be chip 0's alone: its replication check is off) and in
+    parameters to float rounding."""
+    from pytorch_distributed_tpu.models import DqnMlpModel
+
+    B, shape = 16, (4, 6, 6)
+    model = DqnMlpModel(action_space=6, hidden_dim=32, norm_val=255.0)
+    ts, step, mega = _dqn_steps(model, jnp.zeros((1, *shape), jnp.uint8))
+    out = {}
+    for where, mesh in (("one_device", None), ("mesh", _dp_mesh())):
+        m = _pixel_ring(mesh, 64, shape)
+        before = np.asarray(m.state.priority)
+        drawn = m.sample(B, jax.random.PRNGKey(3), beta=0.5)
+        _, (ts2, rs2, metrics) = _run_fused(m, ts, step, mega, B, K,
+                                            megabatch, mesh)
+        out[where] = dict(
+            drawn=jax.device_get(drawn), before=before,
+            priority=np.asarray(rs2.priority),
+            max_priority=float(rs2.max_priority),
+            metrics=jax.device_get(metrics),
+            params=jax.device_get(ts2.params), step=int(ts2.step))
+    a, b = out["one_device"], out["mesh"]
+    # the draw: the same rows, bit for bit; the IS weights divide by the
+    # ring's priority mass, which the mesh sums in another order
+    for f, x, y in zip(a["drawn"]._fields, a["drawn"], b["drawn"]):
+        if f == "weight":
+            np.testing.assert_allclose(x, y, rtol=1e-6)
+        else:
+            np.testing.assert_array_equal(x, y, err_msg=f)
+    assert a["step"] == b["step"] == K
+    rewritten = a["priority"] != a["before"]
+    assert rewritten.any()
+    np.testing.assert_array_equal(rewritten, b["priority"] != b["before"])
+    np.testing.assert_allclose(a["priority"], b["priority"], rtol=1e-5)
+    np.testing.assert_allclose(a["max_priority"], b["max_priority"],
+                               rtol=1e-5)
+    assert a["metrics"].keys() == b["metrics"].keys()
+    assert "learner/critic_loss" in a["metrics"]
+    for k in a["metrics"]:
+        np.testing.assert_allclose(a["metrics"][k], b["metrics"][k],
+                                   rtol=1e-5, atol=1e-7, err_msg=k)
+    jax.tree_util.tree_map(
+        lambda x, y: np.testing.assert_allclose(x, y, rtol=1e-4, atol=1e-6),
+        a["params"], b["params"])
+
+
+def test_a_batch_that_does_not_split_over_the_mesh_is_refused():
+    """On a row-sharded ring every chip takes an equal share of the batch:
+    a size the data axis does not divide is refused loudly, never trained
+    whole on every chip."""
+    m = _pixel_ring(_dp_mesh(), 64, (4, 6, 6))
+    assert m.sample(8, jax.random.PRNGKey(0)).state0.shape[0] == 8
+    with pytest.raises(ValueError, match="does not split over mesh axis"):
+        m.sample(6, jax.random.PRNGKey(0))
+
+
+def test_fused_step_without_a_mesh_states_no_sharding():
+    """No mesh: the batch's sharding helper is the identity, and the
+    lowered program says nothing about shardings or collectives."""
+    m = _frame_ring()
+    assert m.codec.rows is None and m.batch_rows(4) == "4x1"
+    for K, megabatch in [(1, 1), (4, 1), (4, 2)]:
+        keys = (jax.random.split(jax.random.PRNGKey(0), K) if K > 1
+                else jax.random.PRNGKey(0))
+        text = _fused(m, K, megabatch).lower(
+            jnp.float32(0), m.state, keys, jnp.float32(0.4)).as_text()
+        for word in ("sharding", "all_to_all", "manual", "collective"):
+            assert word not in text, (K, megabatch, word)

@@ -416,6 +416,62 @@ class TestFusedMegabatchDispatch:
                                       prio_before)
 
 
+    @pytest.mark.parametrize("M", [1, 2])
+    def test_uniform_ddpg_dispatch_on_a_mesh_matches_one_device(self, M):
+        """A row-sharded uniform ring on the virtual CPU mesh: the fused
+        dispatch (sequential at M=1; at M=2 the group step on each chip's
+        share of every minibatch, device_replay.group_step_on) draws the
+        one-device program's rows and lands on its parameters."""
+        from pytorch_distributed_tpu.memory.device_replay import (
+            DeviceReplay, build_uniform_fused_step,
+        )
+        from pytorch_distributed_tpu.parallel.mesh import (
+            make_mesh, replicated,
+        )
+
+        model = DdpgMlpModel(action_dim=1, norm_val=1.0)
+        params = model.init(jax.random.PRNGKey(0), jnp.zeros((1, OBS)))
+        # plain SGD: linear in the gradient, so another summation order
+        # stays a rounding away (Adam's first step is lr * sign(g))
+        atx, ctx_ = optax.sgd(1e-2), optax.sgd(1e-2)
+        state = init_ddpg_train_state(params, atx, ctx_)
+        actor = lambda p, o: model.apply(p, o, method=model.forward_actor)
+        critic = lambda p, o, a: model.apply(p, o, a,
+                                             method=model.forward_critic)
+        seq = build_ddpg_train_step(actor, critic, atx, ctx_)
+        mega = build_ddpg_megabatch_step(actor, critic, atx, ctx_)
+        rng = np.random.default_rng(0)
+        n, K = 128, 4
+        chunk = Transition(
+            state0=rng.normal(size=(n, OBS)).astype(np.float32),
+            action=rng.uniform(-1, 1, (n, 1)).astype(np.float32),
+            reward=rng.normal(size=n).astype(np.float32),
+            gamma_n=np.full(n, 0.95, np.float32),
+            state1=rng.normal(size=(n, OBS)).astype(np.float32),
+            terminal1=(rng.random(n) < 0.2).astype(np.float32))
+        keys = jax.random.split(jax.random.PRNGKey(7), K)
+        out = []
+        for mesh in (None, make_mesh(dp_size=4, devices=jax.devices()[:4])):
+            ring = DeviceReplay(n, (OBS,), action_shape=(1,),
+                                state_dtype=np.float32,
+                                action_dtype=np.float32, mesh=mesh)
+            ring.feed_chunk(chunk)
+            fused = build_uniform_fused_step(
+                seq, B, steps_per_call=K, donate=False, megabatch=M,
+                megabatch_step=mega if M > 1 else None)
+            ts = (state if mesh is None
+                  else jax.device_put(state, replicated(mesh)))
+            new, metrics = jax.block_until_ready(fused(ts, ring.state, keys))
+            out.append((jax.device_get(new.params),
+                        float(metrics["learner/critic_loss"]),
+                        jax.device_get(ring.sample(B, keys[0]))))
+        (p0, loss0, rows0), (p1, loss1, rows1) = out
+        for x, y in zip(rows0, rows1):
+            np.testing.assert_array_equal(x, y)
+        np.testing.assert_allclose(loss0, loss1, rtol=1e-5)
+        _assert_tree_close(p0, p1, rtol=1e-5, atol=1e-6)
+
+
 class TestMegabatchPerfDrills:
     """The drills every fused hot-path dispatch carries (test_perf.py
     style): the megabatched program must never recompile after warmup
