@@ -133,10 +133,12 @@ class LearnerStats(_Accumulator):
     """Loss accumulators (reference logs.py:15-24; DDPG adds actor_loss,
     reference ddpg_logger.py:51)."""
 
-    # the hybrid trunk's routing counters (models/hybrid.py moe_stats): the
-    # step metric ``learner/<name>`` of each, 0 for every other model
+    # the hybrid trunks' counters (models/hybrid.py moe_stats and
+    # window_applies): the step metric ``learner/<name>`` of each, 0 for
+    # every model that does not report it
     MOE_FIELDS = ("moe_rows_here", "moe_rows_absent_share",
-                  "moe_load_max_over_mean")
+                  "moe_load_max_over_mean", "moe_rows_computed",
+                  "moe_aux_loss", "gdn_decay_mean")
     FIELDS = ("counter", "critic_loss", "actor_loss", "q_mean", "grad_norm",
               "steps_per_sec", "moe_aux", *MOE_FIELDS)
 

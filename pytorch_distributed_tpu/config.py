@@ -59,7 +59,11 @@ CONFIGS = [
     ["r2d2",      "fake",      "chain",       "sequence",    "dtqn-pipe"],# 18 staged transformer Q (pipeline parallel)
     ["dqn",       "pong-sim",  "pong",        "device-per",  "dqn-cnn-wide"],# 19 MXU-filling wide torso (ISSUE 13)
     ["r2d2",      "pong-sim",  "pong",        "device-sequence", "dtqn-hybrid"],# 20 state-space / sparse-expert / grouped-query trunk (models/hybrid.py)
+    ["r2d2",      "pong-sim",  "pong",        "device-sequence", "dtqn-hybrid"],# 21 gated-delta-rule / 512-expert / gated-attention trunk (ROW_DEFAULTS)
 ]
+
+# What a row sets beside its five selectors, before the caller's overrides.
+ROW_DEFAULTS = {21: {"hybrid_preset": "qwen3-next-4"}}
 
 
 # ---------------------------------------------------------------------------
@@ -286,8 +290,10 @@ class ModelParams:
     # Compute dtype for the forward/backward pass on TPU (params stay fp32).
     compute_dtype: str = "bfloat16"
     # dtqn-hybrid: which frozen preset of models/hybrid.py PRESETS holds the
-    # trunk's widths ("nemotron-h-9": the published ones; "tiny": CPU tests).
-    # Widths live there and nowhere else.
+    # trunk's layer pattern, widths and mixer options ("nemotron-h-9": row
+    # 20's published ones; "qwen3-next-4": row 21's, set by ROW_DEFAULTS;
+    # "tiny" / "tiny-qwen": CPU tests of either).  They live there and
+    # nowhere else.
     hybrid_preset: str = "nemotron-h-9"
 
 
@@ -1017,6 +1023,7 @@ def build_options(config: int = 1, **overrides: Any) -> Options:
     :54-69, then applies overrides (our CLI affordance).
     """
     agent_type, env_type, game, memory_type, model_type = CONFIGS[config]
+    overrides = {**ROW_DEFAULTS.get(config, {}), **overrides}
 
     # Selector overrides must land before sub-param construction so the
     # per-family defaults they derive (hyperparams, shapes, dtypes, PER flag)

@@ -1,23 +1,37 @@
 """Hybrid sequence Q-network: a layer PATTERN of state-space (Mamba-2),
-sparse-expert and grouped-query attention blocks over one frame per
-position (model_type ``dtqn-hybrid``, CONFIGS row 20).
+gated-delta-rule, sparse-expert and grouped-query attention blocks over one
+frame per position (model_type ``dtqn-hybrid``, CONFIGS rows 20 and 21).
 
-The trunk is layers 0-8 of a published hybrid language model at their
-published widths (``PRESETS["nemotron-h-9"]``; source and every departure:
-benchmark/configs/nemotron_h_pong.json), with the token embedding and LM
+The trunk is the first layers of a published hybrid language model at their
+published widths (``PRESETS["nemotron-h-9"]``: layers 0-8, CONFIGS row 20,
+benchmark/configs/nemotron_h_pong.json; ``PRESETS["qwen3-next-4"]``: layers
+0-3, eight blocks, CONFIGS row 21, benchmark/configs/qwen3_next_pong.json;
+source and every departure in those files), with the token embedding and LM
 head replaced by the repo's sequence-family contract (models/dtqn.py): one
 84x84 uint8 frame a position -> Dense -> trunk -> final RMSNorm ->
 zero-initialised Q head.  Pre-norm residual blocks ``x <- x +
-mixer(RMSNorm(x))``, no biases but the conv's.  One letter a layer:
+mixer(RMSNorm(x))``, no biases but Mamba's conv's; the norm's scale is
+``w`` or, zero-centred (``norm_plus_one``), ``1 + w``.  One letter a layer:
 
 - ``M``  Mamba-2: ``[z | xBC | dt] = u W_in``; causal depth-wise conv +
   silu on xBC; per head ``S_t = exp(dt_t A) S_{t-1} + dt_t x_t (x) B_t``,
   ``y_t = S_t C_t + D x_t``; gated grouped RMSNorm; ``W_out``.  The
   learner computes it in chunks (``ssd_chunked``: matmuls inside a chunk,
   a scan over chunk states in float32), the actor one position at a time.
-- ``*``  causal grouped-query attention WITHOUT rotary or position table
-  (the family's attention is position-free), in query blocks: no
-  ``(B, heads, T, T)`` array exists.
+- ``D``  gated delta rule (models/gated_delta.py): ``[q | k | v | z] = u
+  W_qkvz``, ``[b | a] = u W_ba``; causal depth-wise conv + silu on
+  ``[q | k | v]``; ``beta = sigmoid(b)``, ``g = -exp(A_log) softplus(a +
+  dt_bias)``; q, k L2-normalised a head, ``q / sqrt(d_k)``; the recurrence;
+  a head's output through a gated RMSNorm (``w_n * o / rms(o) * silu(z)``);
+  ``W_out``.  In chunks for the learner, one position at a time for the
+  actor.
+- ``*``  causal grouped-query attention in query blocks: no ``(B, heads, T,
+  T)`` array exists.  Position-free as published for the first preset;
+  with the second's options a query / key RMSNorm a head (``qk_norm``),
+  rotary on the first ``rotary_dim`` dimensions of each head (rotate-half;
+  position = index in the window, for the actor the carry's count: its key
+  ring holds keys rotated at their own position) and a sigmoid output gate
+  from the doubled query projection (``attn_gate``).
 - ``E``  sigmoid-scored experts: top-k of ``s + b_sel`` over ALL experts,
   weights ``s`` (without ``b_sel``) normalised, times ``route_scale``;
   ``relu(u W_up)^2 W_down`` per expert, plus one shared expert.  The layer
@@ -32,13 +46,20 @@ mixer(RMSNorm(x))``, no biases but the conv's.  One letter a layer:
   every update it moves by ``bias_rate`` against each expert's load
   (``balance_selection_bias``), the family's way of spreading the tokens
   over the experts (at ``bias_rate`` 1e-3 slower than Adam's first updates
-  move the router: PERF.md section 6).
+  move the router: PERF.md section 6).  With ``router = "softmax"``: ``p =
+  softmax(u W_r)`` over all experts, top-k of ``p``, weights ``p`` of the
+  chosen normalised to sum 1, no ``b_sel``; balance is an auxiliary loss
+  ``n_experts * sum_e f_e P_e`` a layer (``f_e`` the share of (token,
+  choice) pairs that chose expert e, ``P_e`` the mean of ``p_e``; over ALL
+  experts) that joins the TD loss with weight ``aux_weight``.  With
+  ``gated_experts``: SwiGLU experts ``(silu(u W_gate) * u W_up) W_down``,
+  and the shared expert the same behind ``sigmoid(u . shared_gate)``.
 
 Contracts shared with the other sequence families (recurrent actor,
 evaluator, sequence learner): ``window_q(frames (B, T, H, W))`` is the
 learner's one causal pass, zero state at position 0; ``__call__(obs,
-carry)`` acts one step through a carry of (conv tails, SSM states, key /
-value caches, count) whose leaves all lead with the batch dimension;
+carry)`` acts one step through a carry of (conv tails, SSM or delta-rule
+states, key / value caches, count) whose leaves all lead with the batch dimension;
 ``state_for_segment`` stores the 1-dim placeholder of every ``dtqn*``
 model.  bfloat16 matmuls; float32 parameters, router, softmax, norms,
 ``dt``, ``A`` and scan state.  Every mixer runs under ``jax.checkpoint``
@@ -57,9 +78,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from pytorch_distributed_tpu.models.gated_delta import (
+    gated_delta_chunked, gated_delta_step,
+)
+from pytorch_distributed_tpu.ops.sequence_losses import AUX_LOSS_KEY
 from pytorch_distributed_tpu.utils.profiling import (
-    SCOPE_ATTN, SCOPE_EMBED, SCOPE_HEAD, SCOPE_MOE, SCOPE_MOE_EXPERTS,
-    SCOPE_MOE_ROUTE, SCOPE_MOE_SHARED, SCOPE_SSM,
+    SCOPE_ATTN, SCOPE_EMBED, SCOPE_GDN, SCOPE_HEAD, SCOPE_MOE,
+    SCOPE_MOE_EXPERTS, SCOPE_MOE_ROUTE, SCOPE_MOE_SHARED, SCOPE_SSM,
 )
 
 F32 = jnp.float32
@@ -69,18 +94,9 @@ F32 = jnp.float32
 class HybridPreset:
     """Every width of the trunk, in ONE place."""
 
-    pattern: str                 # one letter a layer: M, E or *
+    pattern: str                 # one letter a layer: M, D, E or *
     d_model: int
-    # M: Mamba-2
-    ssm_heads: int
-    ssm_head_dim: int
-    ssm_state: int
-    ssm_groups: int
-    conv_kernel: int
-    chunk: int
-    dt_min: float
-    dt_max: float
-    dt_floor: float
+    conv_kernel: int             # M and D: the causal depth-wise conv
     # *: grouped-query attention
     attn_heads: int
     kv_heads: int
@@ -96,6 +112,33 @@ class HybridPreset:
     first_expert: int            # ... starting at this one
     bias_rate: float = 1e-3      # b_sel's step against the load, an update
     norm_eps: float = 1e-5
+    # M: Mamba-2
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_groups: int = 1
+    chunk: int = 0
+    dt_min: float = 1e-3
+    dt_max: float = 0.1
+    dt_floor: float = 1e-4
+    # D: gated delta rule
+    gdn_k_heads: int = 0
+    gdn_v_heads: int = 0         # each key head serves v_heads / k_heads
+    gdn_head_dim: int = 0        # of keys and of values
+    gdn_chunk: int = 64
+    # what the second published trunk does otherwise
+    norm_plus_one: bool = False  # norm scales are 1 + w, w initialised 0
+    qk_norm: bool = False        # *: RMSNorm of queries and keys, a head
+    rotary_dim: int = 0          # *: leading dimensions of a head rotated
+    rope_theta: float = 1e4
+    attn_gate: bool = False      # *: sigmoid output gate beside the query
+    router: str = "sigmoid"      # E: or "softmax" (no b_sel, no scale)
+    gated_experts: bool = False  # E: SwiGLU experts, gated shared expert
+    aux_weight: float = 0.0      # E: weight of the load-balancing loss
+    # the step metrics of "nemotron-h-9" are pinned with its lowered step
+    # (CHANGES.md, PR 31); a preset made since also counts the rows its
+    # grouped matmuls were handed (learner/moe_rows_computed)
+    count_rows_computed: bool = True
 
     @property
     def d_inner(self) -> int:
@@ -104,6 +147,18 @@ class HybridPreset:
     @property
     def conv_dim(self) -> int:
         return self.d_inner + 2 * self.ssm_groups * self.ssm_state
+
+    @property
+    def gdn_key_dim(self) -> int:
+        return self.gdn_k_heads * self.gdn_head_dim
+
+    @property
+    def gdn_value_dim(self) -> int:
+        return self.gdn_v_heads * self.gdn_head_dim
+
+    @property
+    def gdn_conv_dim(self) -> int:
+        return 2 * self.gdn_key_dim + self.gdn_value_dim
 
 
 PRESETS: Dict[str, HybridPreset] = {
@@ -116,7 +171,21 @@ PRESETS: Dict[str, HybridPreset] = {
         conv_kernel=4, chunk=128, dt_min=1e-3, dt_max=0.1, dt_floor=1e-4,
         attn_heads=32, kv_heads=2, attn_head_dim=128, attn_block=256,
         n_experts=128, top_k=6, expert_width=1856, shared_width=3712,
-        route_scale=2.5, experts_held=8, first_expert=0),
+        route_scale=2.5, experts_held=8, first_expert=0,
+        count_rows_computed=False),
+    # layers 0-3 of the published 48 (one period: three gated-delta-rule
+    # mixers to one gated attention, an expert layer after each: eight
+    # blocks), every width as published; 32 of the 512 experts held: one of
+    # the 16 chips that share each layer
+    "qwen3-next-4": HybridPreset(
+        pattern="DEDEDE*E", d_model=2048, conv_kernel=4,
+        gdn_k_heads=16, gdn_v_heads=32, gdn_head_dim=128, gdn_chunk=64,
+        attn_heads=16, kv_heads=2, attn_head_dim=256, attn_block=256,
+        n_experts=512, top_k=10, expert_width=512, shared_width=512,
+        route_scale=1.0, experts_held=32, first_expert=0, norm_eps=1e-6,
+        norm_plus_one=True, qk_norm=True, rotary_dim=64, rope_theta=1e7,
+        attn_gate=True, router="softmax", gated_experts=True,
+        aux_weight=1e-3),
     # CPU tests: every mechanism, no width
     "tiny": HybridPreset(
         pattern="ME*E", d_model=32,
@@ -125,6 +194,15 @@ PRESETS: Dict[str, HybridPreset] = {
         attn_heads=4, kv_heads=2, attn_head_dim=8, attn_block=4,
         n_experts=16, top_k=3, expert_width=16, shared_width=24,
         route_scale=2.5, experts_held=4, first_expert=0),
+    "tiny-qwen": HybridPreset(
+        pattern="DE*E", d_model=32, conv_kernel=4,
+        gdn_k_heads=2, gdn_v_heads=4, gdn_head_dim=8, gdn_chunk=4,
+        attn_heads=4, kv_heads=2, attn_head_dim=8, attn_block=4,
+        n_experts=16, top_k=3, expert_width=16, shared_width=16,
+        route_scale=1.0, experts_held=4, first_expert=0, norm_eps=1e-6,
+        norm_plus_one=True, qk_norm=True, rotary_dim=4, rope_theta=1e7,
+        attn_gate=True, router="softmax", gated_experts=True,
+        aux_weight=1e-3),
 }
 
 
@@ -160,6 +238,12 @@ def rms_norm(x, scale, eps: float, groups: int = 1):
     xg = x.reshape(*shape[:-1], groups, shape[-1] // groups)
     xg = xg * jax.lax.rsqrt(jnp.mean(jnp.square(xg), -1, keepdims=True) + eps)
     return xg.reshape(shape) * scale
+
+
+def norm_scale(w, c: "HybridPreset"):
+    """A norm's scale from its parameter: ``w``, or ``1 + w`` where the
+    preset's norms are zero-centred."""
+    return 1.0 + w if c.norm_plus_one else w
 
 
 def _mm(x, w, cd):
@@ -291,6 +375,63 @@ def mamba_step(p, u, tail, S, c: HybridPreset, cd):
         return _ssm_out(p, y, x, z, c, cd), taps[:, 1:], S
 
 
+def _gdn_inputs(p, qkv, ba, c: HybridPreset):
+    """After the conv: q, k (.., h_k, d) L2-normalised a head and q scaled;
+    v (.., h_v, d); the log-decay g and the write strength beta (.., h_v),
+    float32."""
+    lead, d = qkv.shape[:-1], c.gdn_head_dim
+    q, k, v = jnp.split(qkv, [c.gdn_key_dim, 2 * c.gdn_key_dim], axis=-1)
+    unit = lambda t: t * jax.lax.rsqrt(
+        jnp.sum(jnp.square(t), -1, keepdims=True) + 1e-6)
+    q = unit(q.reshape(*lead, c.gdn_k_heads, d)) / math.sqrt(d)
+    k = unit(k.reshape(*lead, c.gdn_k_heads, d))
+    b, a = jnp.split(ba, 2, axis=-1)
+    g = -jnp.exp(p["A_log"]) * jax.nn.softplus(a + p["dt_bias"])
+    return q, k, v.reshape(*lead, c.gdn_v_heads, d), g, jax.nn.sigmoid(b)
+
+
+def _gdn_out(p, o, z, c: HybridPreset, cd):
+    """A head's gated norm (``w_n * o / rms(o) * silu(z)``), then the out
+    projection."""
+    z = z.astype(F32).reshape(o.shape)
+    o = rms_norm(o, p["gate_norm"], c.norm_eps) * jax.nn.silu(z)
+    return _mm(o.reshape(*o.shape[:-2], c.gdn_value_dim), p["w_out"], cd)
+
+
+def gdn_window(p, u, c: HybridPreset, cd):
+    """One D mixer over (b, T, d) normed input, zero state at t = 0; T
+    padded up to whole chunks (``g`` = ``beta`` = 0 in the padding: it decays
+    nothing and writes nothing).  Returns (out (b, T, d) float32, the state
+    after position T - 1 (b, h_v, d_k, d_v) float32, the mean decay
+    ``exp(g)``: how long the memory is)."""
+    with jax.named_scope(SCOPE_GDN):
+        b, T, _ = u.shape
+        pad = -T % c.gdn_chunk
+        qkv, z = jnp.split(_mm(u, p["w_qkvz"], cd), [c.gdn_conv_dim], axis=-1)
+        K = c.conv_kernel
+        xp = jnp.pad(qkv, ((0, 0), (K - 1, 0), (0, 0)))
+        qkv = jax.nn.silu(sum(xp[:, j:j + T] * p["conv_w"][j]
+                              for j in range(K)))
+        q, k, v, g, beta = _gdn_inputs(p, qkv, _mm(u, p["w_ba"], cd), c)
+        grow = lambda t: jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
+        o, S = gated_delta_chunked(grow(q), grow(k), grow(v), grow(g),
+                                   grow(beta), c.gdn_chunk, cd)
+        return (_gdn_out(p, o[:, :T], z, c, cd), S,
+                jax.lax.stop_gradient(jnp.mean(jnp.exp(g))))
+
+
+def gdn_step(p, u, tail, S, c: HybridPreset, cd):
+    """One position: u (b, d); tail (b, K-1, gdn_conv_dim) the conv's last
+    inputs; S (b, h_v, d_k, d_v) float32."""
+    with jax.named_scope(SCOPE_GDN):
+        qkv, z = jnp.split(_mm(u, p["w_qkvz"], cd), [c.gdn_conv_dim], axis=-1)
+        taps = jnp.concatenate([tail, qkv[:, None]], axis=1)   # (b, K, .)
+        qkv = jax.nn.silu(jnp.einsum("bkc,kc->bc", taps, p["conv_w"]))
+        q, k, v, g, beta = _gdn_inputs(p, qkv, _mm(u, p["w_ba"], cd), c)
+        o, S = gated_delta_step(q, k, v, g, beta, S)
+        return _gdn_out(p, o, z, c, cd), taps[:, 1:], S
+
+
 def _attend(q, k, v, mask, cd):
     """softmax(q k^T / sqrt(hd)) v for one block of queries: q (b, kv, r,
     Tq, hd), k / v (b, kv, Tk, hd), mask (.., Tq, Tk) or None.  Scores and
@@ -304,12 +445,37 @@ def _attend(q, k, v, mask, cd):
                       preferred_element_type=F32)
 
 
-def _qkv(p, u, c: HybridPreset, cd):
-    lead = u.shape[:-1]
-    q = _mm(u, p["w_q"], cd).reshape(*lead, c.attn_heads, c.attn_head_dim)
-    k = _mm(u, p["w_k"], cd).reshape(*lead, c.kv_heads, c.attn_head_dim)
-    v = _mm(u, p["w_v"], cd).reshape(*lead, c.kv_heads, c.attn_head_dim)
-    return q, k, v
+def rotary(x, pos, c: HybridPreset):
+    """Rotate-half rotary on the first ``rotary_dim`` dimensions of each
+    head: x (.., heads, hd) float32, pos broadcastable to x's leading
+    dimensions."""
+    half = c.rotary_dim // 2
+    freq = c.rope_theta ** (-jnp.arange(half, dtype=F32) / half)
+    angle = jnp.asarray(pos, F32)[..., None, None] * freq      # (.., 1, half)
+    cos, sin = jnp.cos(angle), jnp.sin(angle)
+    x1, x2, rest = jnp.split(x, [half, 2 * half], axis=-1)
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin, rest],
+                           axis=-1)
+
+
+def _qkv(p, u, pos, c: HybridPreset, cd):
+    """Queries, keys and values, (.., heads, hd), and the output gate (..,
+    attn_heads * hd) or None.  With the preset's options: ``[q | gate] = u
+    W_q`` a head (``attn_gate``), an RMSNorm of q and k a head
+    (``qk_norm``), rotary at ``pos`` (``rotary_dim``)."""
+    lead, hd = u.shape[:-1], c.attn_head_dim
+    q = _mm(u, p["w_q"], cd).reshape(*lead, c.attn_heads, -1)
+    gate = None
+    if c.attn_gate:
+        q, gate = q[..., :hd], q[..., hd:].reshape(*lead, -1)
+    k = _mm(u, p["w_k"], cd).reshape(*lead, c.kv_heads, hd)
+    v = _mm(u, p["w_v"], cd).reshape(*lead, c.kv_heads, hd)
+    if c.qk_norm:
+        q = rms_norm(q, norm_scale(p["q_norm"], c), c.norm_eps)
+        k = rms_norm(k, norm_scale(p["k_norm"], c), c.norm_eps)
+    if c.rotary_dim:
+        q, k = rotary(q, pos, c), rotary(k, pos, c)
+    return q, k, v, gate
 
 
 def attention_window(p, u, c: HybridPreset, cd):
@@ -319,7 +485,7 @@ def attention_window(p, u, c: HybridPreset, cd):
     with jax.named_scope(SCOPE_ATTN):
         b, T, _ = u.shape
         r, Q = c.attn_heads // c.kv_heads, min(c.attn_block, T)
-        q, k, v = _qkv(p, u, c, cd)
+        q, k, v, gate = _qkv(p, u, jnp.arange(T), c, cd)
         q = q.reshape(b, T, c.kv_heads, r, -1).transpose(0, 2, 3, 1, 4)
         k, v = (t.transpose(0, 2, 1, 3) for t in (k, v))       # (b,kv,T,hd)
 
@@ -334,17 +500,20 @@ def attention_window(p, u, c: HybridPreset, cd):
                      lo) for lo in range(0, T, Q)]
         o = jnp.concatenate(out, axis=3)                       # (b,kv,r,T,hd)
         o = o.transpose(0, 3, 1, 2, 4).reshape(b, T, -1)
+        if gate is not None:
+            o = o * jax.nn.sigmoid(gate)
         return _mm(o, p["w_o"], cd)
 
 
 def attention_step(p, u, kc, vc, count, c: HybridPreset, cd):
     """One position against the caches kc / vc (b, W, kv, hd), a ring of
-    the last W keys (the attention is position-free, so their order in the
-    ring does not matter); ``count`` (b,) positions seen before this one."""
+    the last W keys (position-free, or each key rotated at its own position
+    before it is stored: either way their order in the ring does not
+    matter); ``count`` (b,) positions seen before this one."""
     with jax.named_scope(SCOPE_ATTN):
         b, W = kc.shape[:2]
         r = c.attn_heads // c.kv_heads
-        q, k, v = _qkv(p, u, c, cd)
+        q, k, v, gate = _qkv(p, u, count, c, cd)
         at = (count % W).astype(jnp.int32)
         put = jax.vmap(lambda cache, new, i:
                        jax.lax.dynamic_update_slice_in_dim(cache, new[None],
@@ -354,13 +523,17 @@ def attention_step(p, u, kc, vc, count, c: HybridPreset, cd):
         o = _attend(q.reshape(b, c.kv_heads, r, 1, -1),
                     kc.transpose(0, 2, 1, 3), vc.transpose(0, 2, 1, 3),
                     valid[:, None, None, None, :], cd)
-        return _mm(o.reshape(b, -1), p["w_o"], cd), kc, vc
+        o = o.reshape(b, -1)
+        if gate is not None:
+            o = o * jax.nn.sigmoid(gate)
+        return _mm(o, p["w_o"], cd), kc, vc
 
 
 def route(p, u, c: HybridPreset):
     """Router in float32 over ALL experts: (chosen (N, k) int32, weights
     (N, k) float32, load (E,) int32: the tokens that chose each expert).
-    Selected with ``b_sel``, weighed without."""
+    Sigmoid scores: selected with ``b_sel``, weighed without (``route_aux``
+    is the softmax router)."""
     with jax.named_scope(SCOPE_MOE_ROUTE):
         s = jax.nn.sigmoid(jnp.matmul(u.astype(F32), p["router"],
                                       precision=jax.lax.Precision.HIGHEST))
@@ -371,6 +544,24 @@ def route(p, u, c: HybridPreset):
         load = jnp.sum(jax.nn.one_hot(chosen, c.n_experts, dtype=jnp.int32),
                        axis=(0, 1))
         return chosen.astype(jnp.int32), w, load
+
+
+def route_aux(p, u, c: HybridPreset):
+    """The softmax router: ``route``'s three for ``p = softmax(u W_r)``
+    over all experts, top-k of ``p``, the chosen's ``p`` normalised to sum
+    1, and fourth the layer's load-balancing loss ``n_experts * sum_e f_e
+    P_e`` (``f_e`` the share of the (token, choice) pairs that chose expert
+    e, ``P_e`` the mean of ``p_e`` over the tokens; 1 at a level load)."""
+    with jax.named_scope(SCOPE_MOE_ROUTE):
+        prob = jax.nn.softmax(jnp.matmul(
+            u.astype(F32), p["router"], precision=jax.lax.Precision.HIGHEST))
+        w, chosen = jax.lax.top_k(prob, c.top_k)
+        w = w / jnp.sum(w, axis=-1, keepdims=True)
+        load = jnp.sum(jax.nn.one_hot(chosen, c.n_experts, dtype=jnp.int32),
+                       axis=(0, 1))
+        share = load.astype(F32) / (chosen.shape[0] * c.top_k)
+        aux = c.n_experts * jnp.sum(share * jnp.mean(prob, axis=0))
+        return chosen.astype(jnp.int32), w, load, aux
 
 
 def _tile(dim: int, most: int) -> int:
@@ -386,7 +577,8 @@ def grouped_dot(x, w, sizes, kernel: str = "auto"):
     """``x[rows of group g] @ w[g]`` for rows sorted by group: x (R, k), w
     (H, k, n), sizes (H,) summing to R; float32 out.  On a TPU the Pallas
     grouped matmul that ships with JAX (``megablox.gmm``, kernels ``gmm``
-    and ``tgmm`` in a trace), at tiles of 256 rows by up to 896: the
+    and ``tgmm`` in a trace), at tiles of 256 rows by up to 896 (the rows:
+    at row 21's 160-row groups 128 and 512 were both slower): the
     compiler's own ``jax.lax.ragged_dot`` kernel, at 512 x 128 x 128 tiles,
     took 3.3 times as long (PERF.md section 6).  Elsewhere
     ``jax.lax.ragged_dot``.  ``kernel``: "auto", "xla" or "interpret" (the
@@ -405,7 +597,8 @@ def grouped_dot(x, w, sizes, kernel: str = "auto"):
 def _grouped_ffn(p, u, tok, w_pair, sizes, cd, kernel="auto"):
     """The held experts' part for a run of sorted rows ``tok`` (R,) (rows
     of one expert adjacent, ``sizes`` rows each, summing to R; the rows
-    that belong to no expert here point at token N): two grouped matmuls,
+    that belong to no expert here point at token N): two grouped matmuls
+    (three where the experts are gated: ``w_gate`` beside ``w_up``),
     then each row's result is added to its token.  (N, d) float32.  Rows
     of no token are zero going in, and each grouped matmul's output is
     masked before anything reads it, which masks its cotangents too: the
@@ -415,11 +608,12 @@ def _grouped_ffn(p, u, tok, w_pair, sizes, cd, kernel="auto"):
     N = u.shape[0]
     valid = (tok < N)[:, None]
     keep = lambda t: jnp.where(valid, t, 0.0)
+    dot = lambda a, name: keep(grouped_dot(
+        a.astype(cd), p[name].astype(cd), sizes, kernel))
     x = keep(jnp.take(u, tok, axis=0, mode="fill", fill_value=0))
-    hid = relu2(keep(grouped_dot(x.astype(cd), p["w_up"].astype(cd), sizes,
-                                 kernel)))
-    y = keep(grouped_dot(hid.astype(cd), p["w_down"].astype(cd), sizes,
-                         kernel)) * w_pair[:, None]
+    hid = jax.nn.silu(dot(x, "w_gate")) * dot(x, "w_up") if "w_gate" in p \
+        else relu2(dot(x, "w_up"))
+    y = dot(hid, "w_down") * w_pair[:, None]
     return jnp.zeros((N, y.shape[1]), F32).at[tok].add(y, mode="drop")
 
 
@@ -466,7 +660,8 @@ def routed_experts(p, u, chosen, w, sizes, c: HybridPreset, cd,
     fill = sum(runs) - N * k
     tok = jnp.pad(order // k, (0, fill), constant_values=N)
     w_sorted = jnp.pad(jnp.take(w.reshape(-1), order), (0, fill))
-    p = {name: p[name].astype(cd) for name in ("w_up", "w_down")}
+    p = {name: p[name].astype(cd) for name in ("w_gate", "w_up", "w_down")
+         if name in p}
 
     def run(lo, R):
         """The part of the sorted rows [lo, lo + R)."""
@@ -492,20 +687,37 @@ def routed_experts(p, u, chosen, w, sizes, c: HybridPreset, cd,
     return routed
 
 
-def moe_apply(p, u, c: HybridPreset, cd, kernel="auto"):
+def moe_layer(p, u, c: HybridPreset, cd, kernel="auto"):
     """One E mixer over (N, d) normed tokens: the shared expert plus the
     routed experts held here.  Returns (out (N, d) float32, the tokens
-    that chose each of ALL experts (E,) int32); the rows of the experts
-    held here are ``held_load`` of that."""
+    that chose each of ALL experts (E,) int32, the layer's load-balancing
+    loss or None where the router has none); the rows of the experts held
+    here are ``held_load`` of the load."""
     with jax.named_scope(SCOPE_MOE):
-        chosen, w, load = route(p, u, c)
+        if c.router == "softmax":
+            chosen, w, load, aux = route_aux(p, u, c)
+        else:
+            (chosen, w, load), aux = route(p, u, c), None
         with jax.named_scope(SCOPE_MOE_EXPERTS):
             routed = routed_experts(p, u, chosen, w, held_load(load, c), c,
                                     cd, kernel)
         with jax.named_scope(SCOPE_MOE_SHARED):
-            shared = _mm(relu2(_mm(u, p["w_shared_up"], cd)),
-                         p["w_shared_down"], cd)
-        return routed + shared, load
+            if c.gated_experts:
+                shared = _mm(jax.nn.silu(_mm(u, p["w_shared_gate"], cd))
+                             * _mm(u, p["w_shared_up"], cd),
+                             p["w_shared_down"], cd)
+                shared = shared * jax.nn.sigmoid(jnp.matmul(
+                    u.astype(F32), p["shared_gate"],
+                    precision=jax.lax.Precision.HIGHEST))
+            else:
+                shared = _mm(relu2(_mm(u, p["w_shared_up"], cd)),
+                             p["w_shared_down"], cd)
+        return routed + shared, load, aux
+
+
+def moe_apply(p, u, c: HybridPreset, cd, kernel="auto"):
+    """``moe_layer``'s output and load."""
+    return moe_layer(p, u, c, cd, kernel)[:2]
 
 
 def held_load(load, c: HybridPreset):
@@ -513,15 +725,19 @@ def held_load(load, c: HybridPreset):
     return load[c.first_expert:c.first_expert + c.experts_held]
 
 
-def moe_stats(load: Dict[int, jnp.ndarray], c: HybridPreset
-              ) -> Dict[str, jnp.ndarray]:
+def moe_stats(load: Dict[int, jnp.ndarray], c: HybridPreset,
+              pairs: int = 0) -> Dict[str, jnp.ndarray]:
     """The program's routing counters as step metrics, from each E layer's
     load (the tokens that chose each expert): ``learner/moe_rows_here``
     (the rows the held experts received, mean over the E layers; per layer
     as ``.../E<layer>``), ``learner/moe_rows_absent_share`` (mean: the
     (token, choice) pairs whose expert is on another chip) and
     ``learner/moe_load_max_over_mean`` (the busiest held expert over the
-    mean load of ALL experts, the worst layer: 1 is a balanced router)."""
+    mean load of ALL experts, the worst layer: 1 is a balanced router);
+    where the preset counts them, ``learner/moe_rows_computed`` (mean: the
+    rows the grouped matmuls were handed, every run that holds a row routed
+    here computed whole; ``pairs`` = the update's (token, choice) pairs, a
+    Python int)."""
     if not load:
         return {}
     sizes = {i: held_load(n, c) for i, n in load.items()}
@@ -534,6 +750,12 @@ def moe_stats(load: Dict[int, jnp.ndarray], c: HybridPreset
     out["learner/moe_rows_absent_share"] = 1.0 - here / n_pairs
     out["learner/moe_load_max_over_mean"] = jnp.max(jnp.stack([
         jnp.max(n) / mean for n in sizes.values()]))
+    if c.count_rows_computed and pairs:
+        runs = expert_runs(c, pairs)
+        starts = jnp.array([sum(runs[:j]) for j in range(len(runs))], F32)
+        out["learner/moe_rows_computed"] = jnp.mean(jnp.stack([
+            jnp.sum(jnp.where(starts < r, jnp.array(runs, F32), 0.0))
+            for r in rows.values()]))
     return out
 
 
@@ -588,6 +810,18 @@ def _b_sel_init(key, shape, dtype=F32):
     return 0.01 * jax.random.normal(key, shape, dtype)
 
 
+def _gdn_a_log_init(key, shape, dtype=F32):
+    """The delta-rule family's: ``A = U(0, 16)`` (floored where its log
+    would not be finite)."""
+    return jnp.log(jnp.maximum(
+        jax.random.uniform(key, shape, dtype, 0.0, 16.0), 1e-4))
+
+
+def _norm_init(c: HybridPreset):
+    """A norm's parameter at scale 1: zero where the scale is ``1 + w``."""
+    return nn.initializers.zeros if c.norm_plus_one else _ones
+
+
 def layer_param_specs(kind: str, c: HybridPreset):
     """name -> (initialiser, shape) of one layer's parameters."""
     d = c.d_model
@@ -603,10 +837,38 @@ def layer_param_specs(kind: str, c: HybridPreset):
             "gate_norm": (_ones, (c.d_inner,)),
             "w_out": (_lecun, (c.d_inner, d)),
         }
+    if kind == "D":
+        return {
+            "w_qkvz": (_lecun, (d, c.gdn_conv_dim + c.gdn_value_dim)),
+            "w_ba": (_lecun, (d, 2 * c.gdn_v_heads)),
+            "conv_w": (_lecun, (c.conv_kernel, c.gdn_conv_dim)),
+            "dt_bias": (_ones, (c.gdn_v_heads,)),
+            "A_log": (_gdn_a_log_init, (c.gdn_v_heads,)),
+            "gate_norm": (_ones, (c.gdn_head_dim,)),
+            "w_out": (_lecun, (c.gdn_value_dim, d)),
+        }
     if kind == "*":
         hq, hk = c.attn_heads * c.attn_head_dim, c.kv_heads * c.attn_head_dim
-        return {"w_q": (_lecun, (d, hq)), "w_k": (_lecun, (d, hk)),
-                "w_v": (_lecun, (d, hk)), "w_o": (_lecun, (hq, d))}
+        specs = {"w_q": (_lecun, (d, 2 * hq if c.attn_gate else hq)),
+                 "w_k": (_lecun, (d, hk)),
+                 "w_v": (_lecun, (d, hk)), "w_o": (_lecun, (hq, d))}
+        if c.qk_norm:
+            specs.update(q_norm=(_norm_init(c), (c.attn_head_dim,)),
+                         k_norm=(_norm_init(c), (c.attn_head_dim,)))
+        return specs
+    if kind == "E" and c.gated_experts:
+        H = c.experts_held
+        assert c.router == "softmax", "gated experts come with that router"
+        return {
+            "router": (_lecun, (d, c.n_experts)),
+            "w_gate": (_lecun_experts, (H, d, c.expert_width)),
+            "w_up": (_lecun_experts, (H, d, c.expert_width)),
+            "w_down": (_lecun_experts, (H, c.expert_width, d)),
+            "w_shared_gate": (_lecun, (d, c.shared_width)),
+            "w_shared_up": (_lecun, (d, c.shared_width)),
+            "w_shared_down": (_lecun, (c.shared_width, d)),
+            "shared_gate": (_lecun, (d, 1)),
+        }
     if kind == "E":
         H = c.experts_held
         return {
@@ -629,7 +891,8 @@ class _Layer(nn.Module):
     preset: HybridPreset
 
     def setup(self):
-        self.norm = self.param("norm", _ones, (self.preset.d_model,))
+        self.norm = self.param("norm", _norm_init(self.preset),
+                               (self.preset.d_model,))
         for name, (init, shape) in layer_param_specs(
                 self.kind, self.preset).items():
             setattr(self, name, self.param(name, init, shape))
@@ -654,7 +917,8 @@ class HybridQModel(nn.Module):
         H, W = self.state_shape[-2:]
         self.w_embed = self.param("w_embed", _lecun, (H * W, c.d_model))
         self.layers = [_Layer(kind, c) for kind in c.pattern]
-        self.final_norm = self.param("final_norm", _ones, (c.d_model,))
+        self.final_norm = self.param("final_norm", _norm_init(c),
+                                     (c.d_model,))
         self.head_w = self.param("head_w", nn.initializers.zeros,
                                  (c.d_model, self.action_space))
         self.head_b = self.param("head_b", nn.initializers.zeros,
@@ -672,7 +936,8 @@ class HybridQModel(nn.Module):
 
     def _head(self, x):
         with jax.named_scope(SCOPE_HEAD):
-            x = rms_norm(x, self.final_norm, self.preset.norm_eps)
+            x = rms_norm(x, norm_scale(self.final_norm, self.preset),
+                         self.preset.norm_eps)
             return jnp.matmul(x, self.head_w,
                               precision=jax.lax.Precision.HIGHEST) + self.head_b
 
@@ -681,35 +946,49 @@ class HybridQModel(nn.Module):
     def window_pass(self, frames):
         """One causal pass over (B, T, H, W) frames from a zero state:
         (Q (B, T, A) float32, {E layer index: (E,) tokens that chose each
-        expert}, {M layer index: (B, h, p, n) float32 state after the last
-        position})."""
+        expert}, {M or D layer index: the layer's float32 state after the
+        last position: (B, h, p, n), (B, h_v, d_k, d_v)})."""
+        return self.window_pass_full(frames)[:3]
+
+    def window_pass_full(self, frames):
+        """``window_pass``'s three, and fourth what the second trunk's
+        layers report besides: {"aux": {E layer: its load-balancing loss},
+        "decay": {D layer: its mean decay ``exp(g)``}}."""
         c, cd = self.preset, self.compute_dtype
         x = self._embed(frames)
         B, T, d = x.shape
-        load, states = {}, {}
+        load, states, aux, decay = {}, {}, {}, {}
+        norm = lambda p, x: rms_norm(x, norm_scale(p["norm"], c), c.norm_eps)
         for i, layer in enumerate(self.layers):
             p = layer.params_dict()
             if layer.kind == "E":
                 @jax.checkpoint
                 def mix(p, x):
-                    u = rms_norm(x, p["norm"], c.norm_eps).reshape(B * T, d)
-                    out, n = moe_apply(p, u, c, cd)
-                    return x + out.reshape(B, T, d).astype(cd), n
-                x, load[i] = mix(p, x)
+                    u = norm(p, x).reshape(B * T, d)
+                    out, n, loss = moe_layer(p, u, c, cd)
+                    return x + out.reshape(B, T, d).astype(cd), n, loss
+                x, load[i], loss = mix(p, x)
+                if loss is not None:
+                    aux[i] = loss
             elif layer.kind == "M":
                 @jax.checkpoint
                 def mix(p, x):
-                    out, S = mamba_window(
-                        p, rms_norm(x, p["norm"], c.norm_eps), c, cd)
+                    out, S = mamba_window(p, norm(p, x), c, cd)
                     return x + out.astype(cd), S
                 x, states[i] = mix(p, x)
+            elif layer.kind == "D":
+                @jax.checkpoint
+                def mix(p, x):
+                    out, S, kept = gdn_window(p, norm(p, x), c, cd)
+                    return x + out.astype(cd), S, kept
+                x, states[i], decay[i] = mix(p, x)
             else:
                 @jax.checkpoint
                 def mix(p, x):
-                    u = rms_norm(x, p["norm"], c.norm_eps)
+                    u = norm(p, x)
                     return x + attention_window(p, u, c, cd).astype(cd)
                 x = mix(p, x)
-        return self._head(x), load, states
+        return self._head(x), load, states, {"aux": aux, "decay": decay}
 
     def window_q(self, frames):
         return self.window_pass(frames)[0]
@@ -732,14 +1011,19 @@ class HybridQModel(nn.Module):
 
     def zero_carry(self, batch: int):
         """A flat tuple, every leaf leading with the batch dimension: per M
-        layer (conv tail, SSM state), per * layer (keys, values), then the
-        count of positions seen."""
+        or D layer (conv tail, float32 state), per * layer (keys, values),
+        then the count of positions seen."""
         c, out = self.preset, []
         for kind in c.pattern:
             if kind == "M":
                 out += [jnp.zeros((batch, c.conv_kernel - 1, c.conv_dim), F32),
                         jnp.zeros((batch, c.ssm_heads, c.ssm_head_dim,
                                    c.ssm_state), F32)]
+            elif kind == "D":
+                out += [jnp.zeros((batch, c.conv_kernel - 1, c.gdn_conv_dim),
+                                  F32),
+                        jnp.zeros((batch, c.gdn_v_heads, c.gdn_head_dim,
+                                   c.gdn_head_dim), F32)]
             elif kind == "*":
                 kv = (batch, self.act_window, c.kv_heads, c.attn_head_dim)
                 out += [jnp.zeros(kv, self.compute_dtype),
@@ -762,9 +1046,10 @@ class HybridQModel(nn.Module):
         at = 0
         for layer in self.layers:
             p = layer.params_dict()
-            u = rms_norm(x, p["norm"], c.norm_eps)
-            if layer.kind == "M":
-                out, carry[at], carry[at + 1] = mamba_step(
+            u = rms_norm(x, norm_scale(p["norm"], c), c.norm_eps)
+            if layer.kind in "MD":
+                step = mamba_step if layer.kind == "M" else gdn_step
+                out, carry[at], carry[at + 1] = step(
                     p, u, carry[at], carry[at + 1], c, cd)
                 at += 2
             elif layer.kind == "*":
@@ -788,16 +1073,27 @@ def window_applies(model: HybridQModel, pack_frames: int = 0):
     holds the routing counters of the E layers as step metrics (scalars)
     and each E layer's load under ``LOAD_KEY``; ``target(params, obs) ->
     Q``; ``after_update(params, aux) -> params``, the step ``b_sel`` takes
-    against that load.  ``pack_frames`` = C: obs arrives frame-packed (B,
+    against that load (None for a router without one).  Where the expert
+    layers have a load-balancing loss, aux carries their weighted sum under
+    ``AUX_LOSS_KEY``: the train step adds it to the TD loss and reports
+    it.  ``pack_frames`` = C: obs arrives frame-packed (B,
     T + C, H, W) as the ring stores it, and position t reads frame t + C -
     1, the newest of its stack."""
     newest = lambda obs: obs[:, pack_frames - 1:] if pack_frames else obs
 
+    c = model.preset
+
     def online(params, obs):
-        q, load, _ = model.apply(params, newest(obs),
-                                 method=model.window_pass)
-        aux = moe_stats(load, model.preset)
+        q, load, _, more = model.apply(params, newest(obs),
+                                       method=model.window_pass_full)
+        aux = moe_stats(load, c, q.shape[0] * q.shape[1] * c.top_k)
         aux.update({f"{LOAD_KEY}{i}": n for i, n in load.items()})
+        if more["aux"]:
+            # the one entry the train step adds to the TD loss
+            aux[AUX_LOSS_KEY] = c.aux_weight * sum(more["aux"].values())
+        if more["decay"]:
+            aux["learner/gdn_decay_mean"] = jnp.mean(jnp.stack(
+                list(more["decay"].values())))
         return q, aux
 
     def target(params, obs):
@@ -808,4 +1104,5 @@ def window_applies(model: HybridQModel, pack_frames: int = 0):
             params, {int(k[len(LOAD_KEY):]): n for k, n in aux.items()
                      if k.startswith(LOAD_KEY)}, model.preset)
 
-    return online, target, after_update
+    # only the sigmoid router has a selection bias to step
+    return online, target, after_update if c.router == "sigmoid" else None

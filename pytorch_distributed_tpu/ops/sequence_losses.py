@@ -280,6 +280,12 @@ def build_drqn_train_step(
     return finite_guard(step) if guard else step
 
 
+# the entry of a window_apply's dict that is a LOSS: added to the TD loss as
+# the model weighed it, and reported under this name like the dict's other
+# scalars
+AUX_LOSS_KEY = "learner/moe_aux_loss"
+
+
 def build_dtqn_train_step(
     window_apply: Callable,
     tx: optax.GradientTransformation,
@@ -313,7 +319,9 @@ def build_dtqn_train_step(
     layers) has the dict's scalars joined to the step's metrics instead,
     and the whole dict handed to ``after_update(params, dict) -> params``
     once the optimizer has stepped: the place for what a model updates by
-    a rule of its own and not by a gradient.
+    a rule of its own and not by a gradient.  The dict carries an
+    auxiliary loss under ``AUX_LOSS_KEY``: already weighed by the model,
+    it joins the TD loss as it is (``aux_weight`` is the scalar form's).
     ``target_window_apply``, when given, evaluates
     the target-network pass — MoE passes a q-only apply here so the
     frozen pass skips the mutable sow collection whose aux value is
@@ -365,6 +373,8 @@ def build_dtqn_train_step(
             loss, seq_pr = _masked_loss_and_priority(
                 q_sel, target, m_tm, batch.weight, priority_eta)
             loss = loss + aux_weight * aux
+            if AUX_LOSS_KEY in stats:
+                loss = loss + stats[AUX_LOSS_KEY]
             return loss, (seq_pr, jnp.mean(jnp.max(q_tm, axis=-1)), aux,
                           stats)
 

@@ -66,13 +66,18 @@ SCOPE_UNROLL = "unroll"
 # cut the same device time as the two phases another way
 SCOPE_EMBED = "model.embed"
 SCOPE_SSM = "model.ssm"
+SCOPE_GDN = "model.gdn"               # gated delta rule; holds the one below
+SCOPE_GDN_CHUNK = "gdn.chunk"         # the recurrence proper: decay, the
+#                                       triangular inverse, the chunk
+#                                       products, the scan over chunk states
 SCOPE_ATTN = "model.attn"
 SCOPE_MOE = "model.moe"               # holds the three below
 SCOPE_MOE_ROUTE = "moe.route"
 SCOPE_MOE_EXPERTS = "moe.experts"
 SCOPE_MOE_SHARED = "moe.shared"
 SCOPE_HEAD = "model.head"
-MODEL_SCOPES = (SCOPE_EMBED, SCOPE_SSM, SCOPE_ATTN, SCOPE_MOE, SCOPE_HEAD)
+MODEL_SCOPES = (SCOPE_EMBED, SCOPE_SSM, SCOPE_GDN, SCOPE_ATTN, SCOPE_MOE,
+                SCOPE_HEAD)
 
 
 @functools.lru_cache(maxsize=None)

@@ -40,8 +40,9 @@ def model_hyper(c=TINY, **changed):
         norm_eps=c.norm_eps, first_expert=c.first_expert), **changed)
 
 
-def build(pattern=TINY.pattern, window=17, dtype=jnp.float32, seed=0, **kw):
-    c = dataclasses.replace(TINY, pattern=pattern, **kw)
+def build(pattern=TINY.pattern, window=17, dtype=jnp.float32, seed=0,
+          base=TINY, **kw):
+    c = dataclasses.replace(base, pattern=pattern, **kw)
     model = HybridQModel(action_space=6, state_shape=FRAME, window=window,
                          preset=c, norm_val=255.0, compute_dtype=dtype)
     params = model.init(jax.random.PRNGKey(seed), jnp.zeros((1, *FRAME),
@@ -256,8 +257,8 @@ OVERRIDES = dict(hybrid_preset="tiny", batch_size=4, seq_len=15,
                  compute_dtype="float32", steps_per_dispatch=1)
 
 
-def tiny_learner(tmp_path, **extra):
-    opt = build_options(20, seed=3, root_dir=str(tmp_path), refs="t",
+def tiny_learner(tmp_path, row=20, **extra):
+    opt = build_options(row, seed=3, root_dir=str(tmp_path), refs="t",
                         resume="never", visualize=False,
                         **dict(OVERRIDES, **extra))
     spec = factory.probe_env(opt)
@@ -612,10 +613,13 @@ def test_the_models_parts_are_named_inside_checkpoint_and_scan(tmp_path):
     fused = replay.build_fused_step(step, 4, donate=False, steps_per_call=1)
     text = fused.lower(state, replay.state, jax.random.PRNGKey(0),
                        jnp.float32(0.6)).as_text(debug_info=True)
-    for scope in profiling.MODEL_SCOPES + (
+    # model.gdn is the second trunk's: tests/test_gated_delta_update.py
+    for scope in tuple(s for s in profiling.MODEL_SCOPES
+                       if s != profiling.SCOPE_GDN) + (
             profiling.SCOPE_MOE_ROUTE, profiling.SCOPE_MOE_EXPERTS,
             profiling.SCOPE_MOE_SHARED):
         assert scope in text, scope
+    assert profiling.SCOPE_GDN not in text
     lines = [ln for ln in text.splitlines() if "loc(" in ln]
     for scope in (profiling.SCOPE_SSM, profiling.SCOPE_ATTN,
                   profiling.SCOPE_MOE):

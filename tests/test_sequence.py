@@ -636,3 +636,85 @@ class TestFramePacking:
                             float(pr[0]))
         assert losses["p"][0] == pytest.approx(losses["u"][0], rel=1e-5)
         assert losses["p"][1] == pytest.approx(losses["u"][1], rel=1e-5)
+
+
+class TestDtqnAuxiliaryLoss:
+    """build_dtqn_train_step's three forms of ``window_apply``: Q alone, (Q,
+    scalar) weighed by ``aux_weight`` (row 17), and (Q, dict) whose
+    ``AUX_LOSS_KEY`` entry joins the loss as the model weighed it (row
+    21)."""
+
+    def _step(self, window_apply, **kw):
+        from pytorch_distributed_tpu.memory.sequence_replay import (
+            SegmentBatch,
+        )
+        from pytorch_distributed_tpu.ops.losses import (
+            init_train_state, make_optimizer,
+        )
+        from pytorch_distributed_tpu.ops.sequence_losses import (
+            build_dtqn_train_step,
+        )
+
+        B, T, A = 3, 6, 4
+        params = {"w": 0.1 * jax.random.normal(jax.random.PRNGKey(0), (5, A))}
+        tx = make_optimizer(lr=1e-3)
+        step = build_dtqn_train_step(
+            window_apply, tx, burn_in=1, nstep=2, target_model_update=100,
+            guard=False, **kw)
+        rng = np.random.default_rng(3)
+        L = T - 1
+        batch = SegmentBatch(
+            obs=rng.normal(size=(B, T, 5)).astype(np.float32),
+            action=rng.integers(0, A, size=(B, L)).astype(np.int32),
+            reward=rng.normal(size=(B, L)).astype(np.float32),
+            terminal=np.zeros((B, L), np.float32),
+            mask=np.ones((B, L), np.float32),
+            c0=np.zeros((B, 1), np.float32), h0=np.zeros((B, 1), np.float32),
+            weight=np.ones(B, np.float32), index=np.arange(B, dtype=np.int32))
+        new, metrics, _ = jax.jit(step)(init_train_state(params, tx), batch)
+        grad = jax.tree_util.tree_map(lambda mu: mu / 0.1,
+                                      new.opt_state[-1][0].mu)
+        return metrics, grad["w"]
+
+    @pytest.mark.parametrize("form", ["scalar", "dict"])
+    def test_the_auxiliary_loss_joins_the_td_loss(self, form):
+        from pytorch_distributed_tpu.ops.sequence_losses import AUX_LOSS_KEY
+
+        q = lambda p, obs: obs @ p["w"]
+        aux = lambda p: jnp.sum(jnp.square(p["w"]))
+        plain, g_plain = self._step(q)
+        if form == "scalar":
+            got, g = self._step(lambda p, obs: (q(p, obs), aux(p)),
+                                aux_weight=0.5)
+            assert float(got["learner/moe_aux"]) == pytest.approx(
+                float(aux({"w": 0.1 * jax.random.normal(
+                    jax.random.PRNGKey(0), (5, 4))})))
+        else:
+            # a stray aux_weight is the scalar form's: the dict's entry
+            # comes weighed
+            got, g = self._step(
+                lambda p, obs: (q(p, obs), {AUX_LOSS_KEY: 0.5 * aux(p),
+                                            "learner/some_counter":
+                                            jnp.float32(7.0),
+                                            "load": jnp.zeros((4,))}),
+                aux_weight=0.0)
+            assert float(got["learner/some_counter"]) == 7.0
+            assert "load" not in got                 # arrays are no metric
+            assert float(got[AUX_LOSS_KEY]) == pytest.approx(
+                float(got["learner/critic_loss"])
+                - float(plain["learner/critic_loss"]), rel=1e-5)
+        w = 0.1 * jax.random.normal(jax.random.PRNGKey(0), (5, 4))
+        assert float(got["learner/critic_loss"]) == pytest.approx(
+            float(plain["learner/critic_loss"]) + 0.5 * float(aux({"w": w})),
+            rel=1e-5)
+        np.testing.assert_allclose(g, g_plain + 0.5 * 2.0 * w, rtol=1e-4,
+                                   atol=1e-6)
+
+    def test_a_dict_without_the_entry_adds_nothing(self):
+        q = lambda p, obs: obs @ p["w"]
+        plain, g_plain = self._step(q)
+        got, g = self._step(lambda p, obs: (q(p, obs), {
+            "learner/some_counter": jnp.float32(1.0)}))
+        assert float(got["learner/critic_loss"]) == float(
+            plain["learner/critic_loss"])
+        np.testing.assert_array_equal(g, g_plain)
