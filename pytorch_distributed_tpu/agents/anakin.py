@@ -550,6 +550,8 @@ class AnakinDriver:
                     actor_loss=vals.get("learner/actor_loss", 0.0),
                     q_mean=vals.get("learner/q_mean", 0.0),
                     grad_norm=vals.get("learner/grad_norm", 0.0),
+                    exchange_rounds=vals.get("learner/exchange_rounds",
+                                             0.0),
                     steps_per_sec=(self.lstep - last_stats_lstep)
                     / max(now - t_cadence, 1e-9),
                 )

@@ -139,8 +139,12 @@ class LearnerStats(_Accumulator):
     MOE_FIELDS = ("moe_rows_here", "moe_rows_absent_share",
                   "moe_load_max_over_mean", "moe_rows_computed",
                   "moe_aux_loss", "gdn_decay_mean")
+    # ``exchange_rounds``: the step metric ``learner/exchange_rounds`` of a
+    # learner whose ring is row-sharded over a mesh (memory/device_replay.py
+    # exchange_rounds, at least 1 there); 0 = not reported, and the logger
+    # writes no row
     FIELDS = ("counter", "critic_loss", "actor_loss", "q_mean", "grad_norm",
-              "steps_per_sec", "moe_aux", *MOE_FIELDS)
+              "steps_per_sec", "moe_aux", *MOE_FIELDS, "exchange_rounds")
 
 
 class EvaluatorStats:

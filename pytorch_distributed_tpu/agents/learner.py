@@ -738,6 +738,8 @@ def run_learner(opt: Options, spec: EnvSpec, process_ind: int, memory: Any,
                     moe_aux=vals.get("learner/moe_aux", 0.0),
                     **{k: vals.get(f"learner/{k}", 0.0)
                        for k in stats.MOE_FIELDS},
+                    exchange_rounds=vals.get(health.EXCHANGE_ROUNDS_KEY,
+                                             0.0),
                     steps_per_sec=(lstep - last_stats_lstep)
                     / max(now - t_cadence, 1e-9),
                 )

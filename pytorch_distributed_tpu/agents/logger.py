@@ -89,6 +89,10 @@ def run_logger(opt: Options, clock: GlobalClock, actor_stats: ActorStats,
                         "learner/moe_aux": le["moe_aux"] / le["counter"],
                         **{f"learner/{k}": le[k] / le["counter"]
                            for k in learner_stats.MOE_FIELDS},
+                        # a mesh learner's row exchange only (>= 1 there)
+                        **({"learner/exchange_rounds":
+                            le["exchange_rounds"] / le["counter"]}
+                           if le["exchange_rounds"] else {}),
                     }, step=step)
                 writer.flush()
 

@@ -28,7 +28,7 @@ import numpy as np
 
 from pytorch_distributed_tpu.memory.device_replay import (
     DeviceReplay, RowCodec, gather_rows, group_step_on, jit_feed,
-    ring_write, ring_write_masked, round_capacity,
+    ring_write, ring_write_masked, round_capacity, with_exchange_rounds,
 )
 from pytorch_distributed_tpu.utils.experience import (
     REPLAY_FIELDS, Batch, Transition,
@@ -91,9 +91,10 @@ def per_write_masked(state: PerReplayState, chunk: Transition, valid,
                                               mode="drop")), total
 
 
-def per_sample(state: PerReplayState, key: jax.Array, batch_size: int,
-               beta: jax.Array, sample_fn=None) -> Batch:
-    """Proportional sample + IS weights, all on device.
+def per_draw(state: PerReplayState, key: jax.Array, batch_size: int,
+             beta: jax.Array, sample_fn=None):
+    """Proportional draw + IS weights, all on device: ``(idx, weights)``,
+    replicated on a mesh (the draw is over the whole ring).
 
     ``sample_fn(priority, key, batch_size) -> (idx, probs)`` overrides the
     index draw — the hook the Pallas hierarchical sampler
@@ -119,7 +120,14 @@ def per_sample(state: PerReplayState, key: jax.Array, batch_size: int,
                  / jnp.maximum(total, 1e-12))
         max_w = (fill * jnp.maximum(min_p, 1e-12)) ** (-beta)
         weights = (weights / jnp.maximum(max_w, 1e-12)).astype(jnp.float32)
-    return gather_rows(state, idx, weights)
+    return idx, weights
+
+
+def per_sample(state: PerReplayState, key: jax.Array, batch_size: int,
+               beta: jax.Array, sample_fn=None) -> Batch:
+    """The rows of a ``per_draw`` as a ``Batch``."""
+    return gather_rows(state, *per_draw(state, key, batch_size, beta,
+                                        sample_fn))
 
 
 PRIORITY_XRAY_LOG10_LO = -6.0   # log10 bucket floor (p^alpha units)
@@ -294,6 +302,14 @@ class DevicePerReplay(DeviceReplay):
         def with_leaves(rs: PerReplayState, pri) -> PerReplayState:
             return rs._replace(priority=pri[0], max_priority=pri[1])
 
+        def sample(rs: PerReplayState, key, beta):
+            """``(Batch, idx)``: the batch, and the draw as it was before
+            the batch took it (replicated on a mesh: what
+            ``learner/exchange_rounds`` is counted from at no cost)."""
+            idx, weight = per_draw(rs, key, batch_size, beta,
+                                   sample_fn=draw_fn)
+            return gather_rows(rs, idx, weight), idx
+
         def writeback(rs: PerReplayState, idx, td_abs, skipped):
             """The leaves after one minibatch's |TD| write-back; a
             skipped (non-finite, guarded) minibatch must not scatter its
@@ -316,9 +332,8 @@ class DevicePerReplay(DeviceReplay):
                 # (vmap's own transposes): gather's, where no inner
                 # name says draw
                 with jax.named_scope(PHASE_GATHER):
-                    batches = jax.vmap(
-                        lambda k: per_sample(rs, k, batch_size, beta,
-                                             sample_fn=draw_fn))(kset)
+                    batches, idx = jax.vmap(
+                        lambda k: sample(rs, k, beta))(kset)
                 ts, metrics, td_abs, ok = group_step_on(
                     rs, megabatch_step)(ts, batches)
 
@@ -332,7 +347,7 @@ class DevicePerReplay(DeviceReplay):
                 with jax.named_scope(PHASE_WRITEBACK):
                     pri, _ = jax.lax.scan(land, leaves(rs),
                                           (batches.index, td_abs, ok))
-                return ts, pri, metrics
+                return ts, pri, with_exchange_rounds(metrics, rs, idx)
 
             def multi_mega(ts, rs, keys, beta):
                 with jax.named_scope(PHASE_DRAW):
@@ -353,13 +368,13 @@ class DevicePerReplay(DeviceReplay):
                            donate_argnums=(0, 1) if donate else ())
 
         def substep(ts, rs: PerReplayState, key, beta):
-            batch = per_sample(rs, key, batch_size, beta, sample_fn=draw_fn)
+            batch, idx = sample(rs, key, beta)
             ts, metrics, td_abs = train_step(ts, batch)
             with jax.named_scope(PHASE_WRITEBACK):
                 pri = writeback(rs, batch.index, td_abs,
                                 metrics.get(SKIPPED_KEY)
                                 if isinstance(metrics, dict) else None)
-            return ts, pri, metrics
+            return ts, pri, with_exchange_rounds(metrics, rs, idx)
 
         if steps_per_call <= 1:
             def one(ts, rs, key, beta):

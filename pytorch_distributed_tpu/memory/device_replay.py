@@ -32,6 +32,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import NamedTuple, Optional, Tuple
 
 import jax
@@ -264,15 +265,21 @@ def round_capacity(capacity: int, mesh: Optional[jax.sharding.Mesh],
     return capacity
 
 
-def sample_rows(state: ReplayState, key: jax.Array,
-                batch_size: int) -> Batch:
-    """Uniform on-device sampling from the ring — public so the learner and
-    the driver dryrun can fuse it into their train-step programs."""
+def draw_rows(state: ReplayState, key: jax.Array, batch_size: int):
+    """Uniform draw over the ring's valid rows: ``(idx, weight)``,
+    replicated on a mesh."""
     with jax.named_scope(PHASE_DRAW):
         idx = jax.random.randint(key, (batch_size,), 0,
                                  jnp.maximum(state.fill, 1), jnp.int32)
         weight = jnp.ones((batch_size,), dtype=jnp.float32)
-    return gather_rows(state, idx, weight)
+    return idx, weight
+
+
+def sample_rows(state: ReplayState, key: jax.Array,
+                batch_size: int) -> Batch:
+    """Uniform on-device sampling from the ring — public so the learner and
+    the driver dryrun can fuse it into their train-step programs."""
+    return gather_rows(state, *draw_rows(state, key, batch_size))
 
 
 def gather_rows(state, idx: jax.Array, weight: jax.Array) -> Batch:
@@ -282,8 +289,9 @@ def gather_rows(state, idx: jax.Array, weight: jax.Array) -> Batch:
     the batch only (RowCodec).
 
     On a row-sharded ring (``codec.rows``) the batch comes out sharded the
-    same way, ``index`` and ``weight`` included: chip ``c`` holds rows
-    ``[c*B/dp, (c+1)*B/dp)`` of every leaf, unpacks and trains those, and
+    same way, ``index`` and ``weight`` included, in the draw's order: chip
+    ``c`` holds rows ``[c*B/dp, (c+1)*B/dp)`` of every leaf
+    (``_exchange_rows`` brings them), unpacks and trains those, and
     the compiler partitions the train step behind it (batch-parallel
     forward and backward, an all-reduce of the gradients).  The DRAW is
     not touched: ``idx`` arrives replicated, drawn over the whole ring."""
@@ -306,19 +314,88 @@ def gather_rows(state, idx: jax.Array, weight: jax.Array) -> Batch:
         )
 
 
+def exchange_bound(share: int, ndev: int) -> int:
+    """Rows a chip sends one other chip in one round of ``_exchange_rows``:
+    a static function of the batch's split, never an option.  Of the
+    ``share`` slots of a chip's block a level draw finds Binomial(share,
+    1/ndev) in any one shard; the bound is 6.5 deviations over that mean,
+    up to a multiple of 8 (whole sublanes), at most ``share`` (where one
+    round carries any draw).  (128, 4) -> 64: a second round is a 1e-10
+    event under a level draw, and half the words of a whole block move."""
+    p = 1.0 / ndev
+    need = share * p + 6.5 * math.sqrt(share * p * (1.0 - p))
+    return min(share, -(-math.ceil(need) // 8) * 8)
+
+
+def _exchange_plan(idx: jax.Array, n: int, ndev: int):
+    """What every chip works out alike from a replicated draw, with no
+    communication: by destination block ``(ndev, share)``, the chip that
+    owns each slot's row (shards of ``n`` rows) and the slot's rank among
+    the EARLIER slots of its block with that owner (its place in what the
+    owner sends the block); and the rounds of ``exchange_bound`` rows the
+    fullest (owner, block) pair needs."""
+    share = idx.shape[-1] // ndev
+    owner = (idx // n).reshape(*idx.shape[:-1], ndev, share)
+    earlier = jnp.tril(jnp.ones((share, share), bool), -1)
+    rank = jnp.sum((owner[..., :, None] == owner[..., None, :]) & earlier,
+                   axis=-1, dtype=jnp.int32)
+    bound = exchange_bound(share, ndev)
+    rounds = (jnp.max(rank, axis=(-2, -1)) + bound) // bound
+    return owner, rank, rounds
+
+
+def exchange_rounds(state, idx: jax.Array):
+    """The step metric ``learner/exchange_rounds`` of a draw ``idx`` (as
+    ``gather_rows`` was handed it; a leading group axis counts as one
+    draw, as the vmapped loop runs to its largest): the rounds
+    ``_exchange_rows`` ran to bring it out of ring ``state``.  1 = the
+    draw fit one bounded exchange; under PER new rows enter at the cursor,
+    in ONE shard, at the running max priority, and a draw that leans on
+    them takes more.  None on a ring that is not row-sharded: there is no
+    exchange."""
+    rows = state.codec.rows
+    if rows is None:
+        return None
+    ndev = rows.mesh.shape[rows.spec[0]]
+    with jax.named_scope(PHASE_GATHER):
+        rounds = _exchange_plan(idx, state.reward.shape[0] // ndev, ndev)[2]
+        return jnp.max(rounds).astype(jnp.float32)
+
+
+def with_exchange_rounds(metrics, state, idx: jax.Array):
+    """A train step's ``metrics`` with ``learner/exchange_rounds`` of the
+    batch it trained on, as the fused builders report it; unchanged on a
+    ring with no exchange."""
+    from pytorch_distributed_tpu.utils.health import EXCHANGE_ROUNDS_KEY
+
+    rounds = exchange_rounds(state, idx)
+    if rounds is None or not isinstance(metrics, dict):
+        return metrics
+    return {**metrics, EXCHANGE_ROUNDS_KEY: rounds}
+
+
 def _exchange_rows(cols, idx: jax.Array, weight: jax.Array, rows):
     """Rows ``idx`` (replicated, global) of columns sharded by ``rows``,
     with ``idx`` and ``weight`` themselves, as a batch sharded the same
     way: ``(cols, idx, weight)``.  Written out and not left to the
     partitioner, which answers a gather from a sharded operand with a
     masked local gather and an ALL-REDUCE that leaves the whole batch on
-    every chip (PERF.md PR 30).  Each chip gathers the drawn rows it owns
-    (zeros elsewhere) and sends block ``c`` of them to chip ``c``; a chip
-    sums the ``dp`` blocks it receives, of which one holds each row, so
-    the sum is exact for every dtype.  That is a reduce-scatter spelled
-    as an ``all_to_all``, because the TPU compiler has no integer
-    reduce-scatter: ``psum_scatter`` of the packed words compiles to the
-    all-reduce again, with a slice behind it."""
+    every chip (PERF.md PR 30).
+
+    Only rows a chip owns travel, compacted (PR 32).  In round ``r`` chip
+    ``c`` gathers, for each block ``d``, its rows of ranks ``[r*bound,
+    (r+1)*bound)`` (``_exchange_plan``) from its shard, ``(ndev, bound)``
+    rows in all, and an ``all_to_all`` hands chip ``d`` its part; slot
+    ``j`` of a chip's block then takes row ``rank[j] - r*bound`` of what
+    ``owner[j]`` sent.  Positions no slot asked for carry some row of the
+    sender and are never read.  The rounds a draw needs follow from
+    ``idx`` alone, so every chip runs the same number: one for any level
+    draw (``exchange_bound``), ``share / bound`` when a block's rows all
+    sit in one shard.  The first stands straight-line and the others, the
+    same code, in a loop: a level draw then pays for no select and no
+    zero-filled buffer, and a bound that clamps to ``share`` for no loop
+    (with every round in the loop the chip ran the same 0.76 ms an update,
+    PERF.md PR 32)."""
     axis = rows.spec[0]
     ndev = rows.mesh.shape[axis]
     if idx.shape[0] % ndev:
@@ -327,25 +404,44 @@ def _exchange_rows(cols, idx: jax.Array, weight: jax.Array, rows):
             f"{axis}={ndev}: on a row-sharded ring every chip takes an "
             f"equal share of the batch")
     share = idx.shape[0] // ndev
+    bound = exchange_bound(share, ndev)
 
     def exchange(cols, idx, weight):
         chip = jax.lax.axis_index(axis)
         n = cols["reward"].shape[0]             # rows this chip holds
-        local = idx - chip * n
-        own = (local >= 0) & (local < n)
-        local = jnp.clip(local, 0, n - 1)
+        owner, rank, rounds = _exchange_plan(idx, n, ndev)
+        local = (idx % n).reshape(ndev, share)
+        place = jnp.arange(bound, dtype=jnp.int32)[None, :, None]
 
-        def one(col):
-            got = col[local]
-            mask = own.reshape(-1, *(1,) * (got.ndim - 1))
-            got = jax.lax.all_to_all(
-                jnp.where(mask, got, jnp.zeros((), got.dtype)), axis,
-                split_axis=0, concat_axis=0, tiled=True)
-            return got.reshape(ndev, share, *got.shape[1:]).sum(
-                axis=0, dtype=got.dtype)
+        def one_round(r, out):
+            at = rank - r * bound               # place in this round's part
+            # what this chip sends block d at place p: its row that the
+            # block's slot of rank r*bound + p drew (at most one slot)
+            send = jnp.sum(jnp.where(
+                (owner == chip)[:, None, :] & (at[:, None, :] == place),
+                local[:, None, :], 0), axis=-1).reshape(-1)
+            here = (at[chip] >= 0) & (at[chip] < bound)
+            take = owner[chip] * bound + jnp.clip(at[chip], 0, bound - 1)
 
+            def one(col, had):
+                got = jax.lax.all_to_all(col[send], axis, split_axis=0,
+                                         concat_axis=0, tiled=True)[take]
+                if had is None:     # later rounds rewrite what is not here
+                    return got
+                return jnp.where(
+                    here.reshape(-1, *(1,) * (got.ndim - 1)), got, had)
+
+            return {k: one(col, None if out is None else out[k])
+                    for k, col in cols.items()}
+
+        out = one_round(0, None)
+        if bound < share:           # else one round carries any draw
+            _, out = jax.lax.while_loop(
+                lambda c: c[0] < rounds,
+                lambda c: (c[0] + 1, one_round(c[0], c[1])),
+                (jnp.int32(1), out))
         mine = lambda x: jax.lax.dynamic_slice_in_dim(x, chip * share, share)
-        return jax.tree_util.tree_map(one, cols), mine(idx), mine(weight)
+        return out, mine(idx), mine(weight)
 
     P = jax.sharding.PartitionSpec
     return jax.shard_map(
@@ -366,11 +462,12 @@ def group_step_on(state, megabatch_step):
     ops/losses.py's megabatch builders return; this is its one caller):
     train state in and out replicated, |TD| sharded like the batch.
 
-    Where it stands (PR 30): held on the virtual CPU mesh by
+    Where it stands: held on the virtual CPU mesh by
     tests/test_device_per.py (compiled HLO; every metric and the
     parameters against one device) and tests/test_megabatch.py, and
     compiled at dp4's real size for a described v5e:2x2; no benchmark cell
-    runs a megabatch on a mesh, so it has never RUN on a chip."""
+    runs a megabatch on a mesh: it has run on the four chips once, by a
+    builder's hand (PERF.md section 6, PR 32)."""
     rows = state.codec.rows
     if rows is None:
         return megabatch_step
@@ -424,6 +521,12 @@ def build_uniform_fused_step(step_fn, batch_size: int,
     """
     from pytorch_distributed_tpu.utils.health import reduce_scan_metrics
 
+    def sample(ring_state, key):
+        """``(Batch, idx)``: the batch and the draw as it was before the
+        batch took it (replicated on a mesh)."""
+        idx, weight = draw_rows(ring_state, key, batch_size)
+        return gather_rows(ring_state, idx, weight), idx
+
     if megabatch > 1:
         assert megabatch_step is not None, \
             "megabatch > 1 needs the factory's megabatch step"
@@ -438,12 +541,11 @@ def build_uniform_fused_step(step_fn, batch_size: int,
 
             def one_group(ts, kset):
                 with jax.named_scope(PHASE_GATHER):  # vmap's transposes
-                    batches = jax.vmap(
-                        lambda k: sample_rows(ring_state, k,
-                                              batch_size))(kset)
+                    batches, idx = jax.vmap(
+                        lambda k: sample(ring_state, k))(kset)
                 ts, metrics, _td, _ok = group_step_on(
                     ring_state, megabatch_step)(ts, batches)
-                return ts, metrics
+                return ts, with_exchange_rounds(metrics, ring_state, idx)
 
             ts, metrics = jax.lax.scan(one_group, ts, gkeys)
             return ts, reduce_scan_metrics(metrics)
@@ -452,14 +554,15 @@ def build_uniform_fused_step(step_fn, batch_size: int,
 
     def multi(ts, ring_state, keys):
         def one(ts, key):
-            ts, metrics, _td = step_fn(ts, sample_rows(ring_state, key,
-                                                       batch_size))
-            return ts, metrics
+            batch, idx = sample(ring_state, key)
+            ts, metrics, _td = step_fn(ts, batch)
+            return ts, with_exchange_rounds(metrics, ring_state, idx)
 
         ts, metrics = jax.lax.scan(one, ts, keys)
         # last substep's metrics stand in for the dispatch, EXCEPT the
-        # guard's skip counter, which sums over the scan
-        # (utils/health.py reduce_scan_metrics)
+        # guard's skip counter, which sums over the scan, and the
+        # exchange's rounds, their mean (utils/health.py
+        # reduce_scan_metrics)
         return ts, reduce_scan_metrics(metrics)
 
     return jax.jit(multi, donate_argnums=(0,) if donate else ())

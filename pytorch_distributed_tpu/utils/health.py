@@ -62,6 +62,11 @@ from pytorch_distributed_tpu.utils.profiling import PHASE_OPTIMIZER
 # (non-finite) substep, 0.0 otherwise; summed — not last-sampled — over
 # fused multi-step dispatches (reduce_scan_metrics)
 SKIPPED_KEY = "learner/skipped"
+# rounds the row exchange out of a dp-sharded ring ran for a substep's
+# draw (memory/device_replay.py exchange_rounds; absent on one device);
+# a dispatch reports the MEAN over its K substeps: 1.0 = every update fit
+# one bounded exchange
+EXCHANGE_ROUNDS_KEY = "learner/exchange_rounds"
 
 _ENV_PREFIX = "TPU_APEX_HEALTH_"
 
@@ -140,17 +145,19 @@ def finite_guard(step_fn):
 def reduce_scan_metrics(metrics):
     """Collapse a scanned fused dispatch's stacked substep metrics to one
     row: the last substep's value per key — the sampling contract the
-    learner's stats cadence already has — EXCEPT counter-like keys
-    (``learner/skipped``), which sum over the scan so a dispatch reports
-    how many of its K substeps were skipped, not just whether the last
-    one was."""
+    learner's stats cadence already has — EXCEPT counter-like keys:
+    ``learner/skipped`` sums over the scan, so a dispatch reports how
+    many of its K substeps were skipped, not just whether the last one
+    was, and ``learner/exchange_rounds`` is their mean (one skewed draw
+    in K must show)."""
     import jax
     import jax.numpy as jnp
 
+    over_scan = {SKIPPED_KEY: jnp.sum, EXCHANGE_ROUNDS_KEY: jnp.mean}
     with jax.named_scope(PHASE_OPTIMIZER):
         if not isinstance(metrics, dict):
             return jax.tree_util.tree_map(lambda x: x[-1], metrics)
-        return {k: (jnp.sum(v, axis=0) if k == SKIPPED_KEY else v[-1])
+        return {k: (over_scan[k](v, axis=0) if k in over_scan else v[-1])
                 for k, v in metrics.items()}
 
 

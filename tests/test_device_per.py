@@ -346,21 +346,24 @@ def _hlo_shapes(text):
                 r"%([\w.\-]+) = \w+\[([\d,]*)\]", text)}
 
 
-@pytest.mark.parametrize("name,K,megabatch", FUSED_PROGRAMS)
+# B = 24: 6 rows a chip, which the exchange's bound clamps to; B = 160: 40
+# rows a chip under a bound of 32.  Neither, nor twice it, is another dim
+@pytest.mark.parametrize("name,K,megabatch,B", [
+    *((*p, 24) for p in FUSED_PROGRAMS), ("multi", 4, 1, 160)])
 def test_fused_step_of_a_sharded_ring_is_partitioned_over_dp(
-        name, K, megabatch):
+        name, K, megabatch, B):
     """From the compiled program: every convolution runs on a chip's share
     of the batch, the gradients are reduced across the chips, and the
-    gathered rows reach a chip by an all-to-all, never as a whole batch
-    behind an all-reduce."""
+    gathered rows reach a chip by an all-to-all of ``exchange_bound`` rows
+    a block, never as a whole batch behind an all-reduce."""
     import re
 
+    from pytorch_distributed_tpu.memory.device_replay import exchange_bound
     from pytorch_distributed_tpu.models import DqnCnnModel
 
-    B = 24                      # 6 rows a chip; 24, 48 are no other dim
     mesh = _dp_mesh()
     m = _pixel_ring(mesh, 64, (4, 84, 84))
-    assert m.batch_rows(B) == "6x4dp"
+    assert m.batch_rows(B) == f"{B // DP}x4dp"
     ts, step, mega = _dqn_steps(
         DqnCnnModel(action_space=6), jnp.zeros((1, 4, 84, 84), jnp.uint8))
     compiled, (ts2, rs2, metrics) = _run_fused(m, ts, step, mega, B, K,
@@ -393,8 +396,16 @@ def test_fused_step_of_a_sharded_ring_is_partitioned_over_dp(
                                line.split(" all-reduce(")[0]):
             dims = {int(d) for d in dims.split(",")}
             assert not (words in dims and dims & whole), line
-    assert any(str(words) in l for l in text.splitlines()
-               if " all-to-all(" in l)
+    # the exchange of the two observation columns: ``bound`` rows to each
+    # chip (a whole block only where the bound clamps to it); where a draw
+    # can need more rounds, the same exchange once more, in their loop
+    share, bound = B // DP, exchange_bound(B // DP, DP)
+    assert bound == {24: 6, 160: 32}[B]
+    sent = [re.findall(rf"u32\[(?:\d+,)*(\d+),{words}\]",
+                       l.split(" all-to-all(")[0])
+            for l in text.splitlines() if " all-to-all(" in l]
+    sent = [{int(rows) for rows in found} for found in sent if found]
+    assert sent == [{bound}] * (2 if bound == share else 4), sent
 
 
 @pytest.mark.parametrize("name,K,megabatch", FUSED_PROGRAMS)
@@ -438,7 +449,11 @@ def test_fused_step_of_a_sharded_ring_draws_and_trains_as_one_device(
     np.testing.assert_allclose(a["priority"], b["priority"], rtol=1e-5)
     np.testing.assert_allclose(a["max_priority"], b["max_priority"],
                                rtol=1e-5)
-    assert a["metrics"].keys() == b["metrics"].keys()
+    # one device runs no exchange: the mesh reports its rounds on top
+    assert b["metrics"].keys() - a["metrics"].keys() == {
+        "learner/exchange_rounds"}
+    assert not a["metrics"].keys() - b["metrics"].keys()
+    assert b["metrics"]["learner/exchange_rounds"] == 1.0   # 4 rows a chip
     assert "learner/critic_loss" in a["metrics"]
     for k in a["metrics"]:
         np.testing.assert_allclose(a["metrics"][k], b["metrics"][k],
@@ -446,6 +461,95 @@ def test_fused_step_of_a_sharded_ring_draws_and_trains_as_one_device(
     jax.tree_util.tree_map(
         lambda x, y: np.testing.assert_allclose(x, y, rtol=1e-4, atol=1e-6),
         a["params"], b["params"])
+
+
+def _draw(name, rng, B, capacity):
+    """A draw of ``B`` row numbers from a ring of ``capacity`` rows over
+    ``DP`` shards, by the shape the exchange has to cope with."""
+    n, share = capacity // DP, B // DP
+    level = rng.integers(0, capacity, B)
+    return {
+        "level": level,
+        "first_shard": rng.integers(0, n, B),
+        "last_shard": rng.integers(capacity - n, capacity, B),
+        "one_row": np.full(B, capacity // 3),
+        # chip 1's whole block sits in chip 2's shard
+        "remote_block": np.concatenate([
+            level[:share], rng.integers(2 * n, 3 * n, share),
+            level[2 * share:]]),
+    }[name].astype(np.int32)
+
+
+# (draw, B, rounds): B = 128 is 32 rows a chip under a bound of 24, so a
+# block that sits in one shard takes two rounds; B = 24 is 6 rows a chip,
+# which the bound clamps to: one round carries any draw
+@pytest.mark.parametrize("draw,B,rounds", [
+    ("level", 128, 1), ("first_shard", 128, 2), ("last_shard", 128, 2),
+    ("one_row", 128, 2), ("remote_block", 128, 2), ("first_shard", 24, 1)])
+def test_gather_rows_of_a_sharded_ring_is_the_one_device_gather(
+        draw, B, rounds):
+    """``gather_rows`` on the mesh is ``col[idx]`` bit for bit, every
+    column, index and weight included, however the draw leans on one
+    shard; ``exchange_rounds`` is the rounds it took, and nothing on one
+    device."""
+    from pytorch_distributed_tpu.memory.device_replay import (
+        exchange_bound, exchange_rounds, gather_rows,
+    )
+
+    capacity, shape = 256, (4, 6, 6)
+    assert exchange_bound(B // DP, DP) == {128: 24, 24: 6}[B]
+    rng = np.random.default_rng(5)
+    idx = jnp.asarray(_draw(draw, rng, B, capacity))
+    weight = jnp.asarray(rng.random(B), jnp.float32)
+    one, mesh = (_pixel_ring(mesh, capacity, shape)
+                 for mesh in (None, _dp_mesh()))
+    want = jax.jit(gather_rows)(one.state, idx, weight)
+    got = jax.jit(gather_rows)(mesh.state, idx, weight)
+    for f, x, y in zip(want._fields, want, got):
+        assert y.sharding.spec[0] == "dp", f
+        assert x.dtype == y.dtype, f
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y),
+                                      err_msg=f)
+    np.testing.assert_array_equal(np.asarray(got.index), np.asarray(idx))
+    assert exchange_rounds(one.state, idx) is None
+    assert float(jax.jit(exchange_rounds)(mesh.state, idx)) == rounds
+
+
+def test_exchange_bound_is_a_function_of_the_split_alone():
+    """6.5 deviations over a level draw's mean, in whole sublanes, never
+    more than a chip's share; the cell's (128, 4) sends 64 rows a block."""
+    from pytorch_distributed_tpu.memory.device_replay import exchange_bound
+
+    assert exchange_bound(128, 4) == 64
+    assert exchange_bound(32, 4) == 24
+    assert exchange_bound(6, 4) == 6 and exchange_bound(16, 1) == 16
+    for share, ndev in [(8, 2), (64, 4), (128, 8), (512, 4), (4096, 16)]:
+        b = exchange_bound(share, ndev)
+        assert share / ndev < b <= share and b % 8 == 0, (share, ndev, b)
+
+
+def test_fused_dispatch_reports_the_mean_rounds_of_its_substeps(monkeypatch):
+    """K = 4 substeps on the mesh, two of them drawn from one shard: the
+    dispatch's ``learner/exchange_rounds`` is the mean over the scan, not
+    the last substep's."""
+    from pytorch_distributed_tpu.memory import device_per
+
+    B, K, capacity = 128, 4, 256
+    m = _pixel_ring(_dp_mesh(), capacity, (4, 12, 12))
+    lean = jnp.asarray([0, 1, 1, 0], jnp.uint32)    # substeps 1, 2: shard 0
+    real_draw = device_per.per_draw
+
+    def leaning_draw(state, key, batch_size, beta, sample_fn=None):
+        idx, w = real_draw(state, key[1:], batch_size, beta, sample_fn)
+        return jnp.where(key[0] > 0, idx % (capacity // DP), idx), w
+
+    monkeypatch.setattr(device_per, "per_draw", leaning_draw)
+    fused = m.build_fused_step(_toy_step, B, donate=False, steps_per_call=K)
+    keys = jnp.concatenate(
+        [lean[:, None], jax.random.split(jax.random.PRNGKey(0), K)], axis=1)
+    _, _, metrics = jax.block_until_ready(
+        fused(jnp.float32(0), m.state, keys, jnp.float32(0.4)))
+    assert float(metrics["learner/exchange_rounds"]) == 1.5
 
 
 def test_a_batch_that_does_not_split_over_the_mesh_is_refused():

@@ -102,12 +102,20 @@ def test_device_ingest_chunks_and_feeds():
     assert np.all(np.asarray(b.index) < 4)
 
 
-def test_multi_step_dispatch_topology(tmp_path):
+@pytest.mark.parametrize("devices", [8, 1])
+def test_multi_step_dispatch_topology(tmp_path, monkeypatch, devices):
     """steps_per_dispatch > 1: K scanned updates per dispatched program;
-    clocks/cadences still line up."""
+    clocks/cadences still line up.  With more than one device visible the
+    learner builds a mesh and shards the ring's rows over it: that run's
+    ``scalars.jsonl`` carries the row exchange's ``learner/exchange_rounds``
+    (1: two rows a chip, one round carries any draw); a one-device run has
+    no exchange and writes no such row."""
     from pytorch_distributed_tpu import runtime
     from pytorch_distributed_tpu.config import build_options
+    from pytorch_distributed_tpu.utils.metrics import read_scalars
 
+    seen = jax.devices()[:devices]
+    monkeypatch.setattr(jax, "devices", lambda *a, **kw: seen)
     opt = build_options(
         1, memory_type="device", root_dir=str(tmp_path), num_actors=1,
         steps=60, learn_start=16, batch_size=16, memory_size=1024,
@@ -116,10 +124,12 @@ def test_multi_step_dispatch_topology(tmp_path):
         visualize=False)
     topo = runtime.train(opt, backend="thread")
     assert topo.clock.learner_step.value >= 60
-    from pytorch_distributed_tpu.utils.metrics import read_scalars
-
-    tags = {r["tag"] for r in read_scalars(opt.log_dir)}
-    assert "learner/critic_loss" in tags
+    rows = read_scalars(opt.log_dir)
+    assert "learner/critic_loss" in {r["tag"] for r in rows}
+    rounds = [r["value"] for r in rows
+              if r["tag"] == "learner/exchange_rounds"]
+    assert rounds == ([] if devices == 1 else [1.0] * len(rounds))
+    assert bool(rounds) == (devices > 1)
 
 
 # ---------------------------------------------------------------------------

@@ -141,6 +141,15 @@ class TestFiniteGuard:
         assert float(out["learner/critic_loss"]) == 3.0
         assert float(out[health.SKIPPED_KEY]) == 2.0
 
+    def test_reduce_scan_metrics_averages_the_exchange_rounds(self):
+        import jax.numpy as jnp
+
+        stacked = {"learner/critic_loss": jnp.asarray([1.0, 2.0, 3.0, 4.0]),
+                   health.EXCHANGE_ROUNDS_KEY: jnp.asarray([1., 2., 2., 1.])}
+        out = health.reduce_scan_metrics(stacked)
+        assert float(out["learner/critic_loss"]) == 4.0
+        assert float(out[health.EXCHANGE_ROUNDS_KEY]) == 1.5
+
     def test_per_writeback_suppressed_on_skip(self):
         """A guarded step that skips must leave the fused PER ring's
         priorities bit-unchanged (its zeroed TD would otherwise crush
