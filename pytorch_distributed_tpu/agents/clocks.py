@@ -138,7 +138,8 @@ class LearnerStats(_Accumulator):
     # every model that does not report it
     MOE_FIELDS = ("moe_rows_here", "moe_rows_absent_share",
                   "moe_load_max_over_mean", "moe_rows_computed",
-                  "moe_aux_loss", "gdn_decay_mean")
+                  "moe_aux_loss", "gdn_decay_mean", "kda_decay_mean",
+                  "kda_decay_min")
     # ``exchange_rounds``: the step metric ``learner/exchange_rounds`` of a
     # learner whose ring is row-sharded over a mesh (memory/device_replay.py
     # exchange_rounds, at least 1 there); 0 = not reported, and the logger

@@ -38,7 +38,31 @@ take both operands in the compute dtype and accumulate in float32.
 Every key head serves ``h_v / h_k`` value heads: value head ``h`` reads key
 head ``h // (h_v / h_k)``.  ``q`` and ``k`` arrive normalised (and ``q``
 scaled); this module holds the recurrence and nothing of the layer around
-it (models/hybrid.py ``gdn_window`` / ``gdn_step``).
+it (models/hybrid.py ``gdn_window`` / ``gdn_step``, ``kda_window`` /
+``kda_step``).
+
+THE CHANNEL-GATED RULE (``kda_chunked``): ``g_t`` a vector over the key
+channels, ``S' = Diag(exp(g_t)) S_{t-1}``, the rest as above.  ``gamma`` is
+then ``(L, d_k)`` and the decay no longer comes out of the dot products:
+
+    A_ij = beta_i sum_d k_id k_jd exp(gamma_id - gamma_jd)        j < i
+    P_ij =        sum_d q_id k_jd exp(gamma_id - gamma_jd)        j <= i
+    W = T (beta * K * exp(gamma)),   U = T (beta * V),   V' = U - W S
+    O = (Q * exp(gamma)) S + P V'
+    S <- Diag(exp(gamma_L)) S + (K * exp(gamma_L - gamma))^T V'
+
+``(K * exp(gamma)) (K * exp(-gamma))^T`` overflows float32 once a channel's
+cumulative decay inside a chunk passes e^88, and an ``(L, L, d_k)`` array of
+differences is gigabytes.  So the chunk is cut into sub-blocks of ``sub``
+positions (``intra_chunk_products``): inside a sub-block the differences are
+taken pair by pair; sub-blocks are then joined in pairs, level by level, and
+where a later block meets an earlier one both factors are taken against
+``gamma`` at the LATER block's first position (``exp(gamma_i - gamma_ref) <=
+1`` and ``exp(gamma_ref - gamma_j) <= 1`` for every earlier ``j``: nothing
+overflows, and a factor that underflows is no larger than the true value).
+The scan over chunk states is the scalar-gated rule's; the inverse is formed
+block by block (``unit_lower_inverse_by_blocks``), because this rule's
+published decays are slow and ``A`` is then no small matrix.
 """
 
 from __future__ import annotations
@@ -47,7 +71,7 @@ import jax
 import jax.numpy as jnp
 
 from pytorch_distributed_tpu.utils.profiling import (
-    SCOPE_GDN, SCOPE_GDN_CHUNK,
+    SCOPE_GDN, SCOPE_GDN_CHUNK, SCOPE_KDA, SCOPE_KDA_CHUNK,
 )
 
 F32 = jnp.float32
@@ -87,6 +111,81 @@ def _inverse_bwd(T, g):
 
 
 unit_lower_inverse.defvjp(_inverse_fwd, _inverse_bwd)
+
+
+def _inverse_by_blocks(A):
+    L = A.shape[-1]
+    lead = A.shape[:-2]
+    mm = lambda a, b: jnp.matmul(a, b, precision=_HIGHEST)
+    T, h = jnp.ones((*lead, L, 1, 1), A.dtype), 1          # (.., b, h, h)
+    while h < L:
+        nb = L // (2 * h)
+        # each 2h x 2h diagonal block's lower left quarter
+        A21 = jnp.einsum("...bicj,bc->...bij",
+                         A.reshape(*lead, nb, 2 * h, nb, 2 * h),
+                         jnp.eye(nb, dtype=A.dtype))[..., h:, :h]
+        T = T.reshape(*lead, nb, 2, h, h)
+        T11, T22 = T[..., 0, :, :], T[..., 1, :, :]
+        T = jnp.concatenate([
+            jnp.concatenate([T11, jnp.zeros_like(T11)], -1),
+            jnp.concatenate([-mm(mm(T22, A21), T11), T22], -1)], -2)
+        h *= 2
+    return T[..., 0, :, :]
+
+
+@jax.custom_vjp
+def unit_lower_inverse_by_blocks(A):
+    """``T = (I + A)^-1`` for strictly lower triangular ``A`` (.., L, L), L a
+    power of two, in float32, block by block: the inverse of a 2h x 2h
+    diagonal block from its two h x h halves' inverses, ``[[T11, 0], [-T22
+    A21 T11, T22]]``, from h = 1 up.  Every intermediate is a true inverse of
+    a block, bounded as ``T`` is.  ``unit_lower_inverse``'s ten products pass
+    through powers of ``A`` up to the 32nd instead, whose entries reach
+    ``C(62, 31) a^32`` where keys resemble each other and the decay is slow
+    (``A_ij`` near a constant ``a``): 1e8 at ``a`` = 0.5, and the inverse,
+    whose entries stay below 1, is lost to cancellation in float32 (seen on
+    the chip and repeated on the CPU, PERF.md section 6, PR 34).  Same
+    cotangent as that one's."""
+    return _inverse_by_blocks(A)
+
+
+unit_lower_inverse_by_blocks.defvjp(
+    lambda A: (_inverse_by_blocks(A),) * 2, _inverse_bwd)
+
+
+def _scan_chunks(W, U, K_end, decay, scopes, cd):
+    """The scan over chunk states, the one part of a window that is
+    sequential: ``V' = U - W S``, ``S <- decay S + K_end^T V'``, a chunk a
+    step, from ``S = 0`` in float32.  W (b, c, g, r, L, d_k) float32 (read
+    in the compute dtype), K_end the same in the compute dtype; U (b, c, g,
+    r, L, d_v) float32; ``decay`` a chunk's whole decay, (b, c, g, r) a head
+    or (b, c, g, r, d_k) a key channel; ``scopes`` the caller's two, entered
+    inside the body.  Returns (the state
+    after the last chunk (b, g, r, d_k, d_v) float32, the state each chunk
+    was entered with and each chunk's V', chunk-major (b, c, ..) in the
+    compute dtype: both are read only through products in it)."""
+    b, _, G, r, _, dk = W.shape
+    dv = U.shape[-1]
+    wide = (...,) + (None,) * (6 - decay.ndim)     # up to (b, g, r, d_k, d_v)
+
+    def chunk_state(S, inp):
+        with jax.named_scope(scopes[0]), jax.named_scope(scopes[1]):
+            W_c, U_c, K_c, dec = inp
+            V_new = U_c - jnp.einsum(
+                "bgrld,bgrde->bgrle", W_c, S.astype(cd),
+                preferred_element_type=F32)
+            V_new = V_new.astype(cd)
+            S_next = dec[wide] * S + jnp.einsum(
+                "bgrld,bgrle->bgrde", K_c, V_new,
+                preferred_element_type=F32)
+        return S_next, (S.astype(cd), V_new)
+
+    cm = lambda t: jnp.moveaxis(t, 1, 0)
+    S_end, (S_prev, V_new) = jax.lax.scan(
+        chunk_state, jnp.zeros((b, G, r, dk, dv), F32),
+        (cm(W.astype(cd)), cm(U), cm(K_end), cm(decay)))
+    S_prev, V_new = (jnp.moveaxis(t, 0, 1) for t in (S_prev, V_new))
+    return S_end, S_prev, V_new
 
 
 def gated_delta_chunked(q, k, v, g, beta, chunk: int, cd=jnp.bfloat16):
@@ -130,25 +229,8 @@ def gated_delta_chunked(q, k, v, g, beta, chunk: int, cd=jnp.bfloat16):
         K_end = (kf * to_end[..., None]).astype(cd)        # (b,c,g,r,L,dk)
         chunk_decay = jnp.exp(gamma[..., -1])              # (b,c,g,r)
 
-        def chunk_state(S, inp):
-            with jax.named_scope(SCOPE_GDN), \
-                    jax.named_scope(SCOPE_GDN_CHUNK):
-                W_c, U_c, K_c, dec = inp
-                V_new = U_c - jnp.einsum(
-                    "bgrld,bgrde->bgrle", W_c, S.astype(cd),
-                    preferred_element_type=F32)
-                V_new = V_new.astype(cd)
-                S_next = dec[..., None, None] * S + jnp.einsum(
-                    "bgrld,bgrle->bgrde", K_c, V_new,
-                    preferred_element_type=F32)
-            # both are read only through products in the compute dtype
-            return S_next, (S.astype(cd), V_new)
-
-        cm = lambda t: jnp.moveaxis(t, 1, 0)
-        S_end, (S_prev, V_new) = jax.lax.scan(
-            chunk_state, jnp.zeros((b, G, r, dk, dv), F32),
-            (cm(W.astype(cd)), cm(U), cm(K_end), cm(chunk_decay)))
-        S_prev, V_new = (jnp.moveaxis(t, 0, 1) for t in (S_prev, V_new))
+        S_end, S_prev, V_new = _scan_chunks(
+            W, U, K_end, chunk_decay, (SCOPE_GDN, SCOPE_GDN_CHUNK), cd)
         q_in = (jnp.moveaxis(qc.astype(F32), 2, 3)[:, :, :, None]
                 * jnp.exp(gamma)[..., None]).astype(cd)    # (b,c,g,r,L,dk)
         o = jnp.einsum("bcgrld,bcgrde->bcgrle", q_in, S_prev,
@@ -159,12 +241,102 @@ def gated_delta_chunked(q, k, v, g, beta, chunk: int, cd=jnp.bfloat16):
         return o, S_end.reshape(b, hv, dk, dv)
 
 
+def intra_chunk_products(q, k, gamma, sub: int, cd):
+    """``sum_d x_id k_jd exp(gamma_id - gamma_jd)`` for ``j <= i`` inside each
+    chunk, for x = k and x = q: q, k, gamma (.., L, d) float32 -> two (.., L,
+    L) float32, zero above the diagonal; ``L = sub * 2^n``.  Inside a block
+    of ``sub`` positions the ``(sub, sub, d)`` differences themselves, in
+    float32 (``sub`` = 1: the diagonal alone).  Then blocks are joined in
+    pairs, level by level up to the chunk: the later block's rows against the
+    earlier block's columns are ONE product of ``x * exp(gamma - ref)`` with
+    ``k * exp(ref - gamma)``, operands in the compute dtype, ``ref`` = gamma
+    at the later block's first position, so that both factors are at most
+    1."""
+    L, d = k.shape[-2:]
+    lead = k.shape[:-2]
+    blocks = lambda t, h: t.reshape(*lead, L // h, h, d)
+    gb = blocks(gamma, sub)
+    causal = jnp.tril(jnp.ones((sub, sub), bool))
+    k_pair = blocks(k, sub)[..., None, :, :] * jnp.exp(jnp.where(
+        causal[..., None], gb[..., :, None, :] - gb[..., None, :, :],
+        -jnp.inf))                                         # (.., b, i, j, d)
+    # k's and q's products side by side, not stacked: stacked, a block's
+    # backward held 3 GB more at the cell's shapes (PERF.md section 6)
+    xs = (k, q)
+    Ms = [jnp.sum(blocks(x, sub)[..., :, None, :] * k_pair, axis=-1)
+          for x in xs]                                     # (.., b, h, h)
+    h = sub
+    while h < L:
+        assert L % (2 * h) == 0, (L, sub)
+        halves = lambda t: t.reshape(*lead, L // (2 * h), 2, h, t.shape[-1])
+        g2 = halves(gamma)
+        ref = g2[..., 1, :1, :]                            # (.., b, 1, d)
+        down = jnp.exp(g2[..., 1, :, :] - ref)
+        k_up = (halves(k)[..., 0, :, :]
+                * jnp.exp(ref - g2[..., 0, :, :])).astype(cd)
+        for n, x in enumerate(xs):
+            below = jnp.einsum(
+                "...id,...jd->...ij",
+                (halves(x)[..., 1, :, :] * down).astype(cd), k_up,
+                preferred_element_type=F32)
+            M = halves(Ms[n])                              # (.., b, 2, h, h)
+            Ms[n] = jnp.concatenate([
+                jnp.concatenate([M[..., 0, :, :], jnp.zeros_like(below)], -1),
+                jnp.concatenate([below, M[..., 1, :, :]], -1)], -2)
+        h *= 2
+    return Ms[0][..., 0, :, :], Ms[1][..., 0, :, :]
+
+
+def kda_chunked(q, k, v, g, beta, chunk: int, sub: int, cd=jnp.bfloat16):
+    """The channel-gated recurrence over a window from a zero state, chunk by
+    chunk.  q, k (b, T, h, d_k); v (b, T, h, d_v); g (b, T, h, d_k) and beta
+    (b, T, h) float32; T a whole number of chunks of ``chunk`` positions,
+    each of whole sub-blocks of ``sub`` (pad with g = beta = 0).  Returns (o
+    (b, T, h, d_v) float32, the state after the last position (b, h, d_k,
+    d_v) float32)."""
+    with jax.named_scope(SCOPE_KDA_CHUNK):
+        b, T, h, dk = k.shape
+        dv = v.shape[-1]
+        L, nc = chunk, T // chunk
+        assert nc * L == T, (T, chunk)
+        heads = lambda t: jnp.moveaxis(                    # (b,c,h,L,.)
+            t.astype(F32).reshape(b, nc, L, h, -1), 2, 3)
+        tril = jnp.tril(jnp.ones((L, L), bool))
+        gamma = jnp.einsum("bchsd,ls->bchld", heads(g), tril.astype(F32),
+                           precision=_HIGHEST)
+        bt = heads(beta)                                   # (b,c,h,L,1)
+        qf, kf, vf = heads(q), heads(k), heads(v)
+        KK, QK = intra_chunk_products(qf, kf, gamma, sub, cd)
+        A = jnp.where(jnp.tril(tril, -1), bt * KK, 0.0)
+        Tm = unit_lower_inverse_by_blocks(A).astype(cd)    # (b,c,h,L,L)
+        in_chunk = jnp.exp(gamma)                          # decay since the
+        #                                                    chunk's start
+        W = jnp.einsum("bchls,bchsd->bchld", Tm,
+                       (kf * (bt * in_chunk)).astype(cd),
+                       preferred_element_type=F32)
+        U = jnp.einsum("bchls,bchsd->bchld", Tm, (vf * bt).astype(cd),
+                       preferred_element_type=F32)
+        K_end = (kf * jnp.exp(gamma[..., -1:, :] - gamma)).astype(cd)
+        one = lambda t: t[:, :, :, None]                   # r = 1
+        S_end, S_prev, V_new = _scan_chunks(
+            one(W), one(U), one(K_end),
+            one(jnp.exp(gamma[..., -1, :])), (SCOPE_KDA, SCOPE_KDA_CHUNK),
+            cd)
+        o = jnp.einsum("bchld,bchde->bchle", (qf * in_chunk).astype(cd),
+                       S_prev[:, :, :, 0], preferred_element_type=F32)
+        o = o + jnp.einsum("bchls,bchse->bchle", QK.astype(cd),
+                           V_new[:, :, :, 0], preferred_element_type=F32)
+        return (jnp.moveaxis(o, 3, 2).reshape(b, T, h, dv),
+                S_end.reshape(b, h, dk, dv))
+
+
 def gated_delta_step(q, k, v, g, beta, S):
-    """One position: q, k (b, h_k, d_k); v (b, h_v, d_v); g, beta (b, h_v);
-    S (b, h_v, d_k, d_v) float32.  Returns (o (b, h_v, d_v), S')."""
+    """One position: q, k (b, h_k, d_k); v (b, h_v, d_v); beta (b, h_v); g
+    (b, h_v) a head or (b, h_v, d_k) a key channel; S (b, h_v, d_k, d_v)
+    float32.  Returns (o (b, h_v, d_v), S')."""
     r = v.shape[1] // k.shape[1]
     qh, kh = (jnp.repeat(t.astype(F32), r, axis=1) for t in (q, k))
-    S = jnp.exp(g)[..., None, None] * S
+    S = jnp.exp(g)[(...,) + (None,) * (4 - g.ndim)] * S
     delta = beta[..., None] * (v.astype(F32)
                                - jnp.einsum("bhkv,bhk->bhv", S, kh))
     S = S + kh[..., :, None] * delta[..., None, :]

@@ -1,11 +1,14 @@
 """Hybrid sequence Q-network: a layer PATTERN of state-space (Mamba-2),
-gated-delta-rule, sparse-expert and grouped-query attention blocks over one
-frame per position (model_type ``dtqn-hybrid``, CONFIGS rows 20 and 21).
+gated-delta-rule (a head's or a key channel's gate), sparse-expert, dense
+feed-forward, grouped-query and latent attention blocks over one frame per
+position (model_type ``dtqn-hybrid``, CONFIGS rows 20, 21 and 22).
 
 The trunk is the first layers of a published hybrid language model at their
 published widths (``PRESETS["nemotron-h-9"]``: layers 0-8, CONFIGS row 20,
 benchmark/configs/nemotron_h_pong.json; ``PRESETS["qwen3-next-4"]``: layers
 0-3, eight blocks, CONFIGS row 21, benchmark/configs/qwen3_next_pong.json;
+``PRESETS["kimi-linear-5"]``: layers 0-4, ten blocks, CONFIGS row 22,
+benchmark/configs/kimi_linear_pong.json;
 source and every departure in those files), with the token embedding and LM
 head replaced by the repo's sequence-family contract (models/dtqn.py): one
 84x84 uint8 frame a position -> Dense -> trunk -> final RMSNorm ->
@@ -25,6 +28,25 @@ mixer(RMSNorm(x))``, no biases but Mamba's conv's; the norm's scale is
   a head's output through a gated RMSNorm (``w_n * o / rms(o) * silu(z)``);
   ``W_out``.  In chunks for the learner, one position at a time for the
   actor.
+- ``K``  channel-gated delta rule (Kimi Delta Attention): ``q, k, v = u
+  W_q, u W_k, u W_v``, each through its own causal depth-wise conv + silu;
+  q, k L2-normalised a head, ``q / sqrt(d)``; ``g = -exp(A_log) softplus(f_b
+  (f_a u) + dt_bias)`` a KEY CHANNEL (``A_log`` a head, ``dt_bias`` a
+  channel, ``f`` a low-rank pair), ``beta = sigmoid(u W_b)`` a head; the
+  recurrence with ``S' = Diag(exp(g_t)) S_{t-1}`` (models/gated_delta.py
+  ``kda_chunked``); a head's output through ``w_n * o / rms(o) * sigmoid(g_b
+  (g_a u) + b)``; ``W_out``.  In chunks for the learner, one position at a
+  time for the actor (carry: three conv tails and the float32 state).
+- ``L``  latent attention, position-free: ``q = u W_q`` (heads x (nope +
+  rope)); ``[c | k_r] = u W_kva``; ``c <- RMSNorm(c)``; ``[k_n | v] = c
+  W_kvb`` a head; a head's key is ``[k_n | k_r]``, ``k_r`` SHARED by all
+  heads; causal softmax at scale ``(nope + rope)^-1/2``; ``W_o``.  The
+  learner expands keys and values and attends in query blocks; the actor
+  keeps a ring of LATENTS ``[c | k_r]`` (576 values a position where the
+  expanded keys and values are 32 x 320) and attends in the absorbed form
+  ``(W_kvb_k^T q_n) . c + q_r . k_r``, values rebuilt from the weighted
+  latent.
+- ``F``  a dense SwiGLU block ``(silu(u W_gate) * u W_up) W_down``.
 - ``*``  causal grouped-query attention in query blocks: no ``(B, heads, T,
   T)`` array exists.  Position-free as published for the first preset;
   with the second's options a query / key RMSNorm a head (``qk_norm``),
@@ -53,13 +75,17 @@ mixer(RMSNorm(x))``, no biases but Mamba's conv's; the norm's scale is
   choice) pairs that chose expert e, ``P_e`` the mean of ``p_e``; over ALL
   experts) that joins the TD loss with weight ``aux_weight``.  With
   ``gated_experts``: SwiGLU experts ``(silu(u W_gate) * u W_up) W_down``,
-  and the shared expert the same behind ``sigmoid(u . shared_gate)``.
+  and the shared expert the same, behind ``sigmoid(u . shared_gate)`` where
+  the preset has ``shared_expert_gate``.  Router and expert form are chosen
+  apart: the third preset routes by sigmoid scores with ``b_sel`` over
+  SwiGLU experts and an ungated SwiGLU shared expert.
 
 Contracts shared with the other sequence families (recurrent actor,
 evaluator, sequence learner): ``window_q(frames (B, T, H, W))`` is the
 learner's one causal pass, zero state at position 0; ``__call__(obs,
 carry)`` acts one step through a carry of (conv tails, SSM or delta-rule
-states, key / value caches, count) whose leaves all lead with the batch dimension;
+states, key / value caches or a latent ring, count) whose leaves all lead
+with the batch dimension;
 ``state_for_segment`` stores the 1-dim placeholder of every ``dtqn*``
 model.  bfloat16 matmuls; float32 parameters, router, softmax, norms,
 ``dt``, ``A`` and scan state.  Every mixer runs under ``jax.checkpoint``
@@ -79,12 +105,13 @@ import jax.numpy as jnp
 import numpy as np
 
 from pytorch_distributed_tpu.models.gated_delta import (
-    gated_delta_chunked, gated_delta_step,
+    gated_delta_chunked, gated_delta_step, kda_chunked,
 )
 from pytorch_distributed_tpu.ops.sequence_losses import AUX_LOSS_KEY
 from pytorch_distributed_tpu.utils.profiling import (
-    SCOPE_ATTN, SCOPE_EMBED, SCOPE_GDN, SCOPE_HEAD, SCOPE_MOE,
-    SCOPE_MOE_EXPERTS, SCOPE_MOE_ROUTE, SCOPE_MOE_SHARED, SCOPE_SSM,
+    SCOPE_ATTN, SCOPE_EMBED, SCOPE_GDN, SCOPE_HEAD, SCOPE_KDA, SCOPE_MLA,
+    SCOPE_MLP, SCOPE_MOE, SCOPE_MOE_EXPERTS, SCOPE_MOE_ROUTE,
+    SCOPE_MOE_SHARED, SCOPE_SSM,
 )
 
 F32 = jnp.float32
@@ -94,7 +121,7 @@ F32 = jnp.float32
 class HybridPreset:
     """Every width of the trunk, in ONE place."""
 
-    pattern: str                 # one letter a layer: M, D, E or *
+    pattern: str                 # one letter a layer: M, D, K, L, F, E or *
     d_model: int
     conv_kernel: int             # M and D: the causal depth-wise conv
     # *: grouped-query attention
@@ -135,6 +162,20 @@ class HybridPreset:
     router: str = "sigmoid"      # E: or "softmax" (no b_sel, no scale)
     gated_experts: bool = False  # E: SwiGLU experts, gated shared expert
     aux_weight: float = 0.0      # E: weight of the load-balancing loss
+    # what the third published trunk adds
+    shared_expert_gate: bool = True  # E, gated: sigmoid gate on the shared
+    run_headroom: int = 2        # E: the first run of sorted rows holds this
+    #                              many times the balanced load (expert_runs)
+    kda_heads: int = 0           # K: heads of keys and of values alike
+    kda_head_dim: int = 0
+    kda_gate_rank: int = 0       # K: of the decay's and the output gate's pair
+    kda_chunk: int = 64
+    kda_sub: int = 16            # K: sub-block whose decays are taken in pairs
+    mla_nope: int = 0            # L: a head's key part rebuilt from the latent
+    mla_rope: int = 0            # L: the key part all heads share (unrotated)
+    mla_v: int = 0               # L: a head's value
+    mla_latent: int = 0
+    mlp_width: int = 0           # F
     # the step metrics of "nemotron-h-9" are pinned with its lowered step
     # (CHANGES.md, PR 31); a preset made since also counts the rows its
     # grouped matmuls were handed (learner/moe_rows_computed)
@@ -159,6 +200,10 @@ class HybridPreset:
     @property
     def gdn_conv_dim(self) -> int:
         return 2 * self.gdn_key_dim + self.gdn_value_dim
+
+    @property
+    def kda_dim(self) -> int:
+        return self.kda_heads * self.kda_head_dim
 
 
 PRESETS: Dict[str, HybridPreset] = {
@@ -186,6 +231,21 @@ PRESETS: Dict[str, HybridPreset] = {
         norm_plus_one=True, qk_norm=True, rotary_dim=64, rope_theta=1e7,
         attn_gate=True, router="softmax", gated_experts=True,
         aux_weight=1e-3),
+    # layers 0-4 of the published 27: the leading dense layer once and one
+    # whole period of what follows (three channel-gated delta-rule mixers to
+    # one latent attention; a dense block after the first mixer, an expert
+    # block after each other one: ten blocks), every width as published; 8
+    # of the 256 experts held: one of the 32 chips that share each layer
+    "kimi-linear-5": HybridPreset(
+        pattern="KFKEKELEKE", d_model=2304, conv_kernel=4,
+        kda_heads=32, kda_head_dim=128, kda_gate_rank=128, kda_chunk=64,
+        kda_sub=16,
+        attn_heads=32, kv_heads=32, attn_head_dim=192, attn_block=256,
+        mla_nope=128, mla_rope=64, mla_v=128, mla_latent=512,
+        mlp_width=9216,
+        n_experts=256, top_k=8, expert_width=1024, shared_width=1024,
+        route_scale=2.446, experts_held=8, first_expert=0, norm_eps=1e-5,
+        gated_experts=True, shared_expert_gate=False, run_headroom=4),
     # CPU tests: every mechanism, no width
     "tiny": HybridPreset(
         pattern="ME*E", d_model=32,
@@ -203,6 +263,14 @@ PRESETS: Dict[str, HybridPreset] = {
         norm_plus_one=True, qk_norm=True, rotary_dim=4, rope_theta=1e7,
         attn_gate=True, router="softmax", gated_experts=True,
         aux_weight=1e-3),
+    "tiny-kimi": HybridPreset(
+        pattern="KFLE", d_model=32, conv_kernel=4,
+        kda_heads=4, kda_head_dim=8, kda_gate_rank=8, kda_chunk=4, kda_sub=2,
+        attn_heads=4, kv_heads=4, attn_head_dim=12, attn_block=4,
+        mla_nope=8, mla_rope=4, mla_v=8, mla_latent=16, mlp_width=48,
+        n_experts=16, top_k=3, expert_width=16, shared_width=16,
+        route_scale=2.446, experts_held=4, first_expert=0,
+        gated_experts=True, shared_expert_gate=False),
 }
 
 
@@ -432,6 +500,79 @@ def gdn_step(p, u, tail, S, c: HybridPreset, cd):
         return _gdn_out(p, o, z, c, cd), taps[:, 1:], S
 
 
+def _conv_silu(x, w):
+    """Causal depth-wise conv + silu over (b, T, c): tap j reads position t
+    - (K-1) + j."""
+    K, T = w.shape[0], x.shape[1]
+    xp = jnp.pad(x, ((0, 0), (K - 1, 0), (0, 0)))
+    return jax.nn.silu(sum(xp[:, j:j + T] * w[j] for j in range(K)))
+
+
+def _kda_inputs(p, u, q, k, v, c: HybridPreset, cd):
+    """After the convs: q, k (.., h, d) L2-normalised a head and q scaled; v
+    (.., h, d); the log-decay g (.., h, d) a key channel and the write
+    strength beta (.., h), float32."""
+    lead, h, d = q.shape[:-1], c.kda_heads, c.kda_head_dim
+    unit = lambda t: t * jax.lax.rsqrt(
+        jnp.sum(jnp.square(t), -1, keepdims=True) + 1e-6)
+    q = unit(q.reshape(*lead, h, d)) / math.sqrt(d)
+    k = unit(k.reshape(*lead, h, d))
+    f = _mm(_mm(u, p["w_fa"], cd), p["w_fb"], cd) + p["dt_bias"]
+    g = -jnp.exp(p["A_log"])[:, None] * jax.nn.softplus(
+        f.reshape(*lead, h, d))
+    beta = jax.nn.sigmoid(_mm(u, p["w_b"], cd))
+    return q, k, v.reshape(*lead, h, d), g, beta
+
+
+def _kda_out(p, u, o, c: HybridPreset, cd):
+    """A head's gated norm (``w_n * o / rms(o) * sigmoid(g_b (g_a u) +
+    b)``), then the out projection."""
+    gate = _mm(_mm(u, p["w_ga"], cd), p["w_gb"], cd) + p["gate_bias"]
+    o = rms_norm(o, p["gate_norm"], c.norm_eps) * jax.nn.sigmoid(
+        gate.reshape(o.shape))
+    return _mm(o.reshape(*o.shape[:-2], c.kda_dim), p["w_out"], cd)
+
+
+def kda_window(p, u, c: HybridPreset, cd):
+    """One K mixer over (b, T, d) normed input, zero state at t = 0; T
+    padded up to whole chunks (``g`` = ``beta`` = 0 in the padding).  Returns
+    (out (b, T, d) float32, the state after position T - 1 (b, h, d_k, d_v)
+    float32, the decay ``exp(g)`` of each key channel, its mean over the
+    window (h, d_k): how long each channel remembers)."""
+    with jax.named_scope(SCOPE_KDA):
+        T = u.shape[1]
+        pad = -T % c.kda_chunk
+        q, k, v, g, beta = _kda_inputs(p, u, *(
+            _conv_silu(_mm(u, p[f"w_{x}"], cd), p[f"conv_{x}"])
+            for x in "qkv"), c, cd)
+        grow = lambda t: jnp.pad(
+            t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
+        o, S = kda_chunked(grow(q), grow(k), grow(v), grow(g), grow(beta),
+                           c.kda_chunk, c.kda_sub, cd)
+        return (_kda_out(p, u, o[:, :T], c, cd), S,
+                jax.lax.stop_gradient(jnp.mean(jnp.exp(g), axis=(0, 1))))
+
+
+def kda_step(p, u, tails, S, c: HybridPreset, cd):
+    """One position: u (b, d); tails, three (b, K-1, h d): the last inputs
+    of the convs of q, k and v; S (b, h, d_k, d_v) float32."""
+    with jax.named_scope(SCOPE_KDA):
+        taps = [jnp.concatenate([tail, _mm(u, p[f"w_{x}"], cd)[:, None]],
+                                axis=1) for x, tail in zip("qkv", tails)]
+        q, k, v, g, beta = _kda_inputs(p, u, *(
+            jax.nn.silu(jnp.einsum("bkc,kc->bc", t, p[f"conv_{x}"]))
+            for x, t in zip("qkv", taps)), c, cd)
+        o, S = gated_delta_step(q, k, v, g, beta, S)
+        return _kda_out(p, u, o, c, cd), [t[:, 1:] for t in taps], S
+
+
+def mlp_block(p, u, cd):
+    """One F mixer: a dense SwiGLU block over (.., d) normed input."""
+    with jax.named_scope(SCOPE_MLP):
+        return _mm(jax.nn.silu(_mm(u, p["w_gate"], cd))
+                   * _mm(u, p["w_up"], cd), p["w_down"], cd)
+
+
 def _attend(q, k, v, mask, cd):
     """softmax(q k^T / sqrt(hd)) v for one block of queries: q (b, kv, r,
     Tq, hd), k / v (b, kv, Tk, hd), mask (.., Tq, Tk) or None.  Scores and
@@ -478,31 +619,47 @@ def _qkv(p, u, pos, c: HybridPreset, cd):
     return q, k, v, gate
 
 
+def _causal_blocks(q, k, v, Q: int, scope: str, cd):
+    """Causal attention over a window, block of ``Q`` queries by block, a
+    block reading only the keys up to its own end (the causal half), each
+    under ``jax.checkpoint`` and the caller's ``scope``: q (b, kv, r, T,
+    hd), k / v (b, kv, T, .) -> (b, kv, r, T, hd_v) float32."""
+    T = q.shape[3]
+
+    @jax.checkpoint
+    def block(qb, kb, vb, lo):
+        with jax.named_scope(scope):
+            rows = lo + jnp.arange(qb.shape[3])[:, None]
+            return _attend(qb, kb, vb,
+                           jnp.arange(kb.shape[2])[None, :] <= rows, cd)
+
+    out = [block(q[:, :, :, lo:lo + Q], k[:, :, :lo + Q], v[:, :, :lo + Q],
+                 lo) for lo in range(0, T, Q)]
+    return jnp.concatenate(out, axis=3)
+
+
 def attention_window(p, u, c: HybridPreset, cd):
     """One * mixer over (b, T, d): causal, each key-value head shared by
-    ``attn_heads / kv_heads`` query heads, block of queries by block, a
-    block reading only the keys up to its own end (the causal half)."""
+    ``attn_heads / kv_heads`` query heads, in query blocks."""
     with jax.named_scope(SCOPE_ATTN):
         b, T, _ = u.shape
         r, Q = c.attn_heads // c.kv_heads, min(c.attn_block, T)
         q, k, v, gate = _qkv(p, u, jnp.arange(T), c, cd)
         q = q.reshape(b, T, c.kv_heads, r, -1).transpose(0, 2, 3, 1, 4)
         k, v = (t.transpose(0, 2, 1, 3) for t in (k, v))       # (b,kv,T,hd)
-
-        @jax.checkpoint
-        def block(qb, kb, vb, lo):
-            with jax.named_scope(SCOPE_ATTN):
-                rows = lo + jnp.arange(qb.shape[3])[:, None]
-                return _attend(qb, kb, vb,
-                               jnp.arange(kb.shape[2])[None, :] <= rows, cd)
-
-        out = [block(q[:, :, :, lo:lo + Q], k[:, :, :lo + Q], v[:, :, :lo + Q],
-                     lo) for lo in range(0, T, Q)]
-        o = jnp.concatenate(out, axis=3)                       # (b,kv,r,T,hd)
+        o = _causal_blocks(q, k, v, Q, SCOPE_ATTN, cd)         # (b,kv,r,T,hd)
         o = o.transpose(0, 3, 1, 2, 4).reshape(b, T, -1)
         if gate is not None:
             o = o * jax.nn.sigmoid(gate)
         return _mm(o, p["w_o"], cd)
+
+
+def _ring_put(ring, new, count):
+    """Each env's newest entry ``new`` (b, ..) into slot ``count % W`` of its
+    ring (b, W, ..), in the ring's dtype."""
+    at = (count % ring.shape[1]).astype(jnp.int32)
+    return jax.vmap(lambda r, x, i: jax.lax.dynamic_update_slice_in_dim(
+        r, x[None], i, 0))(ring, new.astype(ring.dtype), at)
 
 
 def attention_step(p, u, kc, vc, count, c: HybridPreset, cd):
@@ -514,11 +671,7 @@ def attention_step(p, u, kc, vc, count, c: HybridPreset, cd):
         b, W = kc.shape[:2]
         r = c.attn_heads // c.kv_heads
         q, k, v, gate = _qkv(p, u, count, c, cd)
-        at = (count % W).astype(jnp.int32)
-        put = jax.vmap(lambda cache, new, i:
-                       jax.lax.dynamic_update_slice_in_dim(cache, new[None],
-                                                           i, 0))
-        kc, vc = put(kc, k.astype(kc.dtype), at), put(vc, v.astype(vc.dtype), at)
+        kc, vc = _ring_put(kc, k, count), _ring_put(vc, v, count)
         valid = jnp.arange(W)[None, :] < jnp.minimum(count + 1, W)[:, None]
         o = _attend(q.reshape(b, c.kv_heads, r, 1, -1),
                     kc.transpose(0, 2, 1, 3), vc.transpose(0, 2, 1, 3),
@@ -527,6 +680,62 @@ def attention_step(p, u, kc, vc, count, c: HybridPreset, cd):
         if gate is not None:
             o = o * jax.nn.sigmoid(gate)
         return _mm(o, p["w_o"], cd), kc, vc
+
+
+def _mla_latent(p, u, c: HybridPreset, cd):
+    """Queries (.., heads, nope + rope) and what a position leaves behind:
+    its normalised latent (.., latent) and the key part all heads share
+    (.., rope), float32."""
+    q = _mm(u, p["w_q"], cd).reshape(*u.shape[:-1], c.attn_heads, -1)
+    lat, k_r = jnp.split(_mm(u, p["w_kva"], cd), [c.mla_latent], axis=-1)
+    return q, rms_norm(lat, p["kv_norm"], c.norm_eps), k_r
+
+
+def mla_window(p, u, c: HybridPreset, cd):
+    """One L mixer over (b, T, d): keys and values expanded from the
+    latent, a head's key ``[k_n | k_r]`` with ``k_r`` the same for every
+    head, then causal attention in query blocks as the * mixer's."""
+    with jax.named_scope(SCOPE_MLA):
+        b, T, _ = u.shape
+        h = c.attn_heads
+        q, lat, k_r = _mla_latent(p, u, c, cd)
+        kv = _mm(lat, p["w_kvb"], cd).reshape(b, T, h, c.mla_nope + c.mla_v)
+        k = jnp.concatenate([kv[..., :c.mla_nope], jnp.broadcast_to(
+            k_r[:, :, None], (b, T, h, c.mla_rope))], axis=-1)
+        q = q.transpose(0, 2, 1, 3)[:, :, None]             # (b,h,1,T,hd)
+        k, v = (t.transpose(0, 2, 1, 3)
+                for t in (k, kv[..., c.mla_nope:]))         # (b,h,T,.)
+        o = _causal_blocks(q, k, v, min(c.attn_block, T), SCOPE_MLA, cd)
+        return _mm(o.transpose(0, 3, 1, 2, 4).reshape(b, T, -1), p["w_o"],
+                   cd)
+
+
+def mla_step(p, u, ring, count, c: HybridPreset, cd):
+    """One position against the ring (b, W, latent + rope) of the last W
+    positions' ``[normalised latent | shared key part]`` (position-free:
+    their order in the ring does not matter), in the absorbed form: a
+    head's query is taken into the latent's space (``W_kvb_k^T q_n``), the
+    scores are read off the ring itself, and the values are rebuilt from
+    the weighted latent.  ``count`` (b,) positions seen before this one."""
+    with jax.named_scope(SCOPE_MLA):
+        b, W = ring.shape[:2]
+        q, lat, k_r = _mla_latent(p, u, c, cd)
+        ring = _ring_put(ring, jnp.concatenate([lat, k_r], -1), count)
+        w_kvb = p["w_kvb"].astype(cd).reshape(c.mla_latent, c.attn_heads, -1)
+        q_abs = jnp.concatenate([jnp.einsum(
+            "bhn,lhn->bhl", q[..., :c.mla_nope].astype(cd),
+            w_kvb[..., :c.mla_nope], preferred_element_type=F32),
+            q[..., c.mla_nope:]], axis=-1)                   # (b,h,lat+rope)
+        s = jnp.einsum("bhl,bwl->bhw", q_abs.astype(cd), ring.astype(cd),
+                       preferred_element_type=F32) / math.sqrt(q.shape[-1])
+        valid = jnp.arange(W)[None, :] < jnp.minimum(count + 1, W)[:, None]
+        w = jax.nn.softmax(jnp.where(valid[:, None], s, -jnp.inf), axis=-1)
+        ctx = jnp.einsum("bhw,bwl->bhl", w.astype(cd),
+                         ring[..., :c.mla_latent].astype(cd),
+                         preferred_element_type=F32)
+        o = jnp.einsum("bhl,lhv->bhv", ctx.astype(cd),
+                       w_kvb[..., c.mla_nope:], preferred_element_type=F32)
+        return _mm(o.reshape(b, -1), p["w_o"], cd), ring
 
 
 def route(p, u, c: HybridPreset):
@@ -620,11 +829,14 @@ def _grouped_ffn(p, u, tok, w_pair, sizes, cd, kernel="auto"):
 def expert_runs(c: HybridPreset, pairs: int) -> Tuple[int, ...]:
     """Lengths of the runs the sorted (token, choice) pairs go through the
     held experts in: the first holds twice the rows a balanced router sends
-    here (``pairs * experts_held / n_experts``), each further one as many
+    here (``pairs * experts_held / n_experts``; ``run_headroom`` times: four
+    where 8 experts of 256 are held, whose seeded router sends them up to
+    twice their share, so that no update of a window needs a second run and
+    a step's time does not depend on the seed), each further one as many
     as all before it, until every pair has a place: whatever the skew no
     row is dropped, and memory is the largest run's.  In whole tiles of
     the kernel's 256 rows (of 8 where a run is shorter)."""
-    first = -(-2 * pairs * c.experts_held // c.n_experts)
+    first = -(-c.run_headroom * pairs * c.experts_held // c.n_experts)
     first = -(-first // 256) * 256 if first >= 256 else -(-first // 8) * 8
     runs = [first]
     while sum(runs) < pairs:
@@ -706,9 +918,10 @@ def moe_layer(p, u, c: HybridPreset, cd, kernel="auto"):
                 shared = _mm(jax.nn.silu(_mm(u, p["w_shared_gate"], cd))
                              * _mm(u, p["w_shared_up"], cd),
                              p["w_shared_down"], cd)
-                shared = shared * jax.nn.sigmoid(jnp.matmul(
-                    u.astype(F32), p["shared_gate"],
-                    precision=jax.lax.Precision.HIGHEST))
+                if c.shared_expert_gate:
+                    shared = shared * jax.nn.sigmoid(jnp.matmul(
+                        u.astype(F32), p["shared_gate"],
+                        precision=jax.lax.Precision.HIGHEST))
             else:
                 shared = _mm(relu2(_mm(u, p["w_shared_up"], cd)),
                              p["w_shared_down"], cd)
@@ -856,32 +1069,55 @@ def layer_param_specs(kind: str, c: HybridPreset):
             specs.update(q_norm=(_norm_init(c), (c.attn_head_dim,)),
                          k_norm=(_norm_init(c), (c.attn_head_dim,)))
         return specs
-    if kind == "E" and c.gated_experts:
-        H = c.experts_held
-        assert c.router == "softmax", "gated experts come with that router"
+    if kind == "K":
+        hd, r = c.kda_dim, c.kda_gate_rank
         return {
-            "router": (_lecun, (d, c.n_experts)),
-            "w_gate": (_lecun_experts, (H, d, c.expert_width)),
-            "w_up": (_lecun_experts, (H, d, c.expert_width)),
-            "w_down": (_lecun_experts, (H, c.expert_width, d)),
-            "w_shared_gate": (_lecun, (d, c.shared_width)),
-            "w_shared_up": (_lecun, (d, c.shared_width)),
-            "w_shared_down": (_lecun, (c.shared_width, d)),
-            "shared_gate": (_lecun, (d, 1)),
+            **{f"w_{x}": (_lecun, (d, hd)) for x in "qkv"},
+            **{f"conv_{x}": (_lecun, (c.conv_kernel, hd)) for x in "qkv"},
+            "w_fa": (_lecun, (d, r)), "w_fb": (_lecun, (r, hd)),
+            "dt_bias": (_dt_bias_init(c), (hd,)),
+            "A_log": (_a_log_init, (c.kda_heads,)),
+            "w_b": (_lecun, (d, c.kda_heads)),
+            "w_ga": (_lecun, (d, r)), "w_gb": (_lecun, (r, hd)),
+            "gate_bias": (nn.initializers.zeros, (hd,)),
+            "gate_norm": (_ones, (c.kda_head_dim,)),
+            "w_out": (_lecun, (hd, d)),
         }
-    if kind == "E":
-        H = c.experts_held
+    if kind == "L":
+        h = c.attn_heads
         return {
-            "router": (_lecun, (d, c.n_experts)),
+            "w_q": (_lecun, (d, h * (c.mla_nope + c.mla_rope))),
+            "w_kva": (_lecun, (d, c.mla_latent + c.mla_rope)),
+            "kv_norm": (_norm_init(c), (c.mla_latent,)),
+            "w_kvb": (_lecun, (c.mla_latent, h * (c.mla_nope + c.mla_v))),
+            "w_o": (_lecun, (h * c.mla_v, d)),
+        }
+    if kind == "F":
+        return {"w_gate": (_lecun, (d, c.mlp_width)),
+                "w_up": (_lecun, (d, c.mlp_width)),
+                "w_down": (_lecun, (c.mlp_width, d))}
+    if kind == "E":
+        H, gated = c.experts_held, c.gated_experts
+        experts = lambda *shape: (_lecun_experts, (H, *shape))
+        shared = lambda *shape: (_lecun, shape)
+        specs = {"router": (_lecun, (d, c.n_experts))}
+        if c.router == "sigmoid":
             # selection has no gradient, so Adam never moves it: it is
             # balance_selection_bias's to move
-            "b_sel": (_b_sel_init, (c.n_experts,)),
-            "w_up": (_lecun_experts, (H, d, c.expert_width)),
-            "w_down": (_lecun_experts, (H, c.expert_width, d)),
-            "w_shared_up": (_lecun, (d, c.shared_width)),
-            "w_shared_down": (_lecun, (c.shared_width, d)),
-        }
-    raise ValueError(f"unknown layer kind {kind!r} in a hybrid pattern")
+            specs["b_sel"] = (_b_sel_init, (c.n_experts,))
+        if gated:
+            specs["w_gate"] = experts(d, c.expert_width)
+        specs.update(w_up=experts(d, c.expert_width),
+                     w_down=experts(c.expert_width, d))
+        if gated:
+            specs["w_shared_gate"] = shared(d, c.shared_width)
+        specs.update(w_shared_up=shared(d, c.shared_width),
+                     w_shared_down=shared(c.shared_width, d))
+        if gated and c.shared_expert_gate:
+            specs["shared_gate"] = shared(d, 1)
+        return specs
+    raise ValueError(f"unknown layer kind {kind!r} in a hybrid pattern "
+                     f"(known: M, D, K, *, L, F, E)")
 
 
 class _Layer(nn.Module):
@@ -952,12 +1188,15 @@ class HybridQModel(nn.Module):
 
     def window_pass_full(self, frames):
         """``window_pass``'s three, and fourth what the second trunk's
-        layers report besides: {"aux": {E layer: its load-balancing loss},
-        "decay": {D layer: its mean decay ``exp(g)``}}."""
+        and third trunks' layers report besides: {"aux": {E layer: its
+        load-balancing loss}, "decay": {D layer: its mean decay ``exp(g)``},
+        "kda_decay": {K layer: each key channel's mean decay (h, d_k)}}; a
+        K layer's state (B, h, d_k, d_v) stands in the third beside M's and
+        D's."""
         c, cd = self.preset, self.compute_dtype
         x = self._embed(frames)
         B, T, d = x.shape
-        load, states, aux, decay = {}, {}, {}, {}
+        load, states, aux, decay, kda_decay = {}, {}, {}, {}, {}
         norm = lambda p, x: rms_norm(x, norm_scale(p["norm"], c), c.norm_eps)
         for i, layer in enumerate(self.layers):
             p = layer.params_dict()
@@ -982,13 +1221,28 @@ class HybridQModel(nn.Module):
                     out, S, kept = gdn_window(p, norm(p, x), c, cd)
                     return x + out.astype(cd), S, kept
                 x, states[i], decay[i] = mix(p, x)
+            elif layer.kind == "K":
+                @jax.checkpoint
+                def mix(p, x):
+                    out, S, kept = kda_window(p, norm(p, x), c, cd)
+                    return x + out.astype(cd), S, kept
+                x, states[i], kda_decay[i] = mix(p, x)
+            elif layer.kind in "LF":
+                @jax.checkpoint
+                def mix(p, x, kind=layer.kind):
+                    u = norm(p, x)
+                    out = mla_window(p, u, c, cd) if kind == "L" \
+                        else mlp_block(p, u, cd)
+                    return x + out.astype(cd)
+                x = mix(p, x)
             else:
                 @jax.checkpoint
                 def mix(p, x):
                     u = norm(p, x)
                     return x + attention_window(p, u, c, cd).astype(cd)
                 x = mix(p, x)
-        return self._head(x), load, states, {"aux": aux, "decay": decay}
+        return self._head(x), load, states, {
+            "aux": aux, "decay": decay, "kda_decay": kda_decay}
 
     def window_q(self, frames):
         return self.window_pass(frames)[0]
@@ -1011,8 +1265,10 @@ class HybridQModel(nn.Module):
 
     def zero_carry(self, batch: int):
         """A flat tuple, every leaf leading with the batch dimension: per M
-        or D layer (conv tail, float32 state), per * layer (keys, values),
-        then the count of positions seen."""
+        or D layer (conv tail, float32 state), per K layer (the conv tails
+        of q, k and v, float32 state), per * layer (keys, values), per L
+        layer one ring of latents (W, latent + rope), then the count of
+        positions seen."""
         c, out = self.preset, []
         for kind in c.pattern:
             if kind == "M":
@@ -1024,10 +1280,19 @@ class HybridQModel(nn.Module):
                                   F32),
                         jnp.zeros((batch, c.gdn_v_heads, c.gdn_head_dim,
                                    c.gdn_head_dim), F32)]
+            elif kind == "K":
+                out += [jnp.zeros((batch, c.conv_kernel - 1, c.kda_dim), F32)
+                        for _ in "qkv"]
+                out += [jnp.zeros((batch, c.kda_heads, c.kda_head_dim,
+                                   c.kda_head_dim), F32)]
             elif kind == "*":
                 kv = (batch, self.act_window, c.kv_heads, c.attn_head_dim)
                 out += [jnp.zeros(kv, self.compute_dtype),
                         jnp.zeros(kv, self.compute_dtype)]
+            elif kind == "L":
+                out += [jnp.zeros((batch, self.act_window,
+                                   c.mla_latent + c.mla_rope),
+                                  self.compute_dtype)]
         return tuple(out) + (jnp.zeros((batch,), jnp.int32),)
 
     def state_for_segment(self, carry, j: int):
@@ -1052,10 +1317,19 @@ class HybridQModel(nn.Module):
                 out, carry[at], carry[at + 1] = step(
                     p, u, carry[at], carry[at + 1], c, cd)
                 at += 2
+            elif layer.kind == "K":
+                out, carry[at:at + 3], carry[at + 3] = kda_step(
+                    p, u, carry[at:at + 3], carry[at + 3], c, cd)
+                at += 4
             elif layer.kind == "*":
                 out, carry[at], carry[at + 1] = attention_step(
                     p, u, carry[at], carry[at + 1], count, c, cd)
                 at += 2
+            elif layer.kind == "L":
+                out, carry[at] = mla_step(p, u, carry[at], count, c, cd)
+                at += 1
+            elif layer.kind == "F":
+                out = mlp_block(p, u, cd)
             else:
                 # a handful of rows, on whichever backend the actor,
                 # evaluator or tester was pinned to: never the TPU kernel
@@ -1094,6 +1368,12 @@ def window_applies(model: HybridQModel, pack_frames: int = 0):
         if more["decay"]:
             aux["learner/gdn_decay_mean"] = jnp.mean(jnp.stack(
                 list(more["decay"].values())))
+        if more["kda_decay"]:
+            # over positions, heads, channels and K blocks; and the channel
+            # of any block that forgets fastest
+            kept = jnp.stack(list(more["kda_decay"].values()))
+            aux["learner/kda_decay_mean"] = jnp.mean(kept)
+            aux["learner/kda_decay_min"] = jnp.min(kept)
         return q, aux
 
     def target(params, obs):

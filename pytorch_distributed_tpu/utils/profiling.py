@@ -70,14 +70,18 @@ SCOPE_GDN = "model.gdn"               # gated delta rule; holds the one below
 SCOPE_GDN_CHUNK = "gdn.chunk"         # the recurrence proper: decay, the
 #                                       triangular inverse, the chunk
 #                                       products, the scan over chunk states
+SCOPE_KDA = "model.kda"               # channel-gated delta rule; holds:
+SCOPE_KDA_CHUNK = "kda.chunk"         # its recurrence proper, as gdn.chunk
 SCOPE_ATTN = "model.attn"
+SCOPE_MLA = "model.mla"               # latent attention
+SCOPE_MLP = "model.mlp"               # a dense feed-forward block
 SCOPE_MOE = "model.moe"               # holds the three below
 SCOPE_MOE_ROUTE = "moe.route"
 SCOPE_MOE_EXPERTS = "moe.experts"
 SCOPE_MOE_SHARED = "moe.shared"
 SCOPE_HEAD = "model.head"
-MODEL_SCOPES = (SCOPE_EMBED, SCOPE_SSM, SCOPE_GDN, SCOPE_ATTN, SCOPE_MOE,
-                SCOPE_HEAD)
+MODEL_SCOPES = (SCOPE_EMBED, SCOPE_SSM, SCOPE_GDN, SCOPE_KDA, SCOPE_ATTN,
+                SCOPE_MLA, SCOPE_MLP, SCOPE_MOE, SCOPE_HEAD)
 
 
 @functools.lru_cache(maxsize=None)

@@ -613,13 +613,17 @@ def test_the_models_parts_are_named_inside_checkpoint_and_scan(tmp_path):
     fused = replay.build_fused_step(step, 4, donate=False, steps_per_call=1)
     text = fused.lower(state, replay.state, jax.random.PRNGKey(0),
                        jnp.float32(0.6)).as_text(debug_info=True)
-    # model.gdn is the second trunk's: tests/test_gated_delta_update.py
+    # model.gdn is the second trunk's (tests/test_gated_delta_update.py),
+    # model.kda, model.mla and model.mlp the third's
+    # (tests/test_kimi_trunk_update.py)
+    later = (profiling.SCOPE_GDN, profiling.SCOPE_KDA, profiling.SCOPE_MLA,
+             profiling.SCOPE_MLP)
     for scope in tuple(s for s in profiling.MODEL_SCOPES
-                       if s != profiling.SCOPE_GDN) + (
+                       if s not in later) + (
             profiling.SCOPE_MOE_ROUTE, profiling.SCOPE_MOE_EXPERTS,
             profiling.SCOPE_MOE_SHARED):
         assert scope in text, scope
-    assert profiling.SCOPE_GDN not in text
+    assert not any(scope in text for scope in later)
     lines = [ln for ln in text.splitlines() if "loc(" in ln]
     for scope in (profiling.SCOPE_SSM, profiling.SCOPE_ATTN,
                   profiling.SCOPE_MOE):
