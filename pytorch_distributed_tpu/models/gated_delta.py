@@ -35,6 +35,11 @@ everything else is batched over the chunks.  float32 for ``g``, ``beta``,
 ``gamma``, the inverse and the scan's state; the products that read them
 take both operands in the compute dtype and accumulate in float32.
 
+ON A TPU ``gated_delta_chunked`` runs that window as a Pallas kernel pair
+(ops/pallas_gated_delta.py: a chunk's intermediates in fast memory, the
+inverse block by block) where the shapes fit its tiles; what is written here
+is the XLA form, which every other backend runs and the kernels are held to.
+
 Every key head serves ``h_v / h_k`` value heads: value head ``h`` reads key
 head ``h // (h_v / h_k)``.  ``q`` and ``k`` arrive normalised (and ``q``
 scaled); this module holds the recurrence and nothing of the layer around
@@ -70,6 +75,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from pytorch_distributed_tpu.ops import pallas_gated_delta
 from pytorch_distributed_tpu.utils.profiling import (
     SCOPE_GDN, SCOPE_GDN_CHUNK, SCOPE_KDA, SCOPE_KDA_CHUNK,
 )
@@ -188,18 +194,36 @@ def _scan_chunks(W, U, K_end, decay, scopes, cd):
     return S_end, S_prev, V_new
 
 
-def gated_delta_chunked(q, k, v, g, beta, chunk: int, cd=jnp.bfloat16):
+def gated_delta_chunked(q, k, v, g, beta, chunk: int, cd=jnp.bfloat16,
+                        kernel: str = "auto"):
     """The recurrence over a window from a zero state, chunk by chunk.  q, k
     (b, T, h_k, d_k); v (b, T, h_v, d_v); g, beta (b, T, h_v) float32; T a
     whole number of chunks (pad with g = beta = 0: such a position decays
     nothing and writes nothing).  Returns (o (b, T, h_v, d_v) float32, the
     state after the last position (b, h_v, d_k, d_v) float32); the scan
-    carries the state in float32."""
+    carries the state in float32.
+
+    ``kernel``: "auto", "xla" or "interpret" (the kernels under the Pallas
+    interpreter: tests).  On ONE TPU chip (the launchers have no sharding
+    rule: a mesh takes the XLA form), where the shapes fit their tiles, the
+    window runs as the Pallas kernel pair of ops/pallas_gated_delta.py
+    (``gdn_chunk_fwd`` / ``gdn_chunk_bwd`` in a trace), a chunk's
+    intermediates in fast memory; the XLA form below everywhere else, and it
+    is the kernels' oracle (tests/test_gdn_kernel.py).  ONE of the two is in
+    a program, chosen here in Python."""
     with jax.named_scope(SCOPE_GDN_CHUNK):
         b, T, G, dk = k.shape
         hv, dv = v.shape[2:]
         r, L, nc = hv // G, chunk, T // chunk
         assert nc * L == T and r * G == hv, (T, chunk, hv, G)
+        assert kernel in ("auto", "xla", "interpret"), kernel
+        if kernel == "auto" and (
+                jax.default_backend() != "tpu" or jax.device_count() > 1
+                or not pallas_gated_delta.fits(L, r, dk, dv)):
+            kernel = "xla"
+        if kernel != "xla":
+            return pallas_gated_delta.gated_delta_window(
+                q, k, v, g, beta, L, cd, interpret=kernel == "interpret")
         tril = jnp.tril(jnp.ones((L, L), bool))
         heads = lambda t: jnp.moveaxis(                    # (b,c,g,r,L)
             t.astype(F32).reshape(b, nc, L, G, r), 2, -1)

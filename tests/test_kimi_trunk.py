@@ -483,17 +483,23 @@ def test_the_two_copies_of_the_reference_are_identical():
         assert "pytorch_distributed_tpu" not in f.read().split('"""')[2]
 
 
-# -- rows 20 and 21 stay the parent's programs --------------------------------
+# -- rows 20, 21 and 22 stay the parent's programs --------------------------------
 
 # sha256 of the K = 1 fused step's CPU lowering (``as_text()`` without debug
 # info) at the cell's sizes on an abstract train state, recorded on commit
-# bba29e9 (PR 32): the two hybrid cells run these programs, and their
-# ``setup_s`` is within 10 % of refusal from anything that changes them
+# bba29e9 (PR 32; row 22's on 0230cdd, PR 34): the three hybrid cells run
+# these programs, and their ``setup_s`` is within 10 % of refusal from
+# anything that changes them (row 22: 60 s against a bound of 6.0).  On a TPU
+# row 21's step holds the delta rule's Pallas kernels instead of the XLA
+# chunk (PR 35: chosen in Python by the backend, so this CPU lowering does
+# not see it; tests/test_gdn_kernel.py holds the TPU lowering's kernels)
 PINNED = {
     "nemotron_h_pong":
         "eb13a71116c83bc2591d7f783ffb6ad8224666c4c4ec3af7a600d130e279ac22",
     "qwen3_next_pong":
         "34f7c692600a8987ef4067bdfabe536914ec697545e26541b64e5cef0f05ac00",
+    "kimi_linear_pong":
+        "f7c19725a4ddb2467a5a39ba186f031825ce3ea10c0b50e885e51ff6bce4a962",
 }
 
 

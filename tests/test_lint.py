@@ -1013,11 +1013,12 @@ def test_check_sh_runs_only_what_exists():
 
 # retired with the pre-chip measurement stack and the NHWC ring fork (PR
 # 28).  History may name them: CHANGES.md, ROADMAP.md, PERF.md from its
-# Findings on, and the issue that retired them; benchmark/ is not ours.
+# Findings on, the issue that retired them and a reviewer's notes on a
+# PR (REVIEW.md quotes file names); benchmark/ is not ours.
 RETIRED = ["bench.py", "bench_gate", "mfu_probe", "BENCH_SMOKE_BASELINE",
            "device_channels_last", "nhwc_input"]
-_HISTORY = {"CHANGES.md", "ROADMAP.md", "ISSUE.md", "PERF_LEDGER.jsonl",
-            os.path.join("tests", "test_lint.py")}
+_HISTORY = {"CHANGES.md", "ROADMAP.md", "ISSUE.md", "REVIEW.md",
+            "PERF_LEDGER.jsonl", os.path.join("tests", "test_lint.py")}
 
 
 @pytest.mark.parametrize("name", RETIRED)
