@@ -432,9 +432,6 @@ class _LocalDqnEngine:
     def collect(self, pending):
         return _unpack_dqn(np.asarray(pending))
 
-    def jit_cache_size(self) -> Optional[int]:
-        return self._act._cache_size()
-
     def close(self) -> None:
         pass
 
@@ -468,9 +465,6 @@ class _LocalDdpgEngine:
     def collect(self, pending):
         return _ou_explore(self._h, np.asarray(pending)), {}
 
-    def jit_cache_size(self) -> Optional[int]:
-        return self._act._cache_size()
-
     def close(self) -> None:
         pass
 
@@ -489,9 +483,6 @@ class _BatchedDqnEngine:
     def collect(self, pending):
         return _unpack_dqn(self._client.collect(pending))
 
-    def jit_cache_size(self) -> Optional[int]:
-        return None  # the jit lives server-side
-
     def close(self) -> None:
         pass
 
@@ -507,9 +498,6 @@ class _BatchedDdpgEngine:
 
     def collect(self, pending):
         return _ou_explore(self._h, self._client.collect(pending)), {}
-
-    def jit_cache_size(self) -> Optional[int]:
-        return None
 
     def close(self) -> None:
         pass
@@ -543,11 +531,11 @@ def _drive_actor_loop(h: _ActorHarness, engine, clock: GlobalClock,
     aggregate of the two so dashboards compare across schedules.
     """
     timer = h.timer
-    h.engine = engine  # introspection: tests read jit_cache_size
+    h.engine = engine  # introspection: tests read its program
     # retrace detector: the fused act program must never recompile
-    # after warmup (batched engines return None — the jit lives
+    # after warmup (batched engines have none — the jit lives
     # server-side and the server registers its own)
-    h.perf.register_jit("act", engine.jit_cache_size)
+    h.perf.register_jit("act", getattr(engine, "_act", None))
     h.start()
     tick = 0
     reset_mask = np.zeros(h.num_envs, dtype=bool)
@@ -652,7 +640,7 @@ def _drive_device_actor_loop(h: _ActorHarness, clock: GlobalClock,
     # post-warmup recompile = a shape/dtype leak paying compile latency
     # on the hot path) and its per-frame FLOPs feed the actor-side MFU
     # on the live plane (utils/perf.py flops_per_frame)
-    h.perf.register_jit("device_rollout", rollout._cache_size)
+    h.perf.register_jit("device_rollout", rollout)
     carry = init_rollout_carry(env, ap.nstep)
     eps_dev = jnp.asarray(eps, jnp.float32)
     key_dev = jnp.asarray(base_key)

@@ -60,6 +60,7 @@ from typing import Any
 import numpy as np
 
 from pytorch_distributed_tpu.config import Options
+from pytorch_distributed_tpu.utils.profiling import report_setup
 
 _ENV_PREFIX = "TPU_APEX_ANAKIN_"
 
@@ -266,10 +267,8 @@ class AnakinDriver:
             _cd = getattr(self.model, "compute_dtype", None)
             if _cd is not None:
                 self.perf.set_compute_dtype(jnp.dtype(_cd).name)
-            self.perf.register_jit("fused_step",
-                                   getattr(prog.fused, "_cache_size", None))
-            self.perf.register_jit("anakin_rollout",
-                                   self.rollout._cache_size)
+            self.perf.register_jit("fused_step", prog.fused)
+            self.perf.register_jit("anakin_rollout", self.rollout)
             # seed-derived even though these keys only feed .lower()
             # for the FLOP capture (apexlint rng-key-reuse contract)
             _pkeys = jax.random.split(
@@ -514,6 +513,7 @@ class AnakinDriver:
                                 step=self.lstep)
         t_cadence = time.monotonic()
         last_stats_lstep = self.lstep
+        setup_reported = False
         while self.lstep < ap.steps and not clock.stop.is_set() \
                 and time.monotonic() < deadline:
             clock.bump_progress("learner")
@@ -571,6 +571,11 @@ class AnakinDriver:
                     self.writer.scalars(self.perf.drain(step=self.lstep),
                                         step=self.lstep)
                 self.writer.scalars(self.timer.drain(), step=self.lstep)
+                if not setup_reported:
+                    # the learner's metrics were fetched above, so both
+                    # programs have run: set-up as the compile record saw it
+                    setup_reported = True
+                    report_setup(self.writer, self.lstep)
                 self._roll_s = self._learn_s = 0.0
                 self._roll_frames = 0
                 t_cadence = now

@@ -261,9 +261,10 @@ class InferenceServer:
 
             self._roll_act = build_packed_roll_act(model.apply)
         else:  # ddpg: deterministic forward, noise stays actor-side
-            fwd = lambda p, o: model.apply(p, o,
-                                           method=model.forward_actor)
-            self._act_single = jax.jit(fwd)
+            def act(p, o):
+                return model.apply(p, o, method=model.forward_actor)
+
+            self._act_single = jax.jit(act)
             self._act_rows = self._act_single
         # per-row key expanders, cached per row count (row counts are
         # per-client env widths — a handful of static shapes)
@@ -277,12 +278,10 @@ class InferenceServer:
             return fn
 
         self._expander = expander
-        self.perf.register_jit("act_single",
-                               getattr(self._act_single, "_cache_size",
-                                       None))
-        self.perf.register_jit("act_rows",
-                               getattr(self._act_rows, "_cache_size",
-                                       None))
+        # this thread's programs are told apart by name (act / act_rows /
+        # roll_act): the retrace detector sees a compile by name only
+        self.perf.register_jit("act_single", self._act_single)
+        self.perf.register_jit("act_rows", self._act_rows)
 
     def _refresh_params(self, block: bool) -> None:
         """Pull the newest published weights onto the device.  Blocking

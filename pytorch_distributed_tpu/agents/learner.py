@@ -44,7 +44,7 @@ from pytorch_distributed_tpu.utils import (
 )
 from pytorch_distributed_tpu.utils.faults import FaultInjector
 from pytorch_distributed_tpu.utils.metrics import MetricsWriter
-from pytorch_distributed_tpu.utils.profiling import StepTimer
+from pytorch_distributed_tpu.utils.profiling import StepTimer, report_setup
 from pytorch_distributed_tpu.utils.rngs import np_rng, process_seed
 
 
@@ -304,12 +304,10 @@ def run_learner(opt: Options, spec: EnvSpec, process_ind: int, memory: Any,
         # analysis ONCE at startup — the same executable the loop
         # dispatches (the AOT lower/compile below dedups through the
         # persistent compile cache on TPU) — so live MFU is one
-        # multiply per stats window.  The jit cache handle feeds the
-        # retrace detector: this program must never recompile after
-        # warmup.
+        # multiply per stats window.  The retrace detector watches the
+        # program: it must never recompile after warmup.
         if perf_mon.enabled:
-            perf_mon.register_jit("fused_step",
-                                  getattr(fused, "_cache_size", None))
+            perf_mon.register_jit("fused_step", fused)
             # seed-derived even though these keys only feed .lower()
             # for the FLOP capture (apexlint rng-key-reuse: no literal-
             # seed streams outside utils.rngs)
@@ -462,7 +460,7 @@ def run_learner(opt: Options, spec: EnvSpec, process_ind: int, memory: Any,
     # ``clock.learner_done`` counts here, so it never leads and reaches
     # ``learner_step`` once the last dispatch is done.
     in_flight: collections.deque = collections.deque()
-    ldone = lstep
+    ldone = ldone_at_entry = lstep
     clock.set_learner_done(ldone)
 
     def _reap() -> None:
@@ -709,6 +707,11 @@ def run_learner(opt: Options, spec: EnvSpec, process_ind: int, memory: Any,
         in_flight.append((jax.tree_util.tree_leaves(metrics)[0], t_enqueue,
                           stride, tracing.current_trace()))
         _reap()
+        if ldone_at_entry is not None and ldone > ldone_at_entry:
+            # the first dispatch is done, so its program is traced, lowered
+            # and compiled or loaded: set-up as the compile record saw it
+            ldone_at_entry = None
+            report_setup(timing_writer, lstep)
 
         # cadences fire on boundary crossings so a multi-step dispatch
         # (stride > 1) never skips them

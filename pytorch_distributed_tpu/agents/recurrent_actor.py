@@ -26,7 +26,7 @@ shared server is a different design — and downgrades to ``pipelined``
 
 from __future__ import annotations
 
-from typing import Any, Optional
+from typing import Any
 
 import numpy as np
 
@@ -156,9 +156,6 @@ class _RecurrentEngine:
                       carry_after=carry_after)
         self._host_carry = carry_after
         return np.asarray(action).astype(np.int64), extras
-
-    def jit_cache_size(self) -> Optional[int]:
-        return self._act._cache_size()
 
     def close(self) -> None:
         pass

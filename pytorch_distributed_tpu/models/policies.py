@@ -153,14 +153,14 @@ def build_packed_roll_act(apply_fn: Callable) -> Callable:
     full upload that also reseeds the device stack), so the device
     reconstruction is bit-exact with what the env emitted."""
 
-    def act(params, stack, new, base_key, tick, eps):
+    def roll_act(params, stack, new, base_key, tick, eps):
         stack = jnp.concatenate([stack[:, 1:], new[:, None]], axis=1)
         q = apply_fn(params, stack)
         action = _rowwise_eps_greedy(q, tick_keys(base_key, tick,
                                                   q.shape[0]), eps)
         return stack, _pack_dqn(q, action)
 
-    return jax.jit(act, donate_argnums=(1,))
+    return jax.jit(roll_act, donate_argnums=(1,))
 
 
 def build_packed_act_rowkeys(apply_fn: Callable) -> Callable:
@@ -170,11 +170,11 @@ def build_packed_act_rowkeys(apply_fn: Callable) -> Callable:
     actor's (base_key, tick, row) fold — identical streams to the local
     paths regardless of batch composition."""
 
-    def act(params, obs, row_keys, eps):
+    def act_rows(params, obs, row_keys, eps):
         q = apply_fn(params, obs)
         return _pack_dqn(q, _rowwise_eps_greedy(q, row_keys, eps))
 
-    return jax.jit(act)
+    return jax.jit(act_rows)
 
 
 # ---------------------------------------------------------------------------

@@ -70,20 +70,20 @@ def compile_cache_dir(environ: Optional[Mapping[str, str]] = None) -> str:
 
 
 def enable_compile_cache() -> Optional[str]:
-    """Turn on JAX's persistent XLA compile cache for this process, at
-    ``compile_cache_dir()`` — TPU platform only.  This is also where an
-    entry point first touches the backend: under an explicit
+    """Install the process's compile-path record (utils/profiling; every
+    entry point calls this before its first program), then turn on JAX's
+    persistent XLA compile cache at ``compile_cache_dir()`` — TPU only.
+    Here an entry point first touches the backend: under an explicit
     ``JAX_PLATFORMS=tpu,cpu`` a chip that is absent or busy raises HERE.
 
-    On the CPU backend this is a NO-OP: XLA's CPU AOT loader can
-    nondeterministically SIGABRT when re-loading cached executables of
-    collective-dense multi-device programs (feature-string mismatch the
-    loader itself warns about; A/B-reproduced 2026-07-31 — 3/8 aborts
-    with cache vs 0/22 without on the pp pipeline step).  TPU cache
-    entries are TPU executables that never cross that loader.  Spawned
-    workers are CPU processes and run cache-free too
-    (runtime.cpu_child_env strips the variable from their exec
-    environment)."""
+    On the CPU backend the cache stays OFF: XLA's CPU AOT loader can
+    nondeterministically SIGABRT re-loading cached collective-dense
+    multi-device programs (A/B-reproduced 2026-07-31: 3/8 aborts with the
+    cache vs 0/22 without on the pp pipeline step).  Spawned workers are
+    CPU processes and run cache-free too (runtime.cpu_child_env)."""
+    from pytorch_distributed_tpu.utils.profiling import install_compile_record
+
+    install_compile_record()
     if jax.devices()[0].platform != "tpu":
         # an ambient env var set before jax import has already landed
         # in the live config

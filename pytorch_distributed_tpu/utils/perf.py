@@ -30,10 +30,11 @@ exported signals:
   is a dashboard read, not a post-mortem.
 - **Retrace detector** (``RetraceDetector``): registered hot-path jit
   programs are expected to compile during warmup and NEVER again; any
-  cache growth after the warmup mark is counted, named, and exported —
-  a recompile on the hot path is a silent throughput cliff (the
-  jit-cache no-retrace smoke in tests/test_actor_pipeline.py pins one
-  program at one point in time; this watches all of them, live).
+  executable JAX makes ready for one after the warmup mark (the
+  compile-path record of utils/profiling.py) is counted, named, and
+  exported — a recompile on the hot path is a silent throughput cliff
+  (the no-retrace smoke in tests/test_actor_pipeline.py pins one program
+  at one point in time; this watches all of them, live).
 - **Transfer audit** (``TransferAudit``): opt-in
   ``jax.transfer_guard``-based attribution of IMPLICIT host<->device
   transfers on paths that must be transfer-free (the fused learner
@@ -59,7 +60,9 @@ import os
 import threading
 import time
 import traceback
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional, Tuple
+
+from pytorch_distributed_tpu.utils import profiling
 
 # ---------------------------------------------------------------------------
 # peak FLOP/s + cost-analysis FLOPs extraction
@@ -217,66 +220,67 @@ def export_env(pp) -> None:
 # ---------------------------------------------------------------------------
 
 class RetraceDetector:
-    """Counts jit cache misses per registered hot-path program and flags
-    growth after warmup.
+    """Counts, per registered hot-path program, the executables JAX made
+    ready for it after warmup, and names the program.
 
-    Registration takes a zero-arg callable returning the program's
-    current jit cache size (``jitted._cache_size`` — the same surface
-    the actor engines already expose via ``jit_cache_size``); callables
-    returning None (server-side jits, plain functions) are skipped per
-    check, not rejected, so callers can register unconditionally.  The
-    FIRST ``check()`` is the warmup mark: everything compiled up to it
-    is expected; any growth seen by a later check is a retrace — a
+    It reads the process's compile-path record (utils/profiling
+    ``CompileRecord``: every backend span, compile or load, filed under
+    its program name), which the first registration installs for a
+    process that never called ``helpers.enable_compile_cache``.  A program is
+    registered by its jitted function or by its name, on the thread that
+    dispatches it; None (a server-side jit, an engine with no program of
+    its own) is skipped, so callers can register unconditionally.  JAX
+    reports a program by name alone, so programs are told apart by name
+    AND thread: two actors of a thread fleet each watch their own ``act``,
+    and programs that one thread dispatches need names of their own
+    (the inference server's ``act`` / ``act_rows`` / ``roll_act``).  The FIRST
+    ``check()`` is the warmup mark: everything made ready up to it is
+    expected; any executable made ready after it is a retrace — a
     shape/dtype leak paying compile latency on the hot path."""
 
     def __init__(self):
-        self._fns: Dict[str, Callable[[], Optional[int]]] = {}
-        self._warm: Dict[str, int] = {}
-        self._warmed = False
-        self.retraces = 0                 # post-warmup recompiles, total
-        self.fired: Dict[str, int] = {}   # per-program retrace counts
+        # label -> (program name, the thread that dispatches it)
+        self._names: Dict[str, Tuple[str, int]] = {}
+        self._warm: Optional[Dict[Tuple[str, int], int]] = None
+        self.retraces = 0                  # post-warmup recompiles, total
+        self.fired: Dict[str, int] = {}    # per-label retrace counts
 
-    def register(self, name: str,
-                 size_fn: Optional[Callable[[], Optional[int]]]) -> None:
-        if size_fn is not None:
-            self._fns[name] = size_fn
+    def register(self, label: str, program: Any) -> None:
+        name = (program if program is None or isinstance(program, str)
+                else getattr(program, "__name__", None))
+        if name:
+            profiling.install_compile_record()
+            self._names[label] = (name, threading.get_ident())
 
-    def _sizes(self) -> Dict[str, int]:
-        out = {}
-        for name, fn in self._fns.items():
-            try:
-                size = fn()
-            except Exception:  # noqa: BLE001 - a dead fn must not kill perf
-                size = None
-            if size is not None:
-                out[name] = int(size)
-        return out
+    def _ready(self) -> Dict[Tuple[str, int], int]:
+        record = profiling.compile_record()
+        return {key: record.ready_on(*key) if record is not None else 0
+                for key in set(self._names.values())}
 
     def mark_warm(self) -> None:
-        """Snapshot current cache sizes as the expected-compile set."""
-        self._warm = self._sizes()
-        self._warmed = True
+        """Snapshot the executables made so far as the expected set."""
+        self._warm = self._ready()
 
     def check(self) -> List[str]:
-        """Names of programs that recompiled since the last check.  The
+        """Labels of programs that recompiled since the last check.  The
         first call marks warmup instead of firing (startup compiles are
         legitimate); each recompile is counted once (the high-water
         advances)."""
-        if not self._warmed:
+        if self._warm is None:
             self.mark_warm()
             return []
-        fired = []
-        for name, size in self._sizes().items():
-            prev = self._warm.get(name)
-            if prev is None:
-                self._warm[name] = size  # late registration: new warmup
-                continue
-            if size > prev:
-                grew = size - prev
-                self.retraces += grew
-                self.fired[name] = self.fired.get(name, 0) + grew
-                self._warm[name] = size
-                fired.append(name)
+        grown = {}
+        for key, ready in self._ready().items():
+            prev = self._warm.get(key)
+            self._warm[key] = ready
+            if prev is not None and ready > prev:  # else: a late register
+                grown[key] = ready - prev
+        self.retraces += sum(grown.values())
+        fired = [label for label, key in self._names.items()
+                 if key in grown]
+        for label in fired:
+            self.fired[label] = self.fired.get(label, 0) \
+                + grown[self._names[label]]
         return fired
 
 
@@ -490,10 +494,11 @@ class PerfMonitor:
             self.flops_per_frame = None
         return self.flops_per_frame
 
-    def register_jit(self, name: str,
-                     size_fn: Optional[Callable[[], Optional[int]]]) -> None:
+    def register_jit(self, label: str, program: Any) -> None:
+        """Watch ``program`` (a jitted function or its name) for
+        recompiles after warmup (``RetraceDetector``)."""
         if self.enabled and self.params.retrace_detector:
-            self.retraces.register(name, size_fn)
+            self.retraces.register(label, program)
 
     # -- hot path ------------------------------------------------------------
 
@@ -579,7 +584,7 @@ class PerfMonitor:
                 out[f"perf/{self.prefix}/rss_peak_bytes"] = float(peak_rss)
             for k, v in device_memory_watermarks().items():
                 out[f"perf/{self.prefix}/{k}"] = v
-        if self.params.retrace_detector and self.retraces._fns \
+        if self.params.retrace_detector and self.retraces._names \
                 and (self._updates or self._frames):
             # gated on work having happened: the warmup mark must land
             # AFTER the first dispatches compiled (an anchor-only drain

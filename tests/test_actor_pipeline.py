@@ -21,6 +21,7 @@ import pytest
 
 from pytorch_distributed_tpu.config import build_options
 from pytorch_distributed_tpu.agents.actor import bounded_actor_run
+from pytorch_distributed_tpu.utils.profiling import install_compile_record
 
 
 def _opt(cfg, tmp_path, backend, **kw):
@@ -144,6 +145,20 @@ def test_pipelined_no_reorder_against_env_resets(tmp_path):
 # ---------------------------------------------------------------------------
 
 
+def _act_builds(run):
+    """``run()`` and the executables JAX made ready meanwhile for a
+    program named ``act`` (utils/profiling's compile-path record)."""
+    record = install_compile_record()
+
+    def ready():
+        p = record.program("act")
+        return p.ready if p is not None else 0
+
+    before = ready()
+    res = run()
+    return res, ready() - before
+
+
 def test_pipelined_throughput_smoke(tmp_path):
     """A few hundred pipelined ticks on CPU: (a) the jitted fused act
     compiled exactly ONCE — a traced-vs-static slip on the tick counter
@@ -152,10 +167,9 @@ def test_pipelined_throughput_smoke(tmp_path):
     precedes advance in the schedule), i.e. the overlap the pipeline
     exists for is nonzero."""
     ticks = 300
-    res = bounded_actor_run(_opt(1, tmp_path, "pipelined"), ticks)
-    h = res["harness"]
-    assert h.engine.jit_cache_size() == 1, \
-        "fused act retraced mid-run (per-tick recompilation)"
+    res, builds = _act_builds(
+        lambda: bounded_actor_run(_opt(1, tmp_path, "pipelined"), ticks))
+    assert builds == 1, "fused act retraced mid-run (per-tick recompilation)"
     t = res["timer_ms"]
     # one dispatch per tick (+ the pipeline-priming one), one sync each
     assert t["actor/time_dispatch_calls"] == ticks + 1
@@ -169,9 +183,9 @@ def test_pipelined_throughput_smoke(tmp_path):
 def test_recurrent_pipelined_no_retrace(tmp_path):
     """The recurrent fused act takes the reset mask + tick as traced
     args — neither may trigger per-tick recompiles."""
-    res = bounded_actor_run(
-        _opt(13, tmp_path, "pipelined", seq_len=8, seq_overlap=4), 80)
-    assert res["harness"].engine.jit_cache_size() == 1
+    _res, builds = _act_builds(lambda: bounded_actor_run(
+        _opt(13, tmp_path, "pipelined", seq_len=8, seq_overlap=4), 80))
+    assert builds == 1
 
 
 # ---------------------------------------------------------------------------

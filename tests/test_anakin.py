@@ -545,7 +545,7 @@ class TestAuditAndPerfPlane:
                 * drv.perf.flops_per_update
                 + rows["learner/env_frames_per_s"]
                 * drv.perf.flops_per_frame, rel=1e-6)
-            assert "anakin_rollout" in drv.perf.retraces._fns
+            assert "anakin_rollout" in drv.perf.retraces._names
             handles.learner_side.close()
         finally:
             perf.reset()

@@ -519,7 +519,7 @@ class TestDeviceActorDriver:
         assert h.env is None  # no host env objects in a device actor
         assert h.perf._frames == dispatches * 2 * 4
         assert h.perf.flops_per_frame and h.perf.flops_per_frame > 0
-        assert "device_rollout" in h.perf.retraces._fns
+        assert "device_rollout" in h.perf.retraces._names
         perf.reset()
 
 

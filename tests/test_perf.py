@@ -9,6 +9,7 @@ real trace from the running topology."""
 
 import json
 import os
+import queue
 import subprocess
 import sys
 import threading
@@ -204,9 +205,12 @@ class TestRetraceDetector:
         import jax
         import jax.numpy as jnp
 
-        f = jax.jit(lambda x: x * 2)
+        def _drill_double(x):   # a name no other program of the process has
+            return x * 2
+
+        f = jax.jit(_drill_double)
         det = perf.RetraceDetector()
-        det.register("act", f._cache_size)
+        det.register("act", f)
         f(jnp.ones(8))
         assert det.check() == []  # first check IS the warmup mark
         for _ in range(3):
@@ -218,11 +222,76 @@ class TestRetraceDetector:
         assert det.retraces == 1 and det.fired == {"act": 1}
         assert det.check() == []  # counted once; high-water advanced
 
-    def test_none_size_fns_are_skipped(self):
+    def test_none_and_unbuilt_programs_are_skipped(self):
         det = perf.RetraceDetector()
         det.register("server-side", None)
-        det.register("batched", lambda: None)
+        det.register("never-built", "_drill_no_such_program")
         assert det.check() == [] and det.check() == []
+        assert det.retraces == 0
+
+    def test_a_program_registered_by_name_and_a_late_register(self):
+        import jax
+        import jax.numpy as jnp
+
+        def _drill_by_name(x):
+            return x - 1
+
+        f = jax.jit(_drill_by_name)
+        det = perf.RetraceDetector()
+        det.check()                          # warmup mark, nothing yet
+        det.register("late", "_drill_by_name")
+        f(jnp.ones(2))
+        assert det.check() == []             # a late register: new warmup
+        f(jnp.ones(5))
+        assert det.check() == ["late"] and det.fired == {"late": 1}
+
+    def test_same_named_programs_of_two_threads_fire_apart(self):
+        """A thread fleet: each actor builds its own ``act`` and watches it
+        from its own thread.  An actor that compiles after the other's
+        warmup mark, or recompiles, fires its own detector only."""
+        import threading
+
+        import jax
+        import jax.numpy as jnp
+
+        def _drill_twin():
+            def _drill_twin_act(x):   # one name, two programs
+                return x * 3
+            return jax.jit(_drill_twin_act)
+
+        dets = [perf.RetraceDetector(), perf.RetraceDetector()]
+        fns = [_drill_twin(), _drill_twin()]
+        # two live actor threads, each running what it is handed
+        inbox = [queue.Queue(), queue.Queue()]
+        outbox = queue.Queue()
+
+        def actor(i):
+            while (size := inbox[i].get()) is not None:
+                dets[i].register("act", fns[i])
+                fns[i](jnp.ones(size))
+                outbox.put(dets[i].check())
+
+        threads = [threading.Thread(target=actor, args=(i,))
+                   for i in range(2)]
+        for t in threads:
+            t.start()
+
+        def step(i, size):
+            inbox[i].put(size)
+            return outbox.get(timeout=60)
+
+        try:
+            assert step(0, 8) == []               # actor 0 warms
+            assert step(1, 8) == []               # actor 1 compiles after
+            assert step(0, 8) == []               # ... not actor 0's
+            assert step(1, 6) == ["act"]          # actor 1 recompiles
+            assert step(0, 8) == [] and dets[0].retraces == 0
+            assert dets[1].fired == {"act": 1}
+        finally:
+            for q in inbox:
+                q.put(None)
+            for t in threads:
+                t.join()
 
     def test_monitor_exports_retrace_count(self):
         import jax
@@ -230,8 +299,11 @@ class TestRetraceDetector:
 
         m = perf.PerfMonitor("actor-0", PerfParams(
             enabled=True, memory_watermarks=False), prefix="actor")
-        f = jax.jit(lambda x: x + 1)
-        m.register_jit("act", f._cache_size)
+        def _drill_inc(x):
+            return x + 1
+
+        f = jax.jit(_drill_inc)
+        m.register_jit("act", f)
         f(jnp.ones(8))
         m.note_frames(1)
         m.drain(now=1.0)  # warmup mark (gated on work having happened)
@@ -563,7 +635,9 @@ class TestPerfPlaneAcceptance:
                     "learner/flops_per_update", "learner/replay_ratio",
                     "actor/env_frames_per_s",
                     "perf/learner/rss_bytes", "perf/learner/rss_peak_bytes",
-                    "perf/actor/rss_bytes"):
+                    "perf/actor/rss_bytes",
+                    # the compile record, written once after warm-up
+                    "learner/setup_trace_s", "learner/setup_traces"):
             assert tag in by_tag, \
                 f"{tag} missing (have {sorted(by_tag)[:40]}...)"
         assert any(r["value"] > 0 for r in by_tag["learner/mfu"])
@@ -715,7 +789,7 @@ class TestDeviceRolloutPerfPlane:
         roll, w = rollout["roll"], rollout["w"]
         m = perf.PerfMonitor("actor-drill", PerfParams(
             enabled=True, memory_watermarks=False), prefix="actor")
-        m.register_jit("device_rollout", roll._cache_size)
+        m.register_jit("device_rollout", roll)
         key = jnp.asarray(np.zeros(2, np.uint32))
         eps = jnp.zeros((2,), jnp.float32)
         carry, _ = roll(w, rollout["carry"](), key, jnp.int32(0), eps)
