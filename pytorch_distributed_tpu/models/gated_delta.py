@@ -67,7 +67,10 @@ where a later block meets an earlier one both factors are taken against
 overflows, and a factor that underflows is no larger than the true value).
 The scan over chunk states is the scalar-gated rule's; the inverse is formed
 block by block (``unit_lower_inverse_by_blocks``), because this rule's
-published decays are slow and ``A`` is then no small matrix.
+published decays are slow and ``A`` is then no small matrix.  ON A TPU
+``kda_chunked`` runs its window as a Pallas kernel pair of its own
+(ops/pallas_kda.py), under the same rule; what is written here is again the
+XLA form and the kernels' oracle.
 """
 
 from __future__ import annotations
@@ -75,7 +78,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from pytorch_distributed_tpu.ops import pallas_gated_delta
+from pytorch_distributed_tpu.ops import pallas_gated_delta, pallas_kda
 from pytorch_distributed_tpu.utils.profiling import (
     SCOPE_GDN, SCOPE_GDN_CHUNK, SCOPE_KDA, SCOPE_KDA_CHUNK,
 )
@@ -311,18 +314,33 @@ def intra_chunk_products(q, k, gamma, sub: int, cd):
     return Ms[0][..., 0, :, :], Ms[1][..., 0, :, :]
 
 
-def kda_chunked(q, k, v, g, beta, chunk: int, sub: int, cd=jnp.bfloat16):
+def kda_chunked(q, k, v, g, beta, chunk: int, sub: int, cd=jnp.bfloat16,
+                kernel: str = "auto"):
     """The channel-gated recurrence over a window from a zero state, chunk by
     chunk.  q, k (b, T, h, d_k); v (b, T, h, d_v); g (b, T, h, d_k) and beta
     (b, T, h) float32; T a whole number of chunks of ``chunk`` positions,
     each of whole sub-blocks of ``sub`` (pad with g = beta = 0).  Returns (o
     (b, T, h, d_v) float32, the state after the last position (b, h, d_k,
-    d_v) float32)."""
+    d_v) float32).
+
+    ``kernel`` as ``gated_delta_chunked``'s: on ONE TPU chip, where the
+    shapes fit their tiles, the window runs as the Pallas kernel pair of
+    ops/pallas_kda.py (``kda_chunk_fwd`` / ``kda_chunk_bwd`` in a trace); the
+    XLA form below everywhere else, and it is the kernels' oracle
+    (tests/test_gdn_kernel.py)."""
     with jax.named_scope(SCOPE_KDA_CHUNK):
         b, T, h, dk = k.shape
         dv = v.shape[-1]
         L, nc = chunk, T // chunk
         assert nc * L == T, (T, chunk)
+        assert kernel in ("auto", "xla", "interpret"), kernel
+        if kernel == "auto" and (
+                jax.default_backend() != "tpu" or jax.device_count() > 1
+                or not pallas_kda.fits(L, sub, dk, dv, h)):
+            kernel = "xla"
+        if kernel != "xla":
+            return pallas_kda.kda_window(q, k, v, g, beta, L, sub, cd,
+                                         interpret=kernel == "interpret")
         heads = lambda t: jnp.moveaxis(                    # (b,c,h,L,.)
             t.astype(F32).reshape(b, nc, L, h, -1), 2, 3)
         tril = jnp.tril(jnp.ones((L, L), bool))
