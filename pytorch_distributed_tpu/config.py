@@ -61,11 +61,13 @@ CONFIGS = [
     ["r2d2",      "pong-sim",  "pong",        "device-sequence", "dtqn-hybrid"],# 20 state-space / sparse-expert / grouped-query trunk (models/hybrid.py)
     ["r2d2",      "pong-sim",  "pong",        "device-sequence", "dtqn-hybrid"],# 21 gated-delta-rule / 512-expert / gated-attention trunk (ROW_DEFAULTS)
     ["r2d2",      "pong-sim",  "pong",        "device-sequence", "dtqn-hybrid"],# 22 channel-gated delta rule / latent attention / 256-expert trunk (ROW_DEFAULTS)
+    ["r2d2",      "pong-sim",  "pong",        "device-sequence", "dtqn-hybrid"],# 23 gated short convolution / grouped-query / 32-expert trunk (ROW_DEFAULTS)
 ]
 
 # What a row sets beside its five selectors, before the caller's overrides.
 ROW_DEFAULTS = {21: {"hybrid_preset": "qwen3-next-4"},
-                22: {"hybrid_preset": "kimi-linear-5"}}
+                22: {"hybrid_preset": "kimi-linear-5"},
+                23: {"hybrid_preset": "lfm2-moe-5"}}
 
 
 # ---------------------------------------------------------------------------
@@ -293,9 +295,10 @@ class ModelParams:
     compute_dtype: str = "bfloat16"
     # dtqn-hybrid: which frozen preset of models/hybrid.py PRESETS holds the
     # trunk's layer pattern, widths and mixer options ("nemotron-h-9": row
-    # 20's published ones; "qwen3-next-4" / "kimi-linear-5": row 21's and
-    # row 22's, set by ROW_DEFAULTS; "tiny" / "tiny-qwen" / "tiny-kimi": CPU
-    # tests of each).  They live there and nowhere else.
+    # 20's published ones; "qwen3-next-4" / "kimi-linear-5" / "lfm2-moe-5":
+    # row 21's, 22's and 23's, set by ROW_DEFAULTS; "tiny" / "tiny-qwen" /
+    # "tiny-kimi" / "tiny-lfm2": CPU tests of each).  They live there and
+    # nowhere else.
     hybrid_preset: str = "nemotron-h-9"
 
 

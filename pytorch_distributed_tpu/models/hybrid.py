@@ -1,14 +1,16 @@
 """Hybrid sequence Q-network: a layer PATTERN of state-space (Mamba-2),
-gated-delta-rule (a head's or a key channel's gate), sparse-expert, dense
-feed-forward, grouped-query and latent attention blocks over one frame per
-position (model_type ``dtqn-hybrid``, CONFIGS rows 20, 21 and 22).
+gated-delta-rule (a head's or a key channel's gate), gated short-convolution,
+sparse-expert, dense feed-forward, grouped-query and latent attention blocks
+over one frame per position (model_type ``dtqn-hybrid``, CONFIGS rows 20 to
+23).
 
 The trunk is the first layers of a published hybrid language model at their
 published widths (``PRESETS["nemotron-h-9"]``: layers 0-8, CONFIGS row 20,
 benchmark/configs/nemotron_h_pong.json; ``PRESETS["qwen3-next-4"]``: layers
 0-3, eight blocks, CONFIGS row 21, benchmark/configs/qwen3_next_pong.json;
 ``PRESETS["kimi-linear-5"]``: layers 0-4, ten blocks, CONFIGS row 22,
-benchmark/configs/kimi_linear_pong.json;
+benchmark/configs/kimi_linear_pong.json; ``PRESETS["lfm2-moe-5"]``: layers
+1-5, ten blocks, CONFIGS row 23, benchmark/configs/lfm2_moe_pong.json;
 source and every departure in those files), with the token embedding and LM
 head replaced by the repo's sequence-family contract (models/dtqn.py): one
 84x84 uint8 frame a position -> Dense -> trunk -> final RMSNorm ->
@@ -46,6 +48,12 @@ mixer(RMSNorm(x))``, no biases but Mamba's conv's; the norm's scale is
   expanded keys and values are 32 x 320) and attends in the absorbed form
   ``(W_kvb_k^T q_n) . c + q_r . k_r``, values rebuilt from the weighted
   latent.
+- ``C``  gated short convolution: ``[B | C | x] = u W_in`` (three of
+  ``d_model``); ``z = B * x``; ``y_t = sum_j w_j * z_{t-(K-1)+j}``, a causal
+  depth-wise conv of ``conv_kernel`` taps, zero before t = 0, no bias and no
+  activation; ``out = (C * y) W_out``, under ``model.sconv`` with the
+  gates and taps under ``sconv.mix``.  The actor carries the last K - 1
+  rows of ``z``.
 - ``F``  a dense SwiGLU block ``(silu(u W_gate) * u W_up) W_down``.
 - ``*``  causal grouped-query attention in query blocks: no ``(B, heads, T,
   T)`` array exists.  Position-free as published for the first preset;
@@ -56,7 +64,9 @@ mixer(RMSNorm(x))``, no biases but Mamba's conv's; the norm's scale is
   from the doubled query projection (``attn_gate``).
 - ``E``  sigmoid-scored experts: top-k of ``s + b_sel`` over ALL experts,
   weights ``s`` (without ``b_sel``) normalised, times ``route_scale``;
-  ``relu(u W_up)^2 W_down`` per expert, plus one shared expert.  The layer
+  ``relu(u W_up)^2 W_down`` per expert, plus one shared expert (none where
+  ``shared_width`` is 0; with ``route_eps`` the weights are divided by their
+  sum + ``route_eps``).  The layer
   is TOLD WHICH EXPERTS IT HOLDS (``first_expert``, ``experts_held``): it
   routes over all of them and computes its own experts' part for the rows
   routed to them, sorted by expert and multiplied as grouped matmuls (on a
@@ -78,14 +88,15 @@ mixer(RMSNorm(x))``, no biases but Mamba's conv's; the norm's scale is
   and the shared expert the same, behind ``sigmoid(u . shared_gate)`` where
   the preset has ``shared_expert_gate``.  Router and expert form are chosen
   apart: the third preset routes by sigmoid scores with ``b_sel`` over
-  SwiGLU experts and an ungated SwiGLU shared expert.
+  SwiGLU experts and an ungated SwiGLU shared expert, the fourth the same
+  with no shared expert.
 
 Contracts shared with the other sequence families (recurrent actor,
 evaluator, sequence learner): ``window_q(frames (B, T, H, W))`` is the
 learner's one causal pass, zero state at position 0; ``__call__(obs,
 carry)`` acts one step through a carry of (conv tails, SSM or delta-rule
-states, key / value caches or a latent ring, count) whose leaves all lead
-with the batch dimension;
+states, short-convolution tails, key / value caches or a latent ring, count)
+whose leaves all lead with the batch dimension;
 ``state_for_segment`` stores the 1-dim placeholder of every ``dtqn*``
 model.  bfloat16 matmuls; float32 parameters, router, softmax, norms,
 ``dt``, ``A`` and scan state.  Every mixer runs under ``jax.checkpoint``
@@ -111,7 +122,7 @@ from pytorch_distributed_tpu.ops.sequence_losses import AUX_LOSS_KEY
 from pytorch_distributed_tpu.utils.profiling import (
     SCOPE_ATTN, SCOPE_EMBED, SCOPE_GDN, SCOPE_HEAD, SCOPE_KDA, SCOPE_MLA,
     SCOPE_MLP, SCOPE_MOE, SCOPE_MOE_EXPERTS, SCOPE_MOE_ROUTE,
-    SCOPE_MOE_SHARED, SCOPE_SSM,
+    SCOPE_MOE_SHARED, SCOPE_SCONV, SCOPE_SCONV_MIX, SCOPE_SSM,
 )
 
 F32 = jnp.float32
@@ -121,9 +132,9 @@ F32 = jnp.float32
 class HybridPreset:
     """Every width of the trunk, in ONE place."""
 
-    pattern: str                 # one letter a layer: M, D, K, L, F, E or *
+    pattern: str                 # one letter a layer: M, D, K, C, L, F, E or *
     d_model: int
-    conv_kernel: int             # M and D: the causal depth-wise conv
+    conv_kernel: int             # M, D, K and C: the causal depth-wise conv
     # *: grouped-query attention
     attn_heads: int
     kv_heads: int
@@ -133,7 +144,7 @@ class HybridPreset:
     n_experts: int               # routed over
     top_k: int
     expert_width: int
-    shared_width: int
+    shared_width: int            # 0: no shared expert
     route_scale: float
     experts_held: int            # computed here ...
     first_expert: int            # ... starting at this one
@@ -176,6 +187,8 @@ class HybridPreset:
     mla_v: int = 0               # L: a head's value
     mla_latent: int = 0
     mlp_width: int = 0           # F
+    # what the fourth published trunk adds
+    route_eps: float = 0.0       # E, sigmoid: weights / (their sum + this)
     # the step metrics of "nemotron-h-9" are pinned with its lowered step
     # (CHANGES.md, PR 31); a preset made since also counts the rows its
     # grouped matmuls were handed (learner/moe_rows_computed)
@@ -246,6 +259,18 @@ PRESETS: Dict[str, HybridPreset] = {
         n_experts=256, top_k=8, expert_width=1024, shared_width=1024,
         route_scale=2.446, experts_held=8, first_expert=0, norm_eps=1e-5,
         gated_experts=True, shared_expert_gate=False, run_headroom=4),
+    # layers 1-5 of the published 24: the second leading dense layer (a
+    # short-convolution mixer and the dense SwiGLU) and one whole period of
+    # what follows (attention, then three short-convolution mixers, an expert
+    # block after each: ten blocks), every width as published; 8 of the 32
+    # experts held: one of the 4 chips that share each layer
+    "lfm2-moe-5": HybridPreset(
+        pattern="CF*ECECECE", d_model=2048, conv_kernel=3,
+        attn_heads=32, kv_heads=8, attn_head_dim=64, attn_block=256,
+        qk_norm=True, rotary_dim=64, rope_theta=1e6, mlp_width=7168,
+        n_experts=32, top_k=4, expert_width=1792, shared_width=0,
+        route_scale=1.0, route_eps=1e-6, experts_held=8, first_expert=0,
+        gated_experts=True, router="sigmoid", norm_eps=1e-5),
     # CPU tests: every mechanism, no width
     "tiny": HybridPreset(
         pattern="ME*E", d_model=32,
@@ -271,6 +296,13 @@ PRESETS: Dict[str, HybridPreset] = {
         n_experts=16, top_k=3, expert_width=16, shared_width=16,
         route_scale=2.446, experts_held=4, first_expert=0,
         gated_experts=True, shared_expert_gate=False),
+    "tiny-lfm2": HybridPreset(
+        pattern="CF*ECE", d_model=32, conv_kernel=3,
+        attn_heads=4, kv_heads=2, attn_head_dim=8, attn_block=4,
+        qk_norm=True, rotary_dim=8, rope_theta=1e6, mlp_width=48,
+        n_experts=16, top_k=4, expert_width=16, shared_width=0,
+        route_scale=1.0, route_eps=1e-6, experts_held=4, first_expert=0,
+        gated_experts=True, router="sigmoid", norm_eps=1e-5),
 }
 
 
@@ -500,12 +532,17 @@ def gdn_step(p, u, tail, S, c: HybridPreset, cd):
         return _gdn_out(p, o, z, c, cd), taps[:, 1:], S
 
 
-def _conv_silu(x, w):
-    """Causal depth-wise conv + silu over (b, T, c): tap j reads position t
-    - (K-1) + j."""
+def _causal_conv(x, w):
+    """Causal depth-wise conv over (b, T, c), zero before t = 0: tap j reads
+    position t - (K-1) + j."""
     K, T = w.shape[0], x.shape[1]
     xp = jnp.pad(x, ((0, 0), (K - 1, 0), (0, 0)))
-    return jax.nn.silu(sum(xp[:, j:j + T] * w[j] for j in range(K)))
+    return sum(xp[:, j:j + T] * w[j] for j in range(K))
+
+
+def _conv_silu(x, w):
+    """Causal depth-wise conv + silu over (b, T, c)."""
+    return jax.nn.silu(_causal_conv(x, w))
 
 
 def _kda_inputs(p, u, q, k, v, c: HybridPreset, cd):
@@ -564,6 +601,28 @@ def kda_step(p, u, tails, S, c: HybridPreset, cd):
             for x, t in zip("qkv", taps)), c, cd)
         o, S = gated_delta_step(q, k, v, g, beta, S)
         return _kda_out(p, u, o, c, cd), [t[:, 1:] for t in taps], S
+
+
+def short_conv_window(p, u, c: HybridPreset, cd):
+    """One C mixer over (b, T, d) normed input, zero before t = 0: ``[B | C
+    | x] = u W_in``, the causal depth-wise conv of ``B * x``, gated by ``C``,
+    ``W_out``.  Out (b, T, d) float32."""
+    with jax.named_scope(SCOPE_SCONV):
+        gate_b, gate_c, x = jnp.split(_mm(u, p["w_in"], cd), 3, axis=-1)
+        with jax.named_scope(SCOPE_SCONV_MIX):
+            y = gate_c * _causal_conv(gate_b * x, p["conv_w"])
+        return _mm(y, p["w_out"], cd)
+
+
+def short_conv_step(p, u, tail, c: HybridPreset, cd):
+    """One position: u (b, d); tail (b, K-1, d) the last K - 1 rows of ``B
+    * x`` the conv reads."""
+    with jax.named_scope(SCOPE_SCONV):
+        gate_b, gate_c, x = jnp.split(_mm(u, p["w_in"], cd), 3, axis=-1)
+        with jax.named_scope(SCOPE_SCONV_MIX):
+            taps = jnp.concatenate([tail, (gate_b * x)[:, None]], axis=1)
+            y = gate_c * jnp.einsum("bkc,kc->bc", taps, p["conv_w"])
+        return _mm(y, p["w_out"], cd), taps[:, 1:]
 
 
 def mlp_block(p, u, cd):
@@ -749,7 +808,10 @@ def route(p, u, c: HybridPreset):
         _, chosen = jax.lax.top_k(s + jax.lax.stop_gradient(p["b_sel"]),
                                   c.top_k)
         w = jnp.take_along_axis(s, chosen, axis=-1)
-        w = w / jnp.sum(w, axis=-1, keepdims=True) * c.route_scale
+        total = jnp.sum(w, axis=-1, keepdims=True)
+        if c.route_eps:
+            total = total + c.route_eps
+        w = w / total * c.route_scale
         load = jnp.sum(jax.nn.one_hot(chosen, c.n_experts, dtype=jnp.int32),
                        axis=(0, 1))
         return chosen.astype(jnp.int32), w, load
@@ -900,11 +962,11 @@ def routed_experts(p, u, chosen, w, sizes, c: HybridPreset, cd,
 
 
 def moe_layer(p, u, c: HybridPreset, cd, kernel="auto"):
-    """One E mixer over (N, d) normed tokens: the shared expert plus the
-    routed experts held here.  Returns (out (N, d) float32, the tokens
-    that chose each of ALL experts (E,) int32, the layer's load-balancing
-    loss or None where the router has none); the rows of the experts held
-    here are ``held_load`` of the load."""
+    """One E mixer over (N, d) normed tokens: the shared expert (where the
+    preset has one) plus the routed experts held here.  Returns (out (N, d)
+    float32, the tokens that chose each of ALL experts (E,) int32, the
+    layer's load-balancing loss or None where the router has none); the rows
+    of the experts held here are ``held_load`` of the load."""
     with jax.named_scope(SCOPE_MOE):
         if c.router == "softmax":
             chosen, w, load, aux = route_aux(p, u, c)
@@ -913,6 +975,8 @@ def moe_layer(p, u, c: HybridPreset, cd, kernel="auto"):
         with jax.named_scope(SCOPE_MOE_EXPERTS):
             routed = routed_experts(p, u, chosen, w, held_load(load, c), c,
                                     cd, kernel)
+        if not c.shared_width:
+            return routed, load, aux
         with jax.named_scope(SCOPE_MOE_SHARED):
             if c.gated_experts:
                 shared = _mm(jax.nn.silu(_mm(u, p["w_shared_gate"], cd))
@@ -1092,6 +1156,10 @@ def layer_param_specs(kind: str, c: HybridPreset):
             "w_kvb": (_lecun, (c.mla_latent, h * (c.mla_nope + c.mla_v))),
             "w_o": (_lecun, (h * c.mla_v, d)),
         }
+    if kind == "C":
+        return {"w_in": (_lecun, (d, 3 * d)),
+                "conv_w": (_lecun, (c.conv_kernel, d)),
+                "w_out": (_lecun, (d, d))}
     if kind == "F":
         return {"w_gate": (_lecun, (d, c.mlp_width)),
                 "w_up": (_lecun, (d, c.mlp_width)),
@@ -1109,6 +1177,8 @@ def layer_param_specs(kind: str, c: HybridPreset):
             specs["w_gate"] = experts(d, c.expert_width)
         specs.update(w_up=experts(d, c.expert_width),
                      w_down=experts(c.expert_width, d))
+        if not c.shared_width:
+            return specs
         if gated:
             specs["w_shared_gate"] = shared(d, c.shared_width)
         specs.update(w_shared_up=shared(d, c.shared_width),
@@ -1117,7 +1187,7 @@ def layer_param_specs(kind: str, c: HybridPreset):
             specs["shared_gate"] = shared(d, 1)
         return specs
     raise ValueError(f"unknown layer kind {kind!r} in a hybrid pattern "
-                     f"(known: M, D, K, *, L, F, E)")
+                     f"(known: M, D, K, *, L, F, E, C)")
 
 
 class _Layer(nn.Module):
@@ -1227,12 +1297,16 @@ class HybridQModel(nn.Module):
                     out, S, kept = kda_window(p, norm(p, x), c, cd)
                     return x + out.astype(cd), S, kept
                 x, states[i], kda_decay[i] = mix(p, x)
-            elif layer.kind in "LF":
+            elif layer.kind in "LFC":
                 @jax.checkpoint
                 def mix(p, x, kind=layer.kind):
                     u = norm(p, x)
-                    out = mla_window(p, u, c, cd) if kind == "L" \
-                        else mlp_block(p, u, cd)
+                    if kind == "L":
+                        out = mla_window(p, u, c, cd)
+                    elif kind == "F":
+                        out = mlp_block(p, u, cd)
+                    else:
+                        out = short_conv_window(p, u, c, cd)
                     return x + out.astype(cd)
                 x = mix(p, x)
             else:
@@ -1266,9 +1340,9 @@ class HybridQModel(nn.Module):
     def zero_carry(self, batch: int):
         """A flat tuple, every leaf leading with the batch dimension: per M
         or D layer (conv tail, float32 state), per K layer (the conv tails
-        of q, k and v, float32 state), per * layer (keys, values), per L
-        layer one ring of latents (W, latent + rope), then the count of
-        positions seen."""
+        of q, k and v, float32 state), per C layer (its conv tail), per *
+        layer (keys, values), per L layer one ring of latents (W, latent +
+        rope), then the count of positions seen."""
         c, out = self.preset, []
         for kind in c.pattern:
             if kind == "M":
@@ -1285,6 +1359,8 @@ class HybridQModel(nn.Module):
                         for _ in "qkv"]
                 out += [jnp.zeros((batch, c.kda_heads, c.kda_head_dim,
                                    c.kda_head_dim), F32)]
+            elif kind == "C":
+                out += [jnp.zeros((batch, c.conv_kernel - 1, c.d_model), F32)]
             elif kind == "*":
                 kv = (batch, self.act_window, c.kv_heads, c.attn_head_dim)
                 out += [jnp.zeros(kv, self.compute_dtype),
@@ -1321,6 +1397,9 @@ class HybridQModel(nn.Module):
                 out, carry[at:at + 3], carry[at + 3] = kda_step(
                     p, u, carry[at:at + 3], carry[at + 3], c, cd)
                 at += 4
+            elif layer.kind == "C":
+                out, carry[at] = short_conv_step(p, u, carry[at], c, cd)
+                at += 1
             elif layer.kind == "*":
                 out, carry[at], carry[at + 1] = attention_step(
                     p, u, carry[at], carry[at + 1], count, c, cd)

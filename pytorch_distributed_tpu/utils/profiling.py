@@ -83,13 +83,15 @@ SCOPE_KDA_CHUNK = "kda.chunk"         # its recurrence proper, as gdn.chunk
 SCOPE_ATTN = "model.attn"
 SCOPE_MLA = "model.mla"               # latent attention
 SCOPE_MLP = "model.mlp"               # a dense feed-forward block
+SCOPE_SCONV = "model.sconv"           # gated short convolution; holds:
+SCOPE_SCONV_MIX = "sconv.mix"         # its gate-conv-gate middle
 SCOPE_MOE = "model.moe"               # holds the three below
 SCOPE_MOE_ROUTE = "moe.route"
 SCOPE_MOE_EXPERTS = "moe.experts"
 SCOPE_MOE_SHARED = "moe.shared"
 SCOPE_HEAD = "model.head"
 MODEL_SCOPES = (SCOPE_EMBED, SCOPE_SSM, SCOPE_GDN, SCOPE_KDA, SCOPE_ATTN,
-                SCOPE_MLA, SCOPE_MLP, SCOPE_MOE, SCOPE_HEAD)
+                SCOPE_MLA, SCOPE_MLP, SCOPE_SCONV, SCOPE_MOE, SCOPE_HEAD)
 
 
 @functools.lru_cache(maxsize=None)

@@ -615,9 +615,10 @@ def test_the_models_parts_are_named_inside_checkpoint_and_scan(tmp_path):
                        jnp.float32(0.6)).as_text(debug_info=True)
     # model.gdn is the second trunk's (tests/test_gated_delta_update.py),
     # model.kda, model.mla and model.mlp the third's
-    # (tests/test_kimi_trunk_update.py)
+    # (tests/test_kimi_trunk_update.py), model.sconv the fourth's
+    # (tests/test_lfm2_trunk_update.py)
     later = (profiling.SCOPE_GDN, profiling.SCOPE_KDA, profiling.SCOPE_MLA,
-             profiling.SCOPE_MLP)
+             profiling.SCOPE_MLP, profiling.SCOPE_SCONV)
     for scope in tuple(s for s in profiling.MODEL_SCOPES
                        if s not in later) + (
             profiling.SCOPE_MOE_ROUTE, profiling.SCOPE_MOE_EXPERTS,
