@@ -844,18 +844,30 @@ def _tile(dim: int, most: int) -> int:
     return next((t for t in range(most, 0, -128) if dim % t == 0), most)
 
 
+def expert_kernel(kernel: str = "auto") -> str:
+    """The grouped matmul ``kernel`` names: "auto" is "pallas" on a TPU
+    backend and "xla" elsewhere; "xla", "pallas" and "interpret" stand.
+    Resolved once a program, so that the rows handed to the kernel and the
+    counter of them (``moe_stats``) follow one choice."""
+    if kernel == "auto":
+        return "pallas" if jax.default_backend() == "tpu" else "xla"
+    assert kernel in ("xla", "pallas", "interpret"), kernel
+    return kernel
+
+
 def grouped_dot(x, w, sizes, kernel: str = "auto"):
     """``x[rows of group g] @ w[g]`` for rows sorted by group: x (R, k), w
-    (H, k, n), sizes (H,) summing to R; float32 out.  On a TPU the Pallas
-    grouped matmul that ships with JAX (``megablox.gmm``, kernels ``gmm``
-    and ``tgmm`` in a trace), at tiles of 256 rows by up to 896 (the rows:
-    at row 21's 160-row groups 128 and 512 were both slower): the
-    compiler's own ``jax.lax.ragged_dot`` kernel, at 512 x 128 x 128 tiles,
-    took 3.3 times as long (PERF.md section 6).  Elsewhere
-    ``jax.lax.ragged_dot``.  ``kernel``: "auto", "xla" or "interpret" (the
-    Pallas kernel under the interpreter: tests)."""
-    if kernel == "auto":
-        kernel = "pallas" if jax.default_backend() == "tpu" else "xla"
+    (H, k, n), sizes (H,) summing to at most R (the rows past their sum
+    belong to no group, and what stands there in the output is undefined);
+    float32 out.  On a TPU the Pallas grouped matmul that ships with JAX
+    (``megablox.gmm``, kernels ``gmm`` and ``tgmm`` in a trace), at tiles
+    of 256 rows by up to 896 (the rows: at row 21's 160-row groups 128 and
+    512 were both slower), which visits only the row tiles its groups
+    cover: the compiler's own ``jax.lax.ragged_dot`` kernel, at 512 x 128
+    x 128 tiles, took 3.3 times as long (PERF.md section 6).  Elsewhere
+    ``jax.lax.ragged_dot``.  ``kernel``: as ``expert_kernel`` ("interpret":
+    the Pallas kernel under the interpreter, tests)."""
+    kernel = expert_kernel(kernel)
     if kernel == "xla":
         return jax.lax.ragged_dot(x, w, sizes, preferred_element_type=F32)
     from jax.experimental.pallas.ops.tpu.megablox import gmm
@@ -867,15 +879,16 @@ def grouped_dot(x, w, sizes, kernel: str = "auto"):
 
 def _grouped_ffn(p, u, tok, w_pair, sizes, cd, kernel="auto"):
     """The held experts' part for a run of sorted rows ``tok`` (R,) (rows
-    of one expert adjacent, ``sizes`` rows each, summing to R; the rows
-    that belong to no expert here point at token N): two grouped matmuls
-    (three where the experts are gated: ``w_gate`` beside ``w_up``),
-    then each row's result is added to its token.  (N, d) float32.  Rows
-    of no token are zero going in, and each grouped matmul's output is
-    masked before anything reads it, which masks its cotangents too: the
-    chip's kernels leave rows outside every group unwritten, and what
-    stands there must reach neither a token nor, times a zero, a
-    gradient."""
+    of one expert adjacent, ``sizes`` rows each; the rows that belong to
+    no expert here point at token N, and may lie outside every group where
+    ``sizes`` sum to less than R): two grouped matmuls (three where the
+    experts are gated: ``w_gate`` beside ``w_up``), then each row's result
+    is added to its token.  (N, d) float32.  Rows of no token are zero
+    going in, and each grouped matmul's output is masked before anything
+    reads it, which masks its cotangents too (``where``'s, not a product's):
+    the chip's kernels leave rows outside every group unwritten, forward
+    and in the backward's ``gmm``, and what stands there, NaN or Inf
+    included, must reach neither a token nor, times a zero, a gradient."""
     N = u.shape[0]
     valid = (tok < N)[:, None]
     keep = lambda t: jnp.where(valid, t, 0.0)
@@ -893,11 +906,13 @@ def expert_runs(c: HybridPreset, pairs: int) -> Tuple[int, ...]:
     held experts in: the first holds twice the rows a balanced router sends
     here (``pairs * experts_held / n_experts``; ``run_headroom`` times: four
     where 8 experts of 256 are held, whose seeded router sends them up to
-    twice their share, so that no update of a window needs a second run and
-    a step's time does not depend on the seed), each further one as many
-    as all before it, until every pair has a place: whatever the skew no
-    row is dropped, and memory is the largest run's.  In whole tiles of
-    the kernel's 256 rows (of 8 where a run is shorter)."""
+    twice their share, so that no update of a window needs a second run),
+    each further one as many as all before it, until every pair has a
+    place: whatever the skew no row is dropped, and memory is the largest
+    run's.  In whole tiles of the kernel's 256 rows (of 8 where a run is
+    shorter).  These are the shapes of the sort, gathers and scatter-adds
+    around the grouped matmuls; what the matmuls compute of a run is
+    ``routed_experts``' rule."""
     first = -(-c.run_headroom * pairs * c.experts_held // c.n_experts)
     first = -(-first // 256) * 256 if first >= 256 else -(-first // 8) * 8
     runs = [first]
@@ -912,18 +927,19 @@ def routed_experts(p, u, chosen, w, sizes, c: HybridPreset, cd,
     held here, (N, d) float32; ``sizes`` (H,) the rows each held expert
     received.  Dropless at any skew: the (token, choice) pairs are sorted
     by held expert, the pairs of absent experts last, and go through the
-    grouped matmuls in runs (``expert_runs``); a run that starts past the
-    last row routed here is skipped, a run that holds one is computed
-    WHOLE (its tail of no expert rides as zero rows of the last expert).
-    Device work follows the rows routed here in steps of a run, and below
-    the first run's end a step's time does not follow the router: Adam's
-    first updates move a layer's rows by tens of per cent from one update
-    to the next (PERF.md section 6).  The price is the first run's empty
-    tail; handing the kernel each expert's true count instead (drop the
-    ``at[-1].add``: rows outside every group are masked already) saves
-    that and makes every step as long as its routing.  The runs are
-    unrolled: a ``scan`` over them would save each run's inputs, the
-    weights among them, once a run for the backward pass."""
+    grouped matmuls in runs of static length (``expert_runs``); a run that
+    starts past the last row routed here is skipped.  Of a run that holds
+    one, the Pallas kernels ("pallas", "interpret": ``expert_kernel``) are
+    handed each held expert's TRUE rows in it: the run's tail past the last
+    routed row belongs to no group, and they launch no tile for it, so
+    their work follows the rows routed here tile by tile (rows outside
+    every group are masked: ``_grouped_ffn``).  ``jax.lax.ragged_dot``
+    ("xla": the CPU, the actor's step, the oracle) has static shapes and
+    saves nothing by that, so there the tail rides as zero rows of the last
+    expert and the run is computed whole.  The runs are unrolled: a
+    ``scan`` over them would save each run's inputs, the weights among
+    them, once a run for the backward pass."""
+    kernel = expert_kernel(kernel)
     N, H, k = u.shape[0], c.experts_held, c.top_k
     local = chosen.reshape(-1) - c.first_expert                # (N k,)
     key = jnp.where((local >= 0) & (local < H), local, H)
@@ -945,7 +961,8 @@ def routed_experts(p, u, chosen, w, sizes, c: HybridPreset, cd,
                     jax.named_scope(SCOPE_MOE_EXPERTS):
                 here = jnp.clip(jnp.minimum(ends, lo + R)
                                 - jnp.maximum(ends - sizes, lo), 0)
-                here = here.at[-1].add(R - jnp.sum(here))
+                if kernel == "xla":
+                    here = here.at[-1].add(R - jnp.sum(here))
                 return _grouped_ffn(
                     p, u, jnp.where(lo + jnp.arange(R) < rows,
                                     tok[lo:lo + R], N),
@@ -1002,7 +1019,7 @@ def held_load(load, c: HybridPreset):
     return load[c.first_expert:c.first_expert + c.experts_held]
 
 
-def moe_stats(load: Dict[int, jnp.ndarray], c: HybridPreset,
+def moe_stats(load: Dict[int, jnp.ndarray], c: HybridPreset, kernel: str,
               pairs: int = 0) -> Dict[str, jnp.ndarray]:
     """The program's routing counters as step metrics, from each E layer's
     load (the tokens that chose each expert): ``learner/moe_rows_here``
@@ -1012,9 +1029,11 @@ def moe_stats(load: Dict[int, jnp.ndarray], c: HybridPreset,
     ``learner/moe_load_max_over_mean`` (the busiest held expert over the
     mean load of ALL experts, the worst layer: 1 is a balanced router);
     where the preset counts them, ``learner/moe_rows_computed`` (mean: the
-    rows the grouped matmuls were handed, every run that holds a row routed
-    here computed whole; ``pairs`` = the update's (token, choice) pairs, a
-    Python int)."""
+    rows inside the groups the grouped matmuls were handed, by
+    ``routed_experts``' rule for the RESOLVED ``kernel`` the layers ran:
+    the rows routed here under "pallas" and "interpret", every run that
+    holds one whole under "xla"; ``pairs`` = the update's (token, choice)
+    pairs, a Python int)."""
     if not load:
         return {}
     sizes = {i: held_load(n, c) for i, n in load.items()}
@@ -1027,7 +1046,9 @@ def moe_stats(load: Dict[int, jnp.ndarray], c: HybridPreset,
     out["learner/moe_rows_absent_share"] = 1.0 - here / n_pairs
     out["learner/moe_load_max_over_mean"] = jnp.max(jnp.stack([
         jnp.max(n) / mean for n in sizes.values()]))
-    if c.count_rows_computed and pairs:
+    if c.count_rows_computed and pairs and kernel != "xla":
+        out["learner/moe_rows_computed"] = here
+    elif c.count_rows_computed and pairs:
         runs = expert_runs(c, pairs)
         starts = jnp.array([sum(runs[:j]) for j in range(len(runs))], F32)
         out["learner/moe_rows_computed"] = jnp.mean(jnp.stack([
@@ -1256,13 +1277,13 @@ class HybridQModel(nn.Module):
         last position: (B, h, p, n), (B, h_v, d_k, d_v)})."""
         return self.window_pass_full(frames)[:3]
 
-    def window_pass_full(self, frames):
+    def window_pass_full(self, frames, kernel: str = "auto"):
         """``window_pass``'s three, and fourth what the second trunk's
         and third trunks' layers report besides: {"aux": {E layer: its
         load-balancing loss}, "decay": {D layer: its mean decay ``exp(g)``},
         "kda_decay": {K layer: each key channel's mean decay (h, d_k)}}; a
         K layer's state (B, h, d_k, d_v) stands in the third beside M's and
-        D's."""
+        D's.  ``kernel``: the E layers' grouped matmul (``expert_kernel``)."""
         c, cd = self.preset, self.compute_dtype
         x = self._embed(frames)
         B, T, d = x.shape
@@ -1274,7 +1295,7 @@ class HybridQModel(nn.Module):
                 @jax.checkpoint
                 def mix(p, x):
                     u = norm(p, x).reshape(B * T, d)
-                    out, n, loss = moe_layer(p, u, c, cd)
+                    out, n, loss = moe_layer(p, u, c, cd, kernel)
                     return x + out.reshape(B, T, d).astype(cd), n, loss
                 x, load[i], loss = mix(p, x)
                 if loss is not None:
@@ -1437,9 +1458,11 @@ def window_applies(model: HybridQModel, pack_frames: int = 0):
     c = model.preset
 
     def online(params, obs):
-        q, load, _, more = model.apply(params, newest(obs),
+        # one choice of kernel for the rows the layers hand it and the count
+        kernel = expert_kernel()
+        q, load, _, more = model.apply(params, newest(obs), kernel,
                                        method=model.window_pass_full)
-        aux = moe_stats(load, c, q.shape[0] * q.shape[1] * c.top_k)
+        aux = moe_stats(load, c, kernel, q.shape[0] * q.shape[1] * c.top_k)
         aux.update({f"{LOAD_KEY}{i}": n for i, n in load.items()})
         if more["aux"]:
             # the one entry the train step adds to the TD loss

@@ -221,6 +221,134 @@ def test_the_pallas_grouped_matmul_is_the_xla_one():
         np.testing.assert_allclose(x, y, rtol=1e-4, atol=1e-5)
 
 
+# -- the Pallas kernels are handed each expert's true rows -----------------------
+
+def _whole_runs(real):
+    """``grouped_dot`` handed a run's tail past the last routed row as rows
+    of the last expert: the Pallas path's form before true counts."""
+    def dot(x, w, sizes, kernel="auto"):
+        return real(x, w, sizes.at[-1].add(x.shape[0] - jnp.sum(sizes)),
+                    kernel)
+    return dot
+
+
+def _nan_outside(real):
+    """``grouped_dot`` with NaN in every row outside the groups, in its
+    output and in its rows' cotangent (the backward's ``gmm``): what the
+    chip may leave in memory the kernels never write."""
+    def outside(t, sizes):
+        inside = jnp.arange(t.shape[0]) < jnp.sum(sizes)
+        return jnp.where(inside[:, None], t, jnp.nan)
+
+    def dot(x, w, sizes, kernel="auto"):
+        @jax.custom_vjp
+        def f(x, w, sizes):
+            return outside(real(x, w, sizes, kernel), sizes)
+
+        def fwd(x, w, sizes):
+            out, back = jax.vjp(lambda x, w: real(x, w, sizes, kernel), x, w)
+            return outside(out, sizes), (back, sizes)
+
+        def bwd(res, g):
+            back, sizes = res
+            dx, dw = back(g)
+            return outside(dx, sizes), dw, None
+
+        f.defvjp(fwd, bwd)
+        return f(x, w, sizes)
+    return dot
+
+
+# b_sel over the 16 experts (4 held, top-3 of 16) and the tokens: a level
+# load inside the first run, one that needs a second run, a held expert with
+# no rows, no rows here, rows that fill the first run exactly (12 tokens:
+# 24 rows, two held experts chosen by every token)
+_LOADS = {
+    "level": (np.zeros(16), 40),
+    "second_run": (np.where(np.arange(16) < 6, 5.0, 0.0), 40),
+    "empty_expert": (np.where(np.arange(16) == 1, -50.0, 0.0), 40),
+    "none_here": (np.where(np.arange(16) < 4, -50.0, 0.0), 40),
+    "first_run_filled": (np.select([np.arange(16) < 2, np.arange(16) < 4],
+                                   [50.0, -50.0], 0.0), 12),
+}
+
+
+@pytest.mark.parametrize("gated", [False, True], ids=["relu2", "swiglu"])
+@pytest.mark.parametrize("load", list(_LOADS))
+def test_true_counts_are_the_whole_runs(load, gated, monkeypatch):
+    """The Pallas path (here under the interpreter) hands the kernels each
+    held expert's true rows of a run; the output and the gradients with
+    respect to the tokens, the router and the expert weights are those of
+    the run computed whole, and stay so with NaN in every row outside the
+    groups."""
+    b_sel, N = _LOADS[load]
+    c = dataclasses.replace(TINY, gated_experts=gated)
+    keys = jax.random.split(jax.random.PRNGKey(3), 16)
+    p = {name: init(k, shape) for k, (name, (init, shape)) in zip(
+        keys, hybrid.layer_param_specs("E", c).items())}
+    p["b_sel"] = jnp.asarray(b_sel, jnp.float32)
+    u = jax.random.normal(jax.random.PRNGKey(8), (N, TINY.d_model))
+
+    def loss(p, u):
+        out, load = hybrid.moe_apply(p, u, c, jnp.float32, "interpret")
+        return jnp.sum(jnp.sin(out)), hybrid.held_load(load, c)
+
+    def run(dot):
+        monkeypatch.setattr(hybrid, "grouped_dot", dot)
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1),
+                                          has_aux=True))(p, u)
+
+    real = hybrid.grouped_dot
+    (want, sizes), g_want = run(_whole_runs(real))
+    first = hybrid.expert_runs(c, N * c.top_k)[0]
+    rows = int(jnp.sum(sizes))
+    assert {"level": 0 < rows < first and int(jnp.min(sizes)) > 0,
+            "second_run": rows > first,
+            "empty_expert": rows > 0 and int(jnp.min(sizes)) == 0,
+            "none_here": rows == 0,
+            "first_run_filled": rows == first}[load]
+    for dot in (real, _nan_outside(real)):
+        (got, _), g_got = run(dot)
+        np.testing.assert_allclose(got, want, rtol=1e-6)
+        assert set(g_got[0]) == set(p)
+        for x, y in zip(jax.tree_util.tree_leaves(g_got),
+                        jax.tree_util.tree_leaves(g_want)):
+            assert np.all(np.isfinite(x))
+            np.testing.assert_allclose(x, y, rtol=1e-6, atol=1e-7)
+
+
+@pytest.mark.parametrize("kernel", ["xla", "interpret"])
+def test_rows_computed_counts_what_the_kernels_were_handed(kernel,
+                                                           monkeypatch):
+    """``learner/moe_rows_computed`` at a skew that needs a second run: the
+    rows routed here where the Pallas kernels run (here the interpreter,
+    chosen as the chip's backend would choose "pallas"), both runs whole
+    where ``jax.lax.ragged_dot`` runs; the rows inside the groups each
+    grouped matmul was handed, either way."""
+    c, model, params = build("E")
+    params["params"]["layers_0"] = dict(
+        params["params"]["layers_0"],
+        b_sel=jnp.where(jnp.arange(TINY.n_experts) < 6, 5.0, 0.0))
+    if kernel == "interpret":
+        monkeypatch.setattr(hybrid, "expert_kernel", lambda kernel="auto": (
+            "interpret" if kernel == "auto" else kernel))
+    handed, real = [], hybrid.grouped_dot
+
+    def dot(x, w, sizes, kernel="auto"):
+        jax.debug.callback(lambda n: handed.append(int(n)), jnp.sum(sizes))
+        return real(x, w, sizes, kernel)
+
+    monkeypatch.setattr(hybrid, "grouped_dot", dot)
+    online = hybrid.window_applies(model)[0]
+    _, m = online(params, frames_of(2, 3, 17))
+    runs = hybrid.expert_runs(c, 3 * 17 * c.top_k)
+    here = float(m["learner/moe_rows_here"])
+    assert len(runs) == 2 and runs[0] < here <= sum(runs)
+    computed = float(m["learner/moe_rows_computed"])
+    assert computed == (here if kernel == "interpret" else sum(runs))
+    assert computed == sum(handed) / 2             # w_up, w_down a run
+
+
 # -- acting through the carry ----------------------------------------------------
 
 def test_acting_step_by_step_is_window_q_with_an_early_reset():
